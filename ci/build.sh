@@ -6,12 +6,8 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-mkdir -p stencil_tpu/_build
-g++ -O2 -shared -fPIC -std=c++17 \
-    stencil_tpu/csrc/qap.cpp -o stencil_tpu/_build/libstencil_qap.so
-
 python - <<'EOF'
-from stencil_tpu import qap
+from stencil_tpu import qap  # builds _build/libstencil_qap-<hash>.so
 assert qap.native_available(), "native QAP solver failed to load"
 import numpy as np
 w = np.array([[0.0, 2.0], [2.0, 0.0]])

@@ -217,10 +217,6 @@ else
 fi
 
 echo "== 4/13 app smoke runs =="
-# overlap app smokes execute remote DMA: possible only on a TPU or
-# with the distributed (mosaic) interpreter — probe, don't assume
-RDMA_OK=$(python -c "from stencil_tpu._compat import remote_dma_runnable
-print(1 if remote_dma_runnable() else 0)")
 smoke() { echo "-- $*"; python "$@" > /dev/null; }
 ( cd apps
   smoke jacobi3d.py --x 8 --y 8 --z 8 --iters 2 --batch 1 --fake-cpu 8
@@ -229,13 +225,8 @@ smoke() { echo "-- $*"; python "$@" > /dev/null; }
   smoke jacobi3d.py --x 8 --y 8 --z 8 --iters 2 --batch 1 --fake-cpu 8 \
         --fake-slices 2 --dcn-axis z
   smoke astaroth.py --nx 8 --ny 8 --nz 8 --iters 1 --fake-cpu 8
-  if [ "$RDMA_OK" = "1" ]; then
-    smoke astaroth.py --nx 8 --ny 8 --nz 8 --iters 1 --fake-cpu 4 \
-          --kernel halo --overlap
-  else
-    echo "-- SKIP astaroth --overlap smoke (no interpreted remote DMA" \
-         "in this JAX; stencil-lint covers the kernels statically)"
-  fi
+  smoke astaroth.py --nx 8 --ny 8 --nz 8 --iters 1 --fake-cpu 4 \
+        --kernel halo --overlap
   smoke bench_exchange.py --x 8 --y 8 --z 8 --iters 2 --fake-cpu 8
   smoke machine_info.py --fake-cpu 8
   smoke bench_qap.py --sizes 4 6
@@ -625,9 +616,7 @@ else
 fi
 OBS_LEGACY="$(mktemp -t obs_legacy.XXXXXX.jsonl)"; rm -f "$OBS_LEGACY"
 python -m stencil_tpu.observatory backfill --out "$OBS_LEGACY" \
-  BENCH_pr3.json BENCH_pr4.json BENCH_pr8.json BENCH_pr10.json \
-  BENCH_r01.json BENCH_r02.json BENCH_r03.json BENCH_r04.json \
-  BENCH_r05.json
+  BENCH_pr3.json BENCH_pr4.json BENCH_pr8.json BENCH_pr10.json
 python -m stencil_tpu.observatory validate "$OBS_LEGACY"
 # the live smoke records and their backfilled ancestors share one
 # converter, so the bench_exchange trajectory diffs across them. A

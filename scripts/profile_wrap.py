@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """What binds the fused kernels? Limiter evidence on hardware.
 
-``--model jacobi`` (default) answers the round-4 question (BASELINE.md):
+``--model jacobi`` (default) answers the round-4 question (BASELINE.json):
 the temporally blocked pair kernel hit 298 iters/s at 512^3 against a
 ~500 iters/s HBM-traffic bound, so something other than traffic now
 binds. ``--model mhd`` asks the same question of the MHD megakernel
@@ -79,7 +79,7 @@ def _verdict(tag: str, rows, ceiling: float, sat: bool,
 
 def _mhd_ladder(args) -> None:
     """MHD rungs: {sequential, pair} x {f32, bf16}, elision-aware
-    traffic model (BASELINE.md: 80 field-volumes/iter sequential, 48
+    traffic model (BASELINE.json: 80 field-volumes/iter sequential, 48
     pair, halved for bf16 storage; ring refetch excluded, so the
     effective-GB/s figures are lower bounds)."""
     import jax
@@ -118,7 +118,7 @@ def _mhd_ladder(args) -> None:
                 print(f"profile_mhd,trace,{args.trace}")
             rate = trimean(rates)
             # dead-w-elided model, in single-field n^3 volumes per
-            # iteration (BASELINE.md: 80 sequential, 48 pair)
+            # iteration (BASELINE.json: 80 sequential, 48 pair)
             volumes = 48.0 if pair else 80.0
             gbs = rate * volumes * n * n * n * item / 1e9
             rows.append((label, rate, gbs))

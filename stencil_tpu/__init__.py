@@ -14,10 +14,6 @@ Instead of MPI ranks + CUDA streams/IPC, the data plane is a 3D
 compute plane is XLA/Pallas kernels.
 """
 
-from . import _compat
-
-_compat.install()
-
 from .geometry import (Dim3, Rect3, Radius, all_directions, deepened,
                        direction_kind)
 from .numerics import Statistics, div_ceil, next_align_of, prime_factors, trimean

@@ -27,8 +27,7 @@ from ..utils.naming import glob_match as _match
 def _setup_backend() -> None:
     """Analysis is pure tracing/lowering: force a small virtual-CPU
     mesh so the shard_map targets resolve their axes without touching
-    accelerators (mirrors tests/conftest.py; shared old-JAX fallback
-    lives in apply_fake_cpu)."""
+    accelerators (mirrors tests/conftest.py)."""
     try:
         from stencil_tpu.utils.config import apply_fake_cpu
 

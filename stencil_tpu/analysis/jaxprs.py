@@ -12,7 +12,7 @@ from __future__ import annotations
 from typing import Any, Callable, Iterator, List, Optional, Tuple
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 Jaxpr = jax_core.Jaxpr
 ClosedJaxpr = jax_core.ClosedJaxpr

@@ -275,13 +275,9 @@ def snap_blocks(kernel: str, Z: int, Y: int,
 
 
 def _block_dims(bm) -> Tuple[int, ...]:
-    out = []
-    for b in bm.block_shape:
-        try:
-            out.append(int(b))
-        except (TypeError, ValueError):
-            out.append(1)  # squeezed dim
-    return tuple(out)
+    from .vmem import _block_dim
+
+    return tuple(_block_dim(b) for b in bm.block_shape)
 
 
 def plan_from_grid_mapping(eqn, budget: int = TILE_SELECT_BUDGET_BYTES,
@@ -311,7 +307,7 @@ def plan_from_grid_mapping(eqn, budget: int = TILE_SELECT_BUDGET_BYTES,
         aval = bm.block_aval
         if _space_name(aval) in ("semaphore", "smem", "any"):
             continue
-        arr = bm.array_shape_dtype
+        arr = bm.array_aval
         try:
             isz = np.dtype(arr.dtype).itemsize
         except TypeError:

@@ -38,8 +38,8 @@ from .report import ERROR, Finding
 HOST_ESCAPE_PRIMS: Dict[str, str] = {
     "pure_callback": "a Python callback runs on host every dispatch",
     "io_callback": "an I/O callback runs on host every dispatch",
-    "debug_callback": "jax.debug.print/callback stalls on host I/O",
-    "debug_print": "debug printing stalls on host I/O",
+    "debug_callback": "jax.debug.callback stalls on host I/O",
+    "debug_print": "jax.debug.print stalls on host I/O",
     "infeed": "infeed blocks the step on host-fed data",
     "outfeed": "outfeed pushes device data at the host mid-step",
 }

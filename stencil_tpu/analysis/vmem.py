@@ -120,11 +120,12 @@ def _kernel_limit(params: dict, default: int) -> int:
 
 
 def _block_dim(b) -> int:
-    """Concrete extent of one block dim: squeezed dims (``None`` in
-    the BlockSpec, the ``Mapped`` sentinel in the GridMapping) occupy
-    one array slice per grid step."""
+    """Concrete extent of one block dim (``Blocked``/``Element`` carry
+    it as ``block_size``): squeezed dims (``None`` in the BlockSpec,
+    ``Squeezed`` in the GridMapping) occupy one array slice per grid
+    step."""
     try:
-        return int(b)
+        return int(getattr(b, "block_size", b))
     except (TypeError, ValueError):
         return 1
 
@@ -162,7 +163,7 @@ def audit_pallas_call(eqn, budget: int, kname: str, target_name: str,
         space = _space_name(aval)
         if space in ("semaphore", "smem", "any"):
             continue
-        arr = bm.array_shape_dtype
+        arr = bm.array_aval
         block = tuple(_block_dim(b) for b in bm.block_shape)
         dtype = np.dtype(arr.dtype)
         block_bytes += _aval_bytes(block, dtype)

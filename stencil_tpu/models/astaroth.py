@@ -495,6 +495,8 @@ class Astaroth:
                     blockers.append(
                         f"uneven grid / z,y % {tile} != 0 / "
                         "overlap requested")
+                if not blockers:
+                    blockers.append("no legal VMEM block shape")
                 why = f" (fast paths unavailable: {', '.join(blockers)})"
             LOG_INFO(f"astaroth kernel path: {kernel}{why}")
         if kernel == "wrap":

@@ -371,6 +371,7 @@ class Jacobi3D:
         from ..parallel import megastep as ms
 
         dd = self.dd
+        self.step_stride = stride
 
         def adopt(out):
             self.dd.curr["temp"] = out
@@ -427,6 +428,10 @@ class Jacobi3D:
     # -- the fused step ------------------------------------------------
     def _build_step(self) -> None:
         self._segment_builder = None
+        #: steps one launch of the built path advances: the in-kernel
+        #: temporal depth on the Pallas wrap/halo paths (2 = the pair
+        #: kernel), the group depth on the XLA temporal path
+        self.step_stride = 1
         self._segment_decline = None
         dd = self.dd
         radius = dd.radius
@@ -1086,19 +1091,31 @@ class Jacobi3D:
                              perf_entry="jacobi")
 
 
+def ripple_field(shape_zyx: Tuple[int, int, int], dtype) -> np.ndarray:
+    """The deterministic field ``((13z + 7y + 3x) mod 17) / 17`` that
+    oracle checks seed: a uniform field cannot expose an exchange that
+    reads the wrong neighbor."""
+    gz, gy, gx = shape_zyx
+    iz = np.arange(gz)[:, None, None]
+    iy = np.arange(gy)[None, :, None]
+    ix = np.arange(gx)[None, None, :]
+    return (((iz * 13 + iy * 7 + ix * 3) % 17) / 17.0).astype(dtype)
+
+
 def dense_reference_step(temp: np.ndarray, hot_c: Tuple[int, int, int],
                          cold_c: Tuple[int, int, int], sph_r: int
                          ) -> np.ndarray:
     """Single-device dense oracle of one jacobi step on a (z,y,x) global
     array with periodic wrap — the correctness reference for the
-    distributed solver (BASELINE.md config 1)."""
+    distributed solver (BASELINE.json config 1)."""
     out = np.zeros_like(temp)
     for axis, dim in ((0, 0), (1, 1), (2, 2)):
         out += np.roll(temp, 1, axis=axis) + np.roll(temp, -1, axis=axis)
     out /= 6.0
     gz, gy, gx = np.meshgrid(np.arange(temp.shape[0]),
                              np.arange(temp.shape[1]),
-                             np.arange(temp.shape[2]), indexing="ij")
+                             np.arange(temp.shape[2]), indexing="ij",
+                             sparse=True)
     hx, hy, hz = hot_c
     cx, cy, cz = cold_c
     d2h = (gx - hx) ** 2 + (gy - hy) ** 2 + (gz - hz) ** 2

@@ -30,11 +30,10 @@ from ..geometry import Dim3, Radius
 
 def on_tpu() -> bool:
     """Single source of truth for "is this process on a TPU backend"
-    (shared by kernel selection and exchange interpret-mode choices)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend not initialized yet
-        return False
+    (shared by kernel selection and exchange interpret-mode choices).
+    A backend that fails to initialise raises: it is never read as
+    "not a TPU", which would quietly interpret every kernel."""
+    return jax.default_backend() == "tpu"
 
 
 def default_interpret() -> bool:

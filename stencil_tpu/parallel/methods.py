@@ -66,18 +66,6 @@ def method_supports_wire_format(m: "Method") -> bool:
     return m in WIRE_CAPABLE
 
 
-def method_runnable(m: "Method") -> bool:
-    """Can this strategy actually EXECUTE in this process? Every
-    XLA-collective strategy runs anywhere; PallasDMA (explicit
-    inter-chip RDMA) needs a TPU backend or the distributed (mosaic)
-    interpreter — the ``_compat`` capability probe. Trace-only uses
-    (the static analyzers) bypass this and call the engines directly."""
-    if m == Method.PallasDMA:
-        from .._compat import remote_dma_runnable
-        return remote_dma_runnable()
-    return True
-
-
 # (requested, fallback) pairs already warned about — the orchestrator
 # consults pick_method several times per realize(); warn once per fact
 _warned: Set[Tuple[int, int]] = set()
@@ -93,19 +81,14 @@ def pick_method(methods: "Method",
 
     PallasDMA (explicit inter-chip RDMA, parallel/pallas_exchange.py)
     wins when requested — it is the opt-in manual-transport path, like
-    the reference's direct-write Colo* methods. The pick is
-    capability-aware: a requested strategy the current backend cannot
-    RUN (``method_runnable``, e.g. PallasDMA off-TPU without the
-    distributed interpreter) is skipped with a logged warning in favor
-    of the next runnable requested strategy, or ``Method.Default`` when
-    nothing requested is runnable — selecting an unrunnable transport
-    would only defer the failure into the jitted program.
-
-    ``runnable``: injectable capability predicate (tests exercise both
-    branches without a TPU); defaults to :func:`method_runnable`.
+    the reference's direct-write Colo* methods. Every strategy runs
+    on the installed JAX (PallasDMA natively on a TPU, and through the
+    distributed Pallas interpreter off it), so by default the
+    highest-priority requested strategy wins. ``runnable`` narrows
+    that: a requested strategy it rejects is skipped with a logged
+    warning in favor of the next accepted one, or ``Method.Default``
+    when none is accepted.
     """
-    if runnable is None:
-        runnable = method_runnable
     requested = [m for m in METHOD_PRIORITY if m in methods]
     if not requested:
         if Method.Auto in methods:
@@ -116,7 +99,7 @@ def pick_method(methods: "Method",
         raise ValueError(f"no usable method in {methods}")
     skipped = []
     for m in requested:
-        if runnable(m):
+        if runnable is None or runnable(m):
             if skipped:
                 _warn_fallback(skipped, m)
             return m
@@ -134,5 +117,5 @@ def _warn_fallback(skipped, chosen: "Method") -> None:
         return
     _warned.add(key)
     names = "|".join(m.name or "?" for m in skipped)
-    LOG_WARN(f"requested exchange method(s) {names} cannot run on this "
-             f"backend (capability probe); falling back to {chosen}")
+    LOG_WARN(f"requested exchange method(s) {names} were rejected "
+             f"by the runnable predicate; falling back to {chosen}")

@@ -12,7 +12,9 @@ bijection ``f`` (list of device slots) minimizing
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import itertools
+import os
 import subprocess
 import time
 from pathlib import Path
@@ -23,25 +25,37 @@ import numpy as np
 _HERE = Path(__file__).resolve().parent
 _SRC = _HERE / "csrc" / "qap.cpp"
 _BUILD_DIR = _HERE / "_build"
-_LIB_PATH = _BUILD_DIR / "libstencil_qap.so"
+
+
+def _lib_path() -> Path:
+    """The library built from the source as it is now: its name carries
+    a hash of ``qap.cpp``, so a library built from any other source
+    (a stale or copied ``_build/``) is never loaded."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libstencil_qap-{digest}.so"
+
 
 _lib: Optional[ctypes.CDLL] = None
 _native_failed = False
 
 
 def _build_native() -> Optional[ctypes.CDLL]:
-    """Compile csrc/qap.cpp to a shared library (cached by mtime)."""
+    """Compile csrc/qap.cpp to a shared library (cached by source
+    hash; built under a private name and renamed into place, so
+    concurrent builders never load a half-written file)."""
     global _native_failed
     if _native_failed:
         return None
     try:
         _BUILD_DIR.mkdir(exist_ok=True)
-        if (not _LIB_PATH.exists()
-                or _LIB_PATH.stat().st_mtime < _SRC.stat().st_mtime):
+        lib_path = _lib_path()
+        if not lib_path.exists():
+            tmp = lib_path.with_suffix(f".{os.getpid()}.tmp")
             cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                   str(_SRC), "-o", str(_LIB_PATH)]
+                   str(_SRC), "-o", str(tmp)]
             subprocess.run(cmd, check=True, capture_output=True)
-        lib = ctypes.CDLL(str(_LIB_PATH))
+            os.replace(tmp, lib_path)
+        lib = ctypes.CDLL(str(lib_path))
         dp = ctypes.POINTER(ctypes.c_double)
         ip = ctypes.POINTER(ctypes.c_int64)
         lib.qap_solve_exact.restype = ctypes.c_double

@@ -41,8 +41,7 @@ from typing import Callable, Dict, List, Optional
 from ..analysis.recompile import (ASSERT_SINGLE_COMPILE_ENV,
                                   SingleCompileGuard)
 from ..analysis.transfer import hot_loop_transfer_guard
-from ..parallel.methods import (METHOD_PRIORITY, Method, method_runnable,
-                                pick_method)
+from ..parallel.methods import METHOD_PRIORITY, Method, pick_method
 from ..utils.checkpoint import restore_domain, save_domain
 from ..utils.logging import LOG_INFO, LOG_WARN
 from ..utils.retry import retry
@@ -118,18 +117,16 @@ def degradation_ladder(method: Method, exchange_every: int,
                        ) -> List[StepConfig]:
     """Successively safer configurations: first halve the temporal-
     blocking depth down to per-step exchanges (deep halos stress the
-    fabric hardest), then fall down the capability-aware
-    ``pick_method`` priority list below the current transport.
-    ``runnable`` is injectable for tests (defaults to the real
-    capability probe)."""
-    if runnable is None:
-        runnable = method_runnable
+    fabric hardest), then fall down the ``pick_method`` priority list
+    below the current transport.
+    ``runnable`` narrows the strategies the ladder may fall to
+    (default: all of them)."""
     out: List[StepConfig] = []
     s = int(exchange_every)
     while s > 1:
         s //= 2
         out.append(StepConfig(method, s))
-    live = [m for m in METHOD_PRIORITY if runnable(m)]
+    live = [m for m in METHOD_PRIORITY if runnable is None or runnable(m)]
     if method in live:
         live = live[live.index(method) + 1:]
     out.extend(StepConfig(m, 1) for m in live)
@@ -162,7 +159,7 @@ class ResilienceReport:
     fused: bool = False
     fused_decline_reason: str = ""
     #: the machine-readable ``megastep.DECLINE_*`` vocabulary code
-    #: behind ``fused_decline_reason`` (greppable cause taxonomy)
+    #: behind ``fused_decline_reason`` (a greppable list of causes)
     fused_decline_code: str = ""
     events: List[Dict] = dataclasses.field(default_factory=list)
 
@@ -646,10 +643,9 @@ class _ResilientRun:
             cfg = _current_config(self.dd)
             self.ladder = degradation_ladder(cfg.method,
                                              cfg.exchange_every)
-        # walk rungs until one actually realizes: capability is known
-        # up front (method_runnable) but domain feasibility (uneven
-        # shards, Boundary.NONE, temporal-depth limits) only surfaces
-        # in the constructor — an infeasible rung is skipped, never
+        # walk rungs until one actually realizes: domain feasibility
+        # (uneven shards, Boundary.NONE, temporal-depth limits) surfaces
+        # only in the constructor — an infeasible rung is skipped, never
         # allowed to kill the recovery with a raw NotImplementedError
         while (self.policy.degrade and self.rebuild is not None
                and self.ladder):

@@ -193,10 +193,8 @@ def candidate_space(geom: TuneGeometry,
                     ) -> List[Candidate]:
     """Every feasible, runnable configuration, in deterministic
     tie-break order (method priority x depth ascending x overlap off
-    first x full-precision wire first). ``runnable`` filters
-    strategies the backend cannot execute (capability probes —
-    PallasDMA off-TPU); defaults to
-    ``parallel.methods.method_runnable``. ``wire_formats`` is opt-in:
+    first x full-precision wire first). ``runnable`` narrows
+    the strategies swept (default: all of them). ``wire_formats`` is opt-in:
     the default sweeps only the identity "f32" wire; pass
     ``("f32", "bf16")`` to also rank the certified half-width wire on
     the ppermute engines. ``wire_layouts`` is likewise opt-in: pass
@@ -208,10 +206,8 @@ def candidate_space(geom: TuneGeometry,
     tuple — which become asymmetric candidates (``Candidate.depths``,
     keys like ``PpermuteSlab[s=1.1.4]``)."""
     from ..geometry import normalize_depths
-    from ..parallel.methods import Method, method_runnable
+    from ..parallel.methods import Method
 
-    if runnable is None:
-        runnable = method_runnable
     uniform = set()
     asym = set()
     for d in depths:
@@ -225,7 +221,7 @@ def candidate_space(geom: TuneGeometry,
                 asym.add((nd.x, nd.y, nd.z))
     out: List[Candidate] = []
     for name in PLAN_METHODS:
-        if not runnable(Method[name]):
+        if runnable is not None and not runnable(Method[name]):
             continue
         specs = ([(s, None) for s in sorted(uniform)]
                  + [(max(d), d) for d in sorted(asym)])

@@ -11,7 +11,6 @@ import pytest
 
 import jax
 
-from stencil_tpu._compat import remote_dma_runnable
 from stencil_tpu.geometry import Dim3
 from stencil_tpu.models.astaroth import (FIELDS, Astaroth, MhdParams,
                                          _hash_field, _radial_explosion)
@@ -291,10 +290,6 @@ class TestBfloat16:
         assert m.kernel_path == "halo-overlap"
 
     @pytest.mark.slow
-    @pytest.mark.skipif(
-        not remote_dma_runnable(),
-        reason="Pallas remote DMA needs a TPU backend or the "
-               "distributed (mosaic) TPU interpreter")
     @pytest.mark.parametrize("pair", ["0", "1"])
     def test_overlap_bf16_matches_f32_oracle(self, pair, monkeypatch):
         """The overlapped (in-kernel RDMA) path in bf16, alone and

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from stencil_tpu._compat import remote_dma_runnable
 from stencil_tpu.geometry import Dim3, Radius
 from stencil_tpu.local_domain import raw_size, zyx_shape
 from stencil_tpu.parallel.exchange import (make_exchange,
@@ -96,21 +95,11 @@ def mesh222():
     return make_mesh((2, 2, 2))
 
 
-# executing (not just tracing) explicit remote DMA needs a TPU or the
-# distributed mosaic interpreter; the static analysis pass (stencil-lint)
-# still checks these paths on every image
-needs_rdma = pytest.mark.skipif(
-    not remote_dma_runnable(),
-    reason="Pallas remote DMA needs a TPU backend or the distributed "
-           "(mosaic) TPU interpreter")
-
-
 class TestExchangeOracle:
     @pytest.mark.parametrize("method", [Method.PpermuteSlab,
                                         Method.PpermutePacked,
                                         Method.AllGather,
-                                        pytest.param(Method.PallasDMA,
-                                                     marks=needs_rdma)])
+                                        Method.PallasDMA])
     def test_radius1_2x2x2(self, mesh222, method):
         gsize = Dim3(8, 8, 8)
         radius = Radius.constant(1)
@@ -140,7 +129,6 @@ class TestExchangeOracle:
         # only face halos on padded sides exist; check full padded region
         check_halos(np.asarray(out), gsize, mesh222, radius)
 
-    @needs_rdma
     def test_pallas_dma_radius2(self, mesh222):
         gsize = Dim3(8, 8, 8)
         radius = Radius.constant(2)
@@ -149,7 +137,6 @@ class TestExchangeOracle:
         out = ex({"q": arr})["q"]
         check_halos(np.asarray(out), gsize, mesh222, radius)
 
-    @needs_rdma
     def test_pallas_dma_asymmetric_1d(self):
         # uncentered kernel over a deep 1D ring: +x 2, -x 1
         mesh = make_mesh((8, 1, 1))

@@ -362,10 +362,10 @@ def test_transfer_fixture_flagged():
     report = run_targets(load_targets(FIXTURES / "bad_transfer.py"))
     assert not report.ok
     msgs = {f.target: f.message for f in report.errors}
-    assert "debug_callback" in msgs["fixture.debug_print_in_step"]
+    assert "debug_print" in msgs["fixture.debug_print_in_step"]
     assert "pure_callback" in msgs["fixture.pure_callback_in_step"]
     m = report.metrics["transfer:fixture.debug_print_in_step"]
-    assert m["host_escapes"] == {"debug_callback": 1}
+    assert m["host_escapes"] == {"debug_print": 1}
 
 
 def test_recompile_fixture_flagged():

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from stencil_tpu._compat import remote_dma_runnable
 from stencil_tpu.models.jacobi import Jacobi3D
 from stencil_tpu.parallel.megastep import (MAX_UNROLL, probe_rel_steps,
                                            segment_chunks)
@@ -197,10 +196,6 @@ def test_overlap_path_declines_on_unsafe_certificate(monkeypatch):
     assert "in-flight aliasing across sub-steps" in d.reason
 
 
-@pytest.mark.skipif(
-    not remote_dma_runnable(),
-    reason="Pallas remote DMA needs a TPU backend or the distributed "
-           "(mosaic) TPU interpreter")
 def test_overlap_segment_bitwise():
     """Certificate-gated fused RDMA segment == stepwise, bitwise: the
     k launches fused into one program carry exactly the per-launch

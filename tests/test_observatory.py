@@ -336,22 +336,48 @@ def test_diff_records_ratio_and_comparability():
     assert not d["comparable"]
 
 
-def test_backfill_committed_legacy_history():
-    """The five committed BENCH_*.json shapes all convert; failed and
-    suspect legacy runs are skipped, never invented."""
+def _harness_payload(n, rc, parsed):
+    """A bench.py run wrapped the way the old harness stored it
+    (``{"n", "cmd", "rc", "tail", "parsed"}``)."""
+    return {"n": n, "cmd": "python bench.py", "rc": rc,
+            "tail": json.dumps(parsed) if parsed else "Traceback ...",
+            "parsed": parsed}
+
+
+def test_backfill_committed_legacy_history(tmp_path):
+    """The committed BENCH_pr*.json shapes and the harness-wrapped
+    bench.py shape all convert; failed and suspect legacy runs are
+    skipped, never invented."""
     from stencil_tpu.observatory.ledger import backfill_files
+    metric = "jacobi3d_512c_iters_per_sec"
+    extra = {"devices": 1, "mesh": [1, 1, 1], "platform": "tpu"}
+    harness = [
+        _harness_payload(1, 0, {"metric": metric, "value": 100.0,
+                                "unit": "iters/s", "extra": extra}),
+        _harness_payload(2, 1, None),
+        _harness_payload(3, 0, {"metric": metric, "value": 0.0,
+                                "unit": "iters/s", "suspect": True,
+                                "extra": {}}),
+        _harness_payload(4, 0, {"metric": metric, "value": None,
+                                "unit": "iters/s", "suspect": True,
+                                "extra": {}}),
+    ]
+    wrapped = []
+    for p in harness:
+        path = tmp_path / f"BENCH_harness{p['n']}.json"
+        path.write_text(json.dumps(p))
+        wrapped.append(path)
     files = [REPO / f for f in
              ("BENCH_pr3.json", "BENCH_pr4.json", "BENCH_pr8.json",
-              "BENCH_pr10.json", "BENCH_r01.json", "BENCH_r02.json",
-              "BENCH_r03.json", "BENCH_r04.json", "BENCH_r05.json")]
+              "BENCH_pr10.json")] + wrapped
     records, skipped = backfill_files(files)
-    assert len(records) == 10
+    assert len(records) == 9
     assert all(r["provenance"] == "legacy" for r in records)
     assert all(validate_record(r) == [] for r in records)
     benches = {r["bench"] for r in records}
     assert {"bench_exchange", "bench_exchange.megastep",
-            "bench_exchange.autotune", "pic"} <= benches
-    # r02 failed, r04/r05 are suspect: skipped with a reason each
+            "bench_exchange.autotune", "pic", metric} <= benches
+    # run 2 failed, runs 3/4 are suspect: skipped with a reason each
     assert len(skipped) == 3
     # legacy history seeds trajectories but never trips the gate
     assert gate_regressions(records) == []

@@ -8,7 +8,6 @@ bin/jacobi3d.cu:296-377)."""
 import numpy as np
 import pytest
 
-from stencil_tpu._compat import remote_dma_runnable
 from stencil_tpu.geometry import Dim3, Radius
 from stencil_tpu.parallel.overlap import split_regions
 
@@ -70,10 +69,6 @@ def test_jacobi_overlap_matches_fused():
     np.testing.assert_allclose(b.temperature(), a.temperature(), atol=1e-6)
 
 
-@pytest.mark.skipif(
-    not remote_dma_runnable(),
-    reason="Pallas remote DMA needs a TPU backend or the distributed "
-           "(mosaic) TPU interpreter")
 def test_jacobi_overlap_kernel_in_kernel_rdma():
     """overlap=True on an x-unsharded even mesh routes to the in-kernel
     RDMA overlap kernel (ops/pallas_overlap.py) — interior computed
@@ -109,10 +104,6 @@ def test_jacobi_overlap_kernel_in_kernel_rdma():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not remote_dma_runnable(),
-    reason="Pallas remote DMA needs a TPU backend or the distributed "
-           "(mosaic) TPU interpreter")
 @pytest.mark.parametrize("mesh_shape,size,thinz,pair", [
     # (1,2,2) on (16,16,48): local (16,8,24) -> nzg=3, exercising BOTH
     # fix-up strips (z edges + the middle y strip); (1,1,2) on

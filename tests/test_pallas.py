@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from stencil_tpu._compat import remote_dma_runnable
 from stencil_tpu.geometry import Dim3, Radius
 from stencil_tpu.local_domain import raw_size, zyx_shape
 from stencil_tpu.ops.fd6 import FieldData
@@ -137,10 +136,6 @@ def test_jacobi_model_wrap_kernel_matches_oracle():
     np.testing.assert_allclose(j.temperature(), temp, atol=1e-6)
 
 
-@pytest.mark.skipif(
-    not remote_dma_runnable(),
-    reason="Pallas remote DMA needs a TPU backend or the distributed "
-           "(mosaic) TPU interpreter")
 def test_jacobi_model_full_pallas_path_matches_oracle():
     """Pallas compute kernel + Pallas RDMA exchange — the all-manual
     path (the reference's Colo*Kernel method analog)."""

@@ -19,20 +19,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.experimental.pallas import tpu as pltpu
 
-from stencil_tpu._compat import has_race_detector
 from stencil_tpu.geometry import Dim3, Radius
 from stencil_tpu.parallel.mesh import make_mesh, mesh_dim
-
-# The vector-clock race detector is the distributed (mosaic) TPU
-# interpreter's; on images whose JAX predates it these tests cannot run
-# at all (no interpreted inter-device DMA either). The static analysis
-# pass (python -m stencil_tpu.analysis) covers the same kernels'
-# DMA/semaphore discipline on every image.
-pytestmark = pytest.mark.skipif(
-    not has_race_detector(),
-    reason="needs pltpu.InterpretParams(detect_races=True) — the "
-           "distributed TPU interpreter's vector-clock race detector")
-
 
 def _capture_races(fn):
     """Run ``fn`` with stdout captured; return (result, race_report)."""
@@ -230,7 +218,7 @@ def test_pair_overlap_negative_control_missing_barrier():
             device_id={"z": other})
         rc.start()
         rc.wait()
-        out_ref[...] = in_ref[...]
+        pltpu.sync_copy(in_ref, out_ref)
 
     def shard(p):
         return pl.pallas_call(
@@ -274,7 +262,7 @@ def _uneven_rdma_exchange(off_by_one: bool):
     rem = 1                    # first `rem` shards are full-length
     alloc = cap + 2 * r
 
-    def kern(in_ref, out_ref, send, recv):
+    def kern(in_ref, out_ref, buf, send, recv):
         me = lax.axis_index("z")
         n = jnp.int32(2)
         up = lax.rem(me + 1, n)
@@ -310,13 +298,15 @@ def _uneven_rdma_exchange(off_by_one: bool):
         bot.start()
         # concurrent local fill of my ACTUAL interior rows [r, r+L)
         # (the halo regions are remote-write-only: disjoint when the
-        # placement is correct)
+        # placement is correct); ANY refs move only by DMA, so the fill
+        # is staged through VMEM
+        pltpu.sync_copy(in_ref, buf)
         i = jnp.arange(alloc)[:, None, None]
         interior = jnp.logical_and(i >= r, i < r + L_me)
-        vals = jnp.where(interior, in_ref[...], jnp.zeros_like(in_ref))
-        out_ref[pl.ds(r, 1)] = vals[r:r + 1]
+        buf[...] = jnp.where(interior, buf[...], jnp.zeros_like(buf))
+        pltpu.sync_copy(buf.at[pl.ds(r, 1)], out_ref.at[pl.ds(r, 1)])
         idx = jnp.minimum(r + L_me - 1, jnp.int32(alloc - 1))
-        out_ref[pl.ds(idx, 1)] = jnp.take(vals, idx[None], axis=0)
+        pltpu.sync_copy(buf.at[pl.ds(idx, 1)], out_ref.at[pl.ds(idx, 1)])
         top.wait()
         bot.wait()
 
@@ -326,7 +316,8 @@ def _uneven_rdma_exchange(off_by_one: bool):
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             out_shape=jax.ShapeDtypeStruct(p.shape, p.dtype),
-            scratch_shapes=[pltpu.SemaphoreType.DMA((2,)),
+            scratch_shapes=[pltpu.VMEM(p.shape, p.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
                             pltpu.SemaphoreType.DMA((2,))],
             compiler_params=pltpu.CompilerParams(
                 collective_id=8, has_side_effects=True),
