@@ -1,6 +1,7 @@
 #!/usr/bin/env bash
 # One-command benchmark campaign: reproduces every BASELINE.json row on
-# the current backend (intended for a real TPU chip). Results land in
+# the current backend (intended for a real TPU chip; the chip benchmark
+# itself is benchmark/run.py, see BENCHMARK.json). Results land in
 # campaign_<timestamp>/ as raw CSV/JSON logs, one file per experiment
 # (the scripts/summit/512node_jacobi3d.sh:15-37 ethos: a reproducible
 # sweep, every number written down).
@@ -33,17 +34,11 @@ run() {  # run <logfile> <cmd...>; failures are recorded, not fatal
     fi
 }
 
-# 1. headline: jacobi3d 512^3 iters/s + exchange stats (BENCH schema;
-#    needs the real backend — skipped in smoke mode)
-if [ "$SMOKE" != "1" ]; then
-    run bench.json python bench.py
-fi
-
-# 2. single-chip kernel A/B: wrap vs halo vs xla, both models
+# 1. single-chip kernel A/B: wrap vs halo vs xla, both models
 run kernels_default.csv python scripts/bench_kernels.py \
     --model both --kernels wrap,halo,xla "${FAKE[@]}"
 
-# 3. block-shape sweeps at the benchmark sizes
+# 2. block-shape sweeps at the benchmark sizes
 for b in "8,128" "16,128" "8,256" "16,64"; do
     run "kernels_jacobi_b${b/,/x}.csv" python scripts/bench_kernels.py \
         --model jacobi --kernels wrap,halo --blocks "$b" \
@@ -72,7 +67,7 @@ run profile_jacobi.csv python scripts/profile_wrap.py \
 run profile_mhd.csv python scripts/profile_wrap.py \
     --model mhd ${PROF[@]+"${PROF[@]}"} "${FAKE[@]}"
 
-# 4. exchange microbenchmarks (BASELINE.json configs 2/4 analogs)
+# 3. exchange microbenchmarks (BASELINE.json configs 2/4 analogs)
 ( cd apps
   run bench_exchange.csv python bench_exchange.py \
       --x "$EX" --y "$EX" --z "$EX" --fr 2 --er 2 --cr 2 \
@@ -89,7 +84,7 @@ run profile_mhd.csv python scripts/profile_wrap.py \
       --interior-slabs "${FAKE[@]}"
 )
 
-# 5. apps at reference configs (weak scaling on whatever devices exist)
+# 4. apps at reference configs (weak scaling on whatever devices exist)
 ( cd apps
   run jacobi3d.csv python jacobi3d.py \
       --x "$JN" --y "$JN" --z "$JN" --iters "$JI" --batch 2 "${FAKE[@]}"
@@ -100,6 +95,5 @@ run profile_mhd.csv python scripts/profile_wrap.py \
 )
 
 echo "campaign complete: $OUT/"
-# bench.json is absent in smoke mode; the summary glob must not turn a
-# fully-green run into a nonzero exit
+# the summary glob must not turn a fully-green run into a nonzero exit
 grep -H "" "$OUT"/*.csv "$OUT"/*.json 2>/dev/null | tail -40 || true

@@ -50,9 +50,11 @@ from ..distributed import DistributedDomain
 from ..geometry import Dim3, Dim3Like, Radius
 from ..local_domain import zyx_shape
 from ..ops.fd6 import RADIUS, FieldData
+from ..ops.stencil_kernels import read_interior, write_interior
 from ..parallel.exchange import dispatch_exchange
 from ..parallel.mesh import mesh_dim
 from ..parallel.methods import Method, pick_method
+from ..telemetry.spans import BLOCK, KERNEL, RUN, get_tracer, layer_scope
 from ..utils.config import load_config
 
 FIELDS = ("lnrho", "uux", "uuy", "uuz", "ax", "ay", "az", "ss")
@@ -123,6 +125,7 @@ def _cross(a, b):
             a[0] * b[1] - a[1] * b[0])
 
 
+@layer_scope(KERNEL)
 def mhd_rates(f: Dict[str, FieldData], prm: MhdParams, dtype):
     """Right-hand sides of all 8 equations at the current state
     (reference: astaroth/user_kernels.h:383-453)."""
@@ -355,22 +358,22 @@ class Astaroth:
         def substep_fused(fields, w, s):
             fields = dispatch_exchange(fields, radius, counts, method,
                                        rem=rem, nonperiodic=nonper)
-            data = {q: FieldData(fields[q].astype(comp), inv_ds,
-                                 pad_lo, local)
-                    for q in FIELDS}
-            rates = mhd_rates(data, prm, comp)
-            alpha = jnp.asarray(RK3_ALPHA[s], comp)
-            beta = jnp.asarray(RK3_BETA[s], comp)
-            dt_ = jnp.asarray(dt, comp)
-            new_f = {}
-            new_w = {}
-            for q in FIELDS:
-                wq = alpha * w[q].astype(comp) + dt_ * rates[q]
-                uq = data[q].value + beta * wq
-                new_w[q] = wq.astype(store)
-                new_f[q] = lax.dynamic_update_slice(
-                    fields[q], uq.astype(store),
-                    (pad_lo.z, pad_lo.y, pad_lo.x))
+            with layer_scope(KERNEL):
+                data = {q: FieldData(fields[q].astype(comp), inv_ds,
+                                     pad_lo, local)
+                        for q in FIELDS}
+                rates = mhd_rates(data, prm, comp)
+                alpha = jnp.asarray(RK3_ALPHA[s], comp)
+                beta = jnp.asarray(RK3_BETA[s], comp)
+                dt_ = jnp.asarray(dt, comp)
+                new_u = {}
+                new_w = {}
+                for q in FIELDS:
+                    wq = alpha * w[q].astype(comp) + dt_ * rates[q]
+                    new_u[q] = (data[q].value + beta * wq).astype(store)
+                    new_w[q] = wq.astype(store)
+            new_f = {q: write_interior(fields[q], new_u[q], radius)
+                     for q in FIELDS}
             return new_f, new_w
 
         def substep_overlap(fields, w, s):
@@ -383,6 +386,7 @@ class Astaroth:
             beta = jnp.asarray(RK3_BETA[s], comp)
             dt_ = jnp.asarray(dt, comp)
 
+            @layer_scope(KERNEL)
             def upd(blocks, dims, off):
                 data = {q: FieldData(blocks[q].astype(comp), inv_ds,
                                      pad_lo, dims)
@@ -402,9 +406,8 @@ class Astaroth:
             fields_ex, parts = overlapped_update(fields, radius, counts,
                                                  method, upd,
                                                  nonperiodic=nonper)
-            new_f = {q: lax.dynamic_update_slice(
-                fields_ex[q], parts[f"f:{q}"],
-                (pad_lo.z, pad_lo.y, pad_lo.x)) for q in FIELDS}
+            new_f = {q: write_interior(fields_ex[q], parts[f"f:{q}"],
+                                       radius) for q in FIELDS}
             new_w = {q: parts[f"w:{q}"] for q in FIELDS}
             return new_f, new_w
 
@@ -653,6 +656,7 @@ class Astaroth:
         def make_update(start, origin):
             ox, oy, oz = origin
 
+            @layer_scope(KERNEL)
             def update_fn(blocks, dims, off, k):
                 sub = (start + k) % 3
                 data = {q: FieldData(blocks[q].astype(comp), inv_ds,
@@ -774,17 +778,15 @@ class Astaroth:
             LOG_WARN("exchange_every has no effect on the single-chip "
                      "wrap path (it performs no exchange); fields still "
                      "carry the deepened allocation pads")
-        lo = dd.alloc_radius.pad_lo()
+        alloc_r = dd.alloc_radius
         local = dd.local_size
         prm = self.prm
         dt = prm.dt
 
         @jax.jit
         def extract(fields):
-            return {q: lax.slice(
-                p, (lo.z, lo.y, lo.x),
-                (lo.z + local.z, lo.y + local.y, lo.x + local.x))
-                for q, p in fields.items()}
+            return {q: read_interior(p, alloc_r, local)
+                    for q, p in fields.items()}
 
         # STENCIL_MHD_PAIR=1 opts into the fused substep-0+1 kernel
         # (one HBM pass for two of the three RK substeps; alpha_0 == 0
@@ -824,9 +826,8 @@ class Astaroth:
         def insert(fields, inner):
             # halos go stale; nothing reads them before the next
             # exchange, and field() reads the interior only
-            return {q: lax.dynamic_update_slice(
-                fields[q], inner[q], (lo.z, lo.y, lo.x))
-                for q in fields}
+            return {q: write_interior(fields[q], inner[q], alloc_r)
+                    for q in fields}
 
         # interior-resident state between calls: step()-per-iteration
         # loops would otherwise pay extract+insert (3 extra full-field
@@ -851,7 +852,7 @@ class Astaroth:
         from ..parallel.exchange import exchange_interior_slabs
 
         dd = self.dd
-        lo = dd.alloc_radius.pad_lo()
+        alloc_r = dd.alloc_radius
         local = dd.local_size
         counts = mesh_dim(dd.mesh)
         prm = self.prm
@@ -882,10 +883,8 @@ class Astaroth:
             LOG_INFO("astaroth halo path: fused substep-0+1 kernel")
 
         def extract_shard(fields):
-            return {q: lax.slice(
-                p, (lo.z, lo.y, lo.x),
-                (lo.z + local.z, lo.y + local.y, lo.x + local.x))
-                for q, p in fields.items()}
+            return {q: read_interior(p, alloc_r, local)
+                    for q, p in fields.items()}
 
         extract = jax.jit(jax.shard_map(
             extract_shard, mesh=dd.mesh, in_specs=(fields_spec,),
@@ -930,9 +929,8 @@ class Astaroth:
             donate_argnums=(0, 1))
 
         def insert_shard(fields, inner):
-            return {q: lax.dynamic_update_slice(
-                fields[q], inner[q], (lo.z, lo.y, lo.x))
-                for q in fields}
+            return {q: write_interior(fields[q], inner[q], alloc_r)
+                    for q in fields}
 
         self._insert = jax.jit(jax.shard_map(
             insert_shard, mesh=dd.mesh, in_specs=(fields_spec, fields_spec),
@@ -957,7 +955,7 @@ class Astaroth:
         from ..ops.pallas_mhd_overlap import mhd_substep_overlap
 
         dd = self.dd
-        lo = dd.alloc_radius.pad_lo()
+        alloc_r = dd.alloc_radius
         local = dd.local_size
         counts = mesh_dim(dd.mesh)
         prm = self.prm
@@ -971,10 +969,8 @@ class Astaroth:
         fields_spec = {q: spec for q in FIELDS}
 
         def extract_shard(fields):
-            return {q: lax.slice(
-                p, (lo.z, lo.y, lo.x),
-                (lo.z + local.z, lo.y + local.y, lo.x + local.x))
-                for q, p in fields.items()}
+            return {q: read_interior(p, alloc_r, local)
+                    for q, p in fields.items()}
 
         extract = jax.jit(jax.shard_map(
             extract_shard, mesh=dd.mesh, in_specs=(fields_spec,),
@@ -1030,9 +1026,8 @@ class Astaroth:
             donate_argnums=(0, 1))
 
         def insert_shard(fields, inner):
-            return {q: lax.dynamic_update_slice(
-                fields[q], inner[q], (lo.z, lo.y, lo.x))
-                for q in fields}
+            return {q: write_interior(fields[q], inner[q], alloc_r)
+                    for q in fields}
 
         self._insert = jax.jit(jax.shard_map(
             insert_shard, mesh=dd.mesh, in_specs=(fields_spec, fields_spec),
@@ -1194,17 +1189,19 @@ class Astaroth:
         self._w = dict(out_w)
 
     def run(self, iters: int) -> None:
-        self._ensure_w()
-        out_f, out_w = self._iter_n(self.dd.curr, self._w,
-                                    jnp.asarray(iters, jnp.int32))
-        self.dd.curr = dict(out_f)
-        self._w = dict(out_w)
+        with get_tracer().span(RUN, iters=iters):
+            self._ensure_w()
+            out_f, out_w = self._iter_n(self.dd.curr, self._w,
+                                        jnp.asarray(iters, jnp.int32))
+            self.dd.curr = dict(out_f)
+            self._w = dict(out_w)
 
     def block(self) -> None:
         from ..utils.timers import device_sync
         inner = self._inner
-        device_sync(inner["lnrho"] if inner is not None
-                    else self.dd.curr["lnrho"])
+        with get_tracer().span(BLOCK):
+            device_sync(inner["lnrho"] if inner is not None
+                        else self.dd.curr["lnrho"])
 
     def field(self, name: str) -> np.ndarray:
         inner = self._inner

@@ -28,10 +28,12 @@ from jax.sharding import PartitionSpec as P
 from ..distributed import DistributedDomain
 from ..geometry import Dim3, Dim3Like, Radius
 from ..local_domain import zyx_shape
-from ..ops.stencil_kernels import global_coords, jacobi7, write_interior
+from ..ops.stencil_kernels import (global_coords, jacobi7, read_interior,
+                                   write_interior)
 from ..parallel.exchange import dispatch_exchange
 from ..parallel.mesh import mesh_dim
 from ..parallel.methods import Method, pick_method
+from ..telemetry.spans import BLOCK, KERNEL, RUN, get_tracer, layer_scope
 
 HOT_TEMP = 1.0   # reference: bin/jacobi3d.cu:12
 COLD_TEMP = 0.0  # reference: bin/jacobi3d.cu:11
@@ -77,6 +79,7 @@ def jacobi_shard_step(p, radius: Radius, counts: Dim3, local: Dim3,
     return write_interior(p, new, radius)
 
 
+@layer_scope(KERNEL)
 def _apply_sources(new, origin_xyz, local: Dim3, hot_c: Dim3, cold_c: Dim3,
                    sph_r: int):
     """Re-impose the Dirichlet hot/cold spheres
@@ -93,6 +96,7 @@ def _apply_sources(new, origin_xyz, local: Dim3, hot_c: Dim3, cold_c: Dim3,
     return new
 
 
+@layer_scope(KERNEL)
 def _apply_sources_windowed(new, origin_xyz, dims: Dim3, gsize: Dim3,
                             hot_c: Dim3, cold_c: Dim3, sph_r: int,
                             nonperiodic: bool):
@@ -681,7 +685,7 @@ class Jacobi3D:
         from ..utils.config import wrap2_disabled
 
         dd = self.dd
-        lo = dd.alloc_radius.pad_lo()
+        alloc_r = dd.alloc_radius
         local = dd.local_size
         gsize = dd.size
         hot, cold, sph_r = sphere_geometry(gsize)
@@ -691,9 +695,7 @@ class Jacobi3D:
                    and not wrap2_disabled())
 
         def steps(p, n):
-            inner = lax.slice(p, (lo.z, lo.y, lo.x),
-                              (lo.z + local.z, lo.y + local.y,
-                               lo.x + local.x))
+            inner = read_interior(p, alloc_r, local)
             if pair_ok:
                 inner = lax.fori_loop(
                     0, n // N,
@@ -711,7 +713,7 @@ class Jacobi3D:
                     inner)
             # halos go stale; nothing reads them before the next
             # exchange, and temperature() reads the interior only
-            return lax.dynamic_update_slice(p, inner, (lo.z, lo.y, lo.x))
+            return write_interior(p, inner, alloc_r)
 
         self._step_n = jax.jit(steps, donate_argnums=0)
         self._step = jax.jit(
@@ -722,16 +724,14 @@ class Jacobi3D:
             # multi-step kernel as ONE pallas launch; c == 1 tail steps
             # run the single-step kernel. Interior is sliced out and
             # written back per chunk (the probe reads the padded state)
-            inner = lax.slice(p, (lo.z, lo.y, lo.x),
-                              (lo.z + local.z, lo.y + local.y,
-                               lo.x + local.x))
+            inner = read_interior(p, alloc_r, local)
             if pair_ok and c == N:
                 inner = jacobi7_wrapn_pallas(inner, hot, cold, sph_r,
                                              steps=N)
             else:
                 for _ in range(c):
                     inner = jacobi7_wrap_pallas(inner, hot, cold, sph_r)
-            return lax.dynamic_update_slice(p, inner, (lo.z, lo.y, lo.x))
+            return write_interior(p, inner, alloc_r)
 
         self._set_segment_builder(shard_advance,
                                   stride=N if pair_ok else 1)
@@ -756,16 +756,14 @@ class Jacobi3D:
         from ..parallel.exchange import shard_origin
 
         dd = self.dd
-        lo = dd.alloc_radius.pad_lo()
+        alloc_r = dd.alloc_radius
         local = dd.local_size
         rem = dd.rem
 
         def shard_steps(p, n):
             ox, oy, oz = shard_origin(local, rem)
             org = jnp.stack([oz, oy, ox]).astype(jnp.int32)
-            inner = lax.slice(p, (lo.z, lo.y, lo.x),
-                              (lo.z + local.z, lo.y + local.y,
-                               lo.x + local.x))
+            inner = read_interior(p, alloc_r, local)
             made = make_body(org)
             if isinstance(made, tuple):
                 body, group_body, gn = made
@@ -776,7 +774,7 @@ class Jacobi3D:
             else:
                 body = made
                 inner = lax.fori_loop(0, n, lambda _, q: body(q), inner)
-            return lax.dynamic_update_slice(p, inner, (lo.z, lo.y, lo.x))
+            return write_interior(p, inner, alloc_r)
 
         spec = P("z", "y", "x")
         sm = jax.shard_map(shard_steps, mesh=dd.mesh, in_specs=(spec, P()),
@@ -798,9 +796,7 @@ class Jacobi3D:
             # per chunk so the in-graph probe reads current state
             ox, oy, oz = shard_origin(local, rem)
             org = jnp.stack([oz, oy, ox]).astype(jnp.int32)
-            inner = lax.slice(p, (lo.z, lo.y, lo.x),
-                              (lo.z + local.z, lo.y + local.y,
-                               lo.x + local.x))
+            inner = read_interior(p, alloc_r, local)
             made = make_body(org)
             if isinstance(made, tuple):
                 body, group_body, gn = made
@@ -812,7 +808,7 @@ class Jacobi3D:
             else:
                 for _ in range(c):
                     inner = made(inner)
-            return lax.dynamic_update_slice(p, inner, (lo.z, lo.y, lo.x))
+            return write_interior(p, inner, alloc_r)
 
         self._set_segment_builder(shard_advance, stride=segment_stride)
 
@@ -1036,12 +1032,14 @@ class Jacobi3D:
     def run(self, iters: int) -> None:
         """``iters`` iterations in one XLA program (fori_loop — no
         per-iteration dispatch)."""
-        self.dd.curr["temp"] = self._step_n(self.dd.curr["temp"],
-                                            jnp.asarray(iters, jnp.int32))
+        with get_tracer().span(RUN, iters=iters):
+            self.dd.curr["temp"] = self._step_n(
+                self.dd.curr["temp"], jnp.asarray(iters, jnp.int32))
 
     def block(self) -> None:
         from ..utils.timers import device_sync
-        device_sync(self.dd.curr["temp"])
+        with get_tracer().span(BLOCK):
+            device_sync(self.dd.curr["temp"])
 
     def temperature(self) -> np.ndarray:
         """Global interior (z,y,x) on host."""

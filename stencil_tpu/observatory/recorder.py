@@ -105,14 +105,12 @@ class FlightRecorder:
         now."""
         spans: List[Dict] = []
         if self._tracer is not None:
-            epoch = float(getattr(self._tracer, "epoch_unix", 0.0))
             for sp in self._tracer.finished()[-self._spans_tail:]:
                 spans.append({
                     "name": sp.name, "span_id": sp.span_id,
                     "parent_id": sp.parent_id,
-                    "start": epoch + sp.start_s,
-                    "end": (epoch + sp.end_s
-                            if sp.end_s is not None else None),
+                    "start": sp.start_s,
+                    "end": sp.end_s,
                     "attrs": {k: _jsonable(v)
                               for k, v in sp.attrs.items()},
                 })

@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..geometry import Dim3
+from ..telemetry.spans import KERNEL, layer_scope
 from .pallas_stencil import default_interpret, sublane_tile_bytes
 
 ESUB = 8  # f32 sublane tile; slab row granularity
@@ -112,6 +113,7 @@ def fit_jacobi_halo_blocks(Z: int, Y: int, X: int, esub: int,
                        cap_z=block_z, cap_y=block_y).blocks()
 
 
+@layer_scope(KERNEL)
 def jacobi7_halo_pallas(interior: jnp.ndarray,
                         slabs: Dict[str, jnp.ndarray],
                         origin_zyx: jnp.ndarray,
@@ -255,6 +257,7 @@ def jacobi7_halo_pallas(interior: jnp.ndarray,
     ]
     return pl.pallas_call(
         kern,
+        name="jacobi7_halo",
         grid=(nzb, nyb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bz, by, X), lambda kz, ky: (kz, ky, 0)),
@@ -314,6 +317,7 @@ def fit_pair_halo_blocks(Z: int, Y: int, X: int, itemsize: int,
                        cap_z=16, cap_y=128).blocks()
 
 
+@layer_scope(KERNEL)
 def jacobi7_halon_pallas(interior: jnp.ndarray,
                          slabs: Dict[str, jnp.ndarray],
                          origin_zyx: jnp.ndarray,
@@ -542,6 +546,7 @@ def jacobi7_halon_pallas(interior: jnp.ndarray,
     inputs += [ylo, yhi] * n2
     return pl.pallas_call(
         kern,
+        name="jacobi7_halon",
         grid=(nzg, nyg),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bz, by, X), lambda kz, ky: (kz, ky, 0)),
@@ -849,6 +854,7 @@ def _mhd_window_plan(Z: int, Y: int, X: int, bz: int, by: int,
     return specs, inputs_for_field, select_window
 
 
+@layer_scope(KERNEL)
 def mhd_substep_halo_pallas(fields: Dict[str, jnp.ndarray],
                             w: Optional[Dict[str, jnp.ndarray]],
                             slabs: Dict[str, Dict[str, jnp.ndarray]],
@@ -952,6 +958,7 @@ def mhd_substep_halo_pallas(fields: Dict[str, jnp.ndarray],
 
     outs = pl.pallas_call(
         kern,
+        name="mhd_substep_halo",
         grid=(nzg, nyg),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -966,6 +973,7 @@ def mhd_substep_halo_pallas(fields: Dict[str, jnp.ndarray],
     return new_f, new_w
 
 
+@layer_scope(KERNEL)
 def mhd_substep01_halo_pallas(fields: Dict[str, jnp.ndarray],
                               slabs: Dict[str, Dict[str, jnp.ndarray]],
                               prm, dt_phys: float,
@@ -1045,6 +1053,7 @@ def mhd_substep01_halo_pallas(fields: Dict[str, jnp.ndarray],
 
     outs = pl.pallas_call(
         kern,
+        name="mhd_substep01_halo",
         grid=(nzg, nyg),
         in_specs=in_specs,
         out_specs=out_specs,

@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..geometry import Dim3
+from ..telemetry.spans import KERNEL, layer_scope
 from .pallas_stencil import default_interpret, sublane_tile
 
 R = 3          # stencil radius (6th order)
@@ -183,6 +184,7 @@ def _fit_blocks(Z: int, Y: int, block_z: int, block_y: int,
                        cap_z=block_z, cap_y=block_y).blocks()
 
 
+@layer_scope(KERNEL)
 def mhd_substep_wrap_pallas(fields: Dict[str, jnp.ndarray],
                             w: Optional[Dict[str, jnp.ndarray]],
                             s: int, prm, dt_phys: float,
@@ -275,6 +277,7 @@ def mhd_substep_wrap_pallas(fields: Dict[str, jnp.ndarray],
 
     outs = pl.pallas_call(
         kern,
+        name="mhd_substep_wrap",
         grid=(Z // bz, Y // by),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -344,6 +347,7 @@ def mhd_pair_update(wins: Dict[str, jnp.ndarray], prm, dtype,
     return out_f, out_w
 
 
+@layer_scope(KERNEL)
 def mhd_substep01_wrap_pallas(fields: Dict[str, jnp.ndarray],
                               prm, dt_phys: float,
                               block_z: int = 8, block_y: int = 32,
@@ -436,6 +440,7 @@ def mhd_substep01_wrap_pallas(fields: Dict[str, jnp.ndarray],
 
     outs = pl.pallas_call(
         kern,
+        name="mhd_substep01_wrap",
         grid=(Z // bz, Y // by),
         in_specs=in_specs,
         out_specs=out_specs,

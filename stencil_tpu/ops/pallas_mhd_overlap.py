@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..geometry import Dim3
+from ..telemetry.spans import KERNEL, layer_scope
 from .pallas_halo import R, _mhd_window_plan, mhd_halo_blocks
 from .pallas_mhd import compute_dtype, mhd_tile
 from .pallas_stencil import default_interpret, on_tpu
@@ -64,6 +65,7 @@ def _interpret_mode():
     return False if on_tpu() else pltpu.InterpretParams()
 
 
+@layer_scope(KERNEL)
 def mhd_substep_overlap_pallas(fields: Dict[str, jnp.ndarray],
                                w: Optional[Dict[str, jnp.ndarray]],
                                s: int, prm, dt_phys: float,
@@ -333,6 +335,7 @@ def mhd_substep_overlap_pallas(fields: Dict[str, jnp.ndarray],
 
     outs = pl.pallas_call(
         kern,
+        name="mhd_substep_overlap",
         grid=(nzg, nyg),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -357,6 +360,7 @@ def mhd_substep_overlap_pallas(fields: Dict[str, jnp.ndarray],
     return new_f, new_w, slabs
 
 
+@layer_scope(KERNEL)
 def mhd_substep_fixup_pallas(fields: Dict[str, jnp.ndarray],
                              w: Optional[Dict[str, jnp.ndarray]],
                              f_partial: Dict[str, jnp.ndarray],
@@ -497,6 +501,7 @@ def mhd_substep_fixup_pallas(fields: Dict[str, jnp.ndarray],
 
     outs = pl.pallas_call(
         kern,
+        name="mhd_substep_fixup",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

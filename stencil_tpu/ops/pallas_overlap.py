@@ -35,6 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..geometry import Dim3
+from ..telemetry.spans import KERNEL, layer_scope
 from .pallas_stencil import on_tpu
 
 # collective_id namespace distinct from parallel/pallas_exchange.py
@@ -52,6 +53,7 @@ def _interpret_mode():
     return False if on_tpu() else pltpu.InterpretParams()
 
 
+@layer_scope(KERNEL)
 def jacobi7_overlap_pallas(interior: jnp.ndarray,
                            origin_zyx: jnp.ndarray,
                            hot_c: Tuple[int, int, int],
@@ -311,6 +313,7 @@ def jacobi7_overlap_pallas(interior: jnp.ndarray,
     ]
     outs = pl.pallas_call(
         outer,
+        name="jacobi7_overlap",
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 5,

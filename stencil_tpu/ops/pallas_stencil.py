@@ -26,6 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..geometry import Dim3, Radius
+from ..telemetry.spans import KERNEL, layer_scope
 
 
 def on_tpu() -> bool:
@@ -68,6 +69,7 @@ def _plane_specs(n_planes: int, z_lo: int, yp: int, xp: int):
     return specs
 
 
+@layer_scope(KERNEL)
 def jacobi7_pallas(padded: jnp.ndarray, radius: Radius, interior: Dim3,
                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """7-point Jacobi average over a halo-padded (z,y,x) shard
@@ -94,6 +96,7 @@ def jacobi7_pallas(padded: jnp.ndarray, radius: Radius, interior: Dim3,
 
     return pl.pallas_call(
         kern,
+        name="jacobi7",
         grid=(Z,),
         in_specs=_plane_specs(3, lo.z, Yp, Xp),
         out_specs=pl.BlockSpec((1, Y, X), lambda k: (k, 0, 0)),
@@ -130,6 +133,7 @@ def _wrap_elems(esub: int, n_steps: int = 0):
     return elems
 
 
+@layer_scope(KERNEL)
 def jacobi7_wrap_pallas(interior: jnp.ndarray,
                         hot_c: Tuple[int, int, int],
                         cold_c: Tuple[int, int, int], sph_r: int,
@@ -213,6 +217,7 @@ def jacobi7_wrap_pallas(interior: jnp.ndarray,
 
     return pl.pallas_call(
         kern,
+        name="jacobi7_wrap",
         grid=(Z // bz, Y // by),
         in_specs=[
             # plane before this z block, periodic
@@ -242,6 +247,7 @@ def jacobi7_wrap_pallas(interior: jnp.ndarray,
     )(interior, interior, interior, interior, interior)
 
 
+@layer_scope(KERNEL)
 def jacobi7_wrapn_pallas(interior: jnp.ndarray,
                          hot_c: Tuple[int, int, int],
                          cold_c: Tuple[int, int, int], sph_r: int,
@@ -398,6 +404,7 @@ def jacobi7_wrapn_pallas(interior: jnp.ndarray,
                 lambda kz, ky, f=zmaps[key], g=ymap: (f(kz), g(ky), 0)))
     return pl.pallas_call(
         kern,
+        name="jacobi7_wrapn",
         grid=(Z // bz, Y // by),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bz, by, X), lambda kz, ky: (kz, ky, 0)),
@@ -428,6 +435,7 @@ _D2_C = -49.0 / 18.0
 _D2 = (3.0 / 2.0, -3.0 / 20.0, 1.0 / 90.0)
 
 
+@layer_scope(KERNEL)
 def laplace6_pallas(padded: jnp.ndarray, radius: Radius, interior: Dim3,
                     inv_ds: Tuple[float, float, float] = (1.0, 1.0, 1.0),
                     interpret: Optional[bool] = None) -> jnp.ndarray:
@@ -465,6 +473,7 @@ def laplace6_pallas(padded: jnp.ndarray, radius: Radius, interior: Dim3,
 
     return pl.pallas_call(
         kern,
+        name="laplace6",
         grid=(Z,),
         in_specs=_plane_specs(7, lo.z, Yp, Xp),
         out_specs=pl.BlockSpec((1, Y, X), lambda k: (k, 0, 0)),

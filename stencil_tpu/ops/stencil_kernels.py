@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..geometry import Dim3, Radius
+from ..telemetry.spans import INTERIOR, KERNEL, layer_scope
 
 
 def shifted(padded: jnp.ndarray, off: Tuple[int, int, int],
@@ -30,6 +31,7 @@ def shifted(padded: jnp.ndarray, off: Tuple[int, int, int],
          pad_lo.x + ox + interior.x))
 
 
+@layer_scope(KERNEL)
 def jacobi7(padded: jnp.ndarray, radius: Radius, interior: Dim3) -> jnp.ndarray:
     """7-point Jacobi average: (sum of 6 face neighbors) / 6
     (reference: bin/jacobi3d.cu:65-80)."""
@@ -43,6 +45,7 @@ def jacobi7(padded: jnp.ndarray, radius: Radius, interior: Dim3) -> jnp.ndarray:
     return acc * (1.0 / 6.0)
 
 
+@layer_scope(KERNEL)
 def laplacian27(padded: jnp.ndarray, radius: Radius, interior: Dim3,
                 weights=None) -> jnp.ndarray:
     """27-point weighted stencil (radius-1 box) — exercises edge/corner
@@ -66,6 +69,7 @@ def laplacian27(padded: jnp.ndarray, radius: Radius, interior: Dim3,
     return out
 
 
+@layer_scope(KERNEL)
 def central_diff(padded: jnp.ndarray, axis: int, radius: Radius,
                  interior: Dim3) -> jnp.ndarray:
     """Second-order central difference along grid ``axis`` (0=x, 1=y,
@@ -83,6 +87,14 @@ def central_diff(padded: jnp.ndarray, axis: int, radius: Radius,
             - shifted(padded, tuple(minus), lo, interior)) * 0.5
 
 
+@layer_scope(INTERIOR)
+def read_interior(padded: jnp.ndarray, radius: Radius,
+                  interior: Dim3) -> jnp.ndarray:
+    """The interior of a padded shard, halos dropped."""
+    return shifted(padded, (0, 0, 0), radius.pad_lo(), interior)
+
+
+@layer_scope(INTERIOR)
 def write_interior(padded: jnp.ndarray, interior_vals: jnp.ndarray,
                    radius: Radius) -> jnp.ndarray:
     """Place interior-shaped values into a padded shard (halos keep

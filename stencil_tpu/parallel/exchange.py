@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..geometry import Dim3, Radius
+from ..telemetry.spans import EXCHANGE, layer_scope
 from .methods import Method, pick_method
 
 # grid axis index -> array dimension of a (z,y,x)-ordered field
@@ -215,6 +216,7 @@ def _shard_interiors(arr, alloc_r: Radius) -> Tuple[int, int, int]:
                  for a in range(3))
 
 
+@layer_scope(EXCHANGE)
 def exchange_shard(arr: jnp.ndarray, radius: Radius,
                    mesh_counts: Dim3,
                    axis_order: Tuple[int, ...] = (0, 1, 2),
@@ -332,6 +334,7 @@ def exchange_shard(arr: jnp.ndarray, radius: Radius,
     return arr
 
 
+@layer_scope(EXCHANGE)
 def accumulate_shard(arr: jnp.ndarray, radius: Radius,
                      mesh_counts: Dim3,
                      axis_order: Tuple[int, ...] = (2, 1, 0),
@@ -402,6 +405,7 @@ def accumulate_shard(arr: jnp.ndarray, radius: Radius,
     return arr
 
 
+@layer_scope(EXCHANGE)
 def exchange_interior_slabs(p: jnp.ndarray, mesh_counts: Dim3,
                             rz: int, ry: int, radius_rows: int = 0,
                             y_z_extended: bool = False,
@@ -515,6 +519,7 @@ def exchange_interior_slabs(p: jnp.ndarray, mesh_counts: Dim3,
             "ylo": yembed(ylo_r, True), "yhi": yembed(yhi_r, False)}
 
 
+@layer_scope(EXCHANGE)
 def exchange_shard_packed(arrs: Dict[str, jnp.ndarray], radius: Radius,
                           mesh_counts: Dim3,
                           axis_order: Tuple[int, ...] = (0, 1, 2),
@@ -644,6 +649,7 @@ def exchange_shard_packed(arrs: Dict[str, jnp.ndarray], radius: Radius,
     return out
 
 
+@layer_scope(EXCHANGE)
 def exchange_shard_allgather(arr: jnp.ndarray, radius: Radius,
                              mesh_counts: Dim3,
                              axis_order: Tuple[int, ...] = (0, 1, 2)
@@ -690,6 +696,7 @@ def _single_axis_radius(radius: Radius, axis: int) -> Radius:
     return r
 
 
+@layer_scope(EXCHANGE)
 def dispatch_exchange(fields: Dict[str, jnp.ndarray], radius: Radius,
                       mesh_counts: Dim3, method: Method,
                       axis_order: Tuple[int, ...] = (0, 1, 2),

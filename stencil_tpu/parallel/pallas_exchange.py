@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..geometry import Dim3, Radius
+from ..telemetry.spans import EXCHANGE, layer_scope
 from .exchange import AXIS_NAME, AXIS_TO_DIM, exchange_shard
 
 # collective_id namespace for this module's barrier semaphores; one id
@@ -106,6 +107,7 @@ def _exchange_axis_pallas(arr: jnp.ndarray, axis: int, r_lo: int, r_hi: int,
 
     return pl.pallas_call(
         kern,
+        name="exchange_axis",
         out_shape=jax.ShapeDtypeStruct(arr.shape, arr.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -118,6 +120,7 @@ def _exchange_axis_pallas(arr: jnp.ndarray, axis: int, r_lo: int, r_hi: int,
     )(arr)
 
 
+@layer_scope(EXCHANGE)
 def exchange_shard_pallas(arr: jnp.ndarray, radius: Radius,
                           mesh_counts: Dim3,
                           axis_order: Tuple[int, ...] = (0, 1, 2),

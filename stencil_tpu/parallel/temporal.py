@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..geometry import DepthsLike, Dim3, Radius, normalize_depths
+from ..telemetry.spans import INTERIOR, layer_scope
 from .exchange import dispatch_exchange
 from .methods import Method
 
@@ -142,6 +143,7 @@ def _region_blocks(fields: Dict[str, jnp.ndarray], p_lo: Dim3,
         for q, p in fields.items()}
 
 
+@layer_scope(INTERIOR)
 def _write_region(fields: Dict[str, jnp.ndarray], p_lo: Dim3, off: Dim3,
                   outs: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
     fields = dict(fields)

@@ -19,7 +19,14 @@ The first two come from wrapping :func:`..utils.profiling.scope`
 an in-process record a service can export per run.
 
 :class:`Tracer` is thread-safe: each thread keeps its own span stack
-(``threading.local``), finished spans land in one bounded ring.
+(``threading.local``), finished spans land in one bounded ring. A
+span starts on the host clock the profiler stamps ``TraceMe`` events
+with (``CLOCK_REALTIME``, ``time.time_ns``), so an exported span and
+the profiler's event for it line up (a profile's own times are
+relative to its ``profile_start_time``, the "Task Environment"
+plane); its duration is measured on the monotonic clock.
+
+The solvers name their layers from one table, :data:`SCOPES`.
 """
 
 from __future__ import annotations
@@ -33,8 +40,63 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, Iterator, List, Optional, Sequence
 
+import jax
+
 from ..utils.profiling import scope
 from .events import new_run_id
+
+RUN = "stencil.run"
+BLOCK = "stencil.block"
+KERNEL = "stencil.kernel"
+EXCHANGE = "stencil.exchange"
+INTERIOR = "stencil.interior"
+
+#: Every name the solvers put on their layers, and what reads it.
+#: Host spans are :meth:`Tracer.span` records and ``TraceAnnotation``
+#: ranges on the profiler's host clock; the three in-graph scopes are
+#: ``jax.named_scope``s (:func:`layer_scope`) opened while a step
+#: program traces, so each compiled op under one carries the name in
+#: its ``op_name`` metadata (``tf_op`` in a profile). The copies XLA
+#: adds to carry a ``fori_loop``'s state sit under none of them.
+SCOPES: Dict[str, str] = {
+    RUN: "host span around Jacobi3D.run / Astaroth.run, attr `iters`: "
+         "the host dispatch of one chunk of steps; a profile's device "
+         "idle gaps inside it are dispatch",
+    BLOCK: "host span around block(): the host waiting for the device; "
+           "idle gaps inside it are the host waking up",
+    KERNEL: "in-graph: the stencil arithmetic — every pallas_call "
+            "(named after its function without `_pallas`), the XLA "
+            "path's jacobi7 and sources, mhd_rates and the RK update; "
+            "read by kernel_scope_ms_per_step",
+    EXCHANGE: "in-graph: halo exchange — the ppermute/all-gather "
+              "sweeps with their packing and unpacking, the slabs fed "
+              "to the halo kernels, the temporal deep exchange, the "
+              "PallasDMA kernel; read by exchange_scope_ms_per_step and "
+              "exchange_scope_exposed_ms_per_step",
+    INTERIOR: "in-graph: the interior sliced out of the padded field and "
+              "written back into it (dynamic_update_slice); read by "
+              "interior_writeback_ms_per_step",
+}
+#: the in-graph scopes; ops under none of them are read by
+#: unscoped_ms_per_step
+LAYER_SCOPES = (KERNEL, EXCHANGE, INTERIOR)
+
+@contextmanager
+def layer_scope(name: str) -> Iterator[None]:
+    """``jax.named_scope(name)`` for one of :data:`LAYER_SCOPES`, as a
+    context manager or a decorator (a fresh scope per call, so a
+    decorated function may be traced re-entrantly or from two threads).
+    Trace-time only: no run-time cost."""
+    if name not in LAYER_SCOPES:
+        raise ValueError(f"{name!r} is not one of {LAYER_SCOPES}")
+    with jax.named_scope(name):
+        yield
+
+
+def _now_s() -> float:
+    """Seconds on the profiler's host clock (``TraceMe`` stamps
+    ``CLOCK_REALTIME``)."""
+    return time.time_ns() * 1e-9
 
 
 @dataclasses.dataclass
@@ -44,7 +106,7 @@ class Span:
     name: str
     span_id: str
     parent_id: Optional[str]
-    start_s: float          # seconds since the tracer's epoch
+    start_s: float          # seconds on the profiler's host clock
     end_s: Optional[float] = None
     thread: int = 0
     attrs: Dict = dataclasses.field(default_factory=dict)
@@ -70,9 +132,8 @@ class Tracer:
         self._local = threading.local()
         self._finished: deque = deque(maxlen=int(capacity))
         self._dropped = 0
-        self._epoch = time.perf_counter()
-        #: wall-clock time of the epoch (Perfetto metadata)
-        self.epoch_unix = time.time()
+        #: when the tracer was made, on the spans' clock (metadata)
+        self.epoch_unix = _now_s()
 
     # -- recording ------------------------------------------------------
     def _stack(self) -> List[Span]:
@@ -104,14 +165,17 @@ class Tracer:
         st = self._stack()
         sp = Span(name=name, span_id=self._new_id(),
                   parent_id=st[-1].span_id if st else None,
-                  start_s=time.perf_counter() - self._epoch,
+                  start_s=_now_s(),
                   thread=threading.get_ident(), attrs=dict(attrs))
         st.append(sp)
+        t0 = time.perf_counter_ns()
         try:
             with scope(name):
                 yield sp
         finally:
-            sp.end_s = time.perf_counter() - self._epoch
+            # the duration on the monotonic clock: a step of the wall
+            # clock never makes it negative
+            sp.end_s = sp.start_s + (time.perf_counter_ns() - t0) * 1e-9
             st.pop()
             with self._lock:
                 if len(self._finished) == self._finished.maxlen:

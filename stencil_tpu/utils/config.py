@@ -34,7 +34,7 @@ REPO_COMPILE_CACHE = os.path.join(
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its
     directory ("" when off). The one place the repo's entry points
-    (chip_smoke.py, bench.py, apps, __graft_entry__.py) configure it.
+    (chip_smoke.py, apps, __graft_entry__.py) configure it.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it
     and this sets no other directory; otherwise the cache goes to

@@ -339,6 +339,107 @@ def test_span_named_scope_reaches_traced_ops():
     assert any("telemetry-span-label" in s for s in stacks), stacks
 
 
+def _spans_of(tracer, drive):
+    from stencil_tpu.telemetry import set_tracer
+
+    prev = set_tracer(tracer)
+    try:
+        with tracer.span("campaign") as root:
+            drive()
+    finally:
+        set_tracer(prev)
+    return root, tracer.finished()
+
+
+@pytest.mark.parametrize("model", ["jacobi", "astaroth"])
+def test_solver_entry_points_record_spans(model):
+    """run()/block() open their host spans under the caller's span,
+    run() with its ``iters``; opening them neither retraces the step
+    program nor puts the host span's name on its ops."""
+    import jax
+
+    from stencil_tpu.telemetry.spans import BLOCK, RUN
+
+    if model == "jacobi":
+        from stencil_tpu.models.jacobi import Jacobi3D
+        m = Jacobi3D(8, 8, 8, mesh_shape=(1, 1, 1), dtype=np.float32,
+                     devices=jax.devices()[:1], kernel="xla")
+        prog = m._step_n
+    else:
+        from stencil_tpu.models.astaroth import Astaroth
+        m = Astaroth(8, 8, 8, mesh_shape=(1, 1, 1), dtype=np.float32,
+                     devices=jax.devices()[:1], kernel="xla")
+        prog = m._iter_n
+
+    def drive():
+        m.run(3)
+        m.step()
+        m.run(2)
+        m.block()
+
+    root, spans = _spans_of(Tracer(run_id="solver"), drive)
+    runs = [s for s in spans if s.name == RUN]
+    assert [s.attrs for s in runs] == [{"iters": 3}, {"iters": 2}]
+    for name in (RUN, BLOCK):
+        got = [s for s in spans if s.name == name]
+        assert got and all(s.parent_id == root.span_id for s in got), name
+        assert all(s.end_s >= s.start_s for s in got)
+    assert sorted({s.name for s in spans}) == sorted({"campaign", RUN, BLOCK})
+    assert prog._cache_size() == 1
+
+
+def test_span_duration_survives_a_wall_clock_step():
+    """A span starts on the wall clock but is timed on the monotonic
+    one: the wall clock stepping back mid-span leaves its duration and
+    the exported trace valid."""
+    from unittest import mock
+
+    wall = iter(range(10 ** 12, 0, -10 ** 9))   # 1 s back per reading
+    t = Tracer()
+    with mock.patch("stencil_tpu.telemetry.spans.time.time_ns",
+                    lambda: next(wall)):
+        with t.span("outer"):
+            with t.span("inner"):
+                pass
+    spans = t.finished()
+    assert [s.name for s in spans] == ["inner", "outer"]
+    assert all(s.duration_s >= 0 for s in spans)
+    assert validate_chrome_trace(t.chrome_trace()) == []
+
+
+def test_span_times_match_the_profiler(tmp_path):
+    """A span's exported start and duration agree with the profiler's
+    TraceAnnotation event for it (its times are relative to the
+    profile's ``profile_start_time``) to well under 1 ms: one clock."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    t = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("stencil.block"):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    data = ProfileData.from_file(path)
+    start0 = None
+    events = []
+    for plane in data.planes:
+        if plane.name == "Task Environment":
+            start0 = {k: v for k, v in plane.stats}["profile_start_time"]
+        if plane.name.startswith("/host:"):
+            events += [e for line in plane.lines for e in line.events
+                       if e.name == "stencil.block"]
+    assert start0 is not None and len(events) == 1
+    (exported,) = t.chrome_trace()["traceEvents"]
+    assert abs((start0 + events[0].start_ns) / 1e3 - exported["ts"]) < 1000
+    assert abs(events[0].duration_ns / 1e3 - exported["dur"]) < 1000
+
+
 # ---------------------------------------------------------------------------
 # in-graph step metrics: ride the probe's one all-reduce
 

@@ -2,12 +2,16 @@
 not attached: the chip's own compiler refuses what interpret mode
 accepts (unaligned slices, more VMEM than a kernel may use), at no chip
 time. Real widths: Jacobi 512^3 and MHD 256^3 f32, as chip_smoke.py
-runs them.
+runs them. Each compiled kernel keeps its stable name (its function's
+name without ``_pallas``) under the ``stencil.kernel`` scope, which is
+how a profile tells the kernels apart.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and every xdist worker
 imports this file.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -40,12 +44,19 @@ def _spec(shape, sharding, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernel: str):
     # as deployed: 32-bit (conftest turns x64 on for the CPU suite, and
     # the kernels' index arithmetic does not lower for Mosaic in 64-bit)
     with jax.enable_x64(False):
         compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        name = re.match(r"\s*(?:ROOT )?%([\w-]+)(\.\d+)? = ", line).group(1)
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert name == kernel and name not in ("body", "kern"), line[:120]
+        assert f"/stencil.kernel/{kernel}/pallas_call" in op_name, op_name
     return compiled
 
 
@@ -60,7 +71,7 @@ def test_jacobi_wrap_pair_512(one_chip):
     hot, cold, sph_r = sphere_geometry(Dim3(n, n, n))
     _compile(lambda x: jacobi7_wrap2_pallas(x, tuple(hot), tuple(cold),
                                             sph_r, interpret=False),
-             _spec((n, n, n), one_chip))
+             _spec((n, n, n), one_chip), kernel="jacobi7_wrapn")
 
 
 @pytest.mark.parametrize("substep", [0, 1])
@@ -80,7 +91,7 @@ def test_mhd_wrap_substep_256(one_chip, substep):
         return mhd_substep_wrap_pallas(f, wk, substep, prm, prm.dt,
                                        interpret=False)
 
-    _compile(sub, fields, w)
+    _compile(sub, fields, w, kernel="mhd_substep_wrap")
 
 
 def test_jacobi_halo_pair_4chip_shard(one_chip):
@@ -112,5 +123,31 @@ def test_jacobi_halo_pair_4chip_shard(one_chip):
                                     interpret=False)
 
     _compile(shard, _spec((Z, Y, X), one_chip), slabs,
-             _spec((3,), one_chip, jnp.int32))
+             _spec((3,), one_chip, jnp.int32), kernel="jacobi7_halon")
 
+
+
+def test_jacobi_wrap_single_step(one_chip):
+    """The single-step wrap kernel (the tail of an odd run(n))."""
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.models.jacobi import sphere_geometry
+    from stencil_tpu.ops.pallas_stencil import jacobi7_wrap_pallas
+
+    n = 128
+    hot, cold, sph_r = sphere_geometry(Dim3(n, n, n))
+    _compile(lambda x: jacobi7_wrap_pallas(x, tuple(hot), tuple(cold),
+                                           sph_r, interpret=False),
+             _spec((n, n, n), one_chip), kernel="jacobi7_wrap")
+
+
+def test_mhd_wrap_pair_substep(one_chip):
+    """The fused substep-0+1 MHD wrap kernel (STENCIL_MHD_PAIR=1)."""
+    from stencil_tpu.models.astaroth import FIELDS, MhdParams
+    from stencil_tpu.ops.pallas_mhd import mhd_substep01_wrap_pallas
+
+    n = 64
+    prm = MhdParams()
+    fields = {q: _spec((n, n, n), one_chip) for q in FIELDS}
+    _compile(lambda f: mhd_substep01_wrap_pallas(f, prm, prm.dt,
+                                                 interpret=False),
+             fields, kernel="mhd_substep01_wrap")
