@@ -183,6 +183,40 @@ def _wrap_steps(tile: int, requested: int = 0) -> int:
     return min(max(n, 1), tile)
 
 
+def _chained_loop(x, count, fn):
+    """``count`` applications of ``fn`` (traced ``count``): two chained
+    calls per loop iteration, then one more under a ``cond`` when
+    ``count`` is odd. A kernel that writes a fresh output cannot write
+    the buffer it reads, and with one call per body XLA copies each
+    result back into the loop carry; with two, the first call's input
+    is dead by the second call, whose output then takes the carry's
+    buffer. Only the odd call keeps a copy."""
+    x = lax.fori_loop(0, count // 2, lambda _, q: fn(fn(q)), x)
+    return lax.cond(count % 2 == 1, fn, lambda q: q, x)
+
+
+def wrap_run_loop(inner, n, pair, single, N: int):
+    """``n`` single-chip wrap steps (traced ``n``) on the unpadded
+    interior: ``n // N`` calls of ``pair`` (an ``N``-step kernel) as
+    chained pairs of calls, then ``n % N`` calls of ``single`` — the
+    same kernel applications in the same order as one call per loop
+    iteration, so bitwise the same result. ``pair=None`` runs ``n``
+    singles, chained the same way."""
+    if pair is None:
+        return _chained_loop(inner, n, single)
+    inner = _chained_loop(inner, n // N, pair)
+    return lax.fori_loop(0, n % N, lambda _, q: single(q), inner)
+
+
+def wrap_loop_split(n: int, unit: int) -> Tuple[int, int]:
+    """``(loop_steps, tail_steps)`` of :func:`wrap_run_loop` for ``n``
+    steps when one kernel call advances ``unit`` steps: the steps the
+    copy-free two-call loop body advances, and the rest (the odd call
+    and the single-step tail)."""
+    loop = n // (2 * unit) * (2 * unit)
+    return loop, n - loop
+
+
 #: memoized overlap-kernel schedule certificates, keyed by the traced
 #: geometry AND the certifier's identity (so a monkeypatched certifier
 #: in tests is never shadowed by a cached verdict)
@@ -436,6 +470,9 @@ class Jacobi3D:
         #: temporal depth on the Pallas wrap/halo paths (2 = the pair
         #: kernel), the group depth on the XLA temporal path
         self.step_stride = 1
+        #: steps one kernel call of the wrap path's run loop advances
+        #: (0 on the other paths): sizes run()'s span attributes
+        self._loop_unit = 0
         self._segment_decline = None
         dd = self.dd
         radius = dd.radius
@@ -694,26 +731,21 @@ class Jacobi3D:
         pair_ok = (local.y % tile == 0 and N > 1
                    and not wrap2_disabled())
 
+        def single(q):
+            return jacobi7_wrap_pallas(q, hot, cold, sph_r)
+
+        def pair(q):
+            return jacobi7_wrapn_pallas(q, hot, cold, sph_r, steps=N)
+
         def steps(p, n):
             inner = read_interior(p, alloc_r, local)
-            if pair_ok:
-                inner = lax.fori_loop(
-                    0, n // N,
-                    lambda _, q: jacobi7_wrapn_pallas(q, hot, cold,
-                                                      sph_r, steps=N),
-                    inner)
-                inner = lax.fori_loop(
-                    0, n % N,
-                    lambda _, q: jacobi7_wrap_pallas(q, hot, cold, sph_r),
-                    inner)
-            else:
-                inner = lax.fori_loop(
-                    0, n,
-                    lambda _, q: jacobi7_wrap_pallas(q, hot, cold, sph_r),
-                    inner)
+            inner = wrap_run_loop(inner, n, pair if pair_ok else None,
+                                  single, N)
             # halos go stale; nothing reads them before the next
             # exchange, and temperature() reads the interior only
             return write_interior(p, inner, alloc_r)
+
+        self._loop_unit = N if pair_ok else 1
 
         self._step_n = jax.jit(steps, donate_argnums=0)
         self._step = jax.jit(
@@ -1031,8 +1063,14 @@ class Jacobi3D:
 
     def run(self, iters: int) -> None:
         """``iters`` iterations in one XLA program (fori_loop — no
-        per-iteration dispatch)."""
-        with get_tracer().span(RUN, iters=iters):
+        per-iteration dispatch). On the wrap path the span also says
+        how many of them the copy-free loop body advances
+        (``loop_steps``) and how many the tails (``tail_steps``)."""
+        attrs = {}
+        if self._loop_unit:
+            loop, tail = wrap_loop_split(iters, self._loop_unit)
+            attrs = {"loop_steps": loop, "tail_steps": tail}
+        with get_tracer().span(RUN, iters=iters, **attrs):
             self.dd.curr["temp"] = self._step_n(
                 self.dd.curr["temp"], jnp.asarray(iters, jnp.int32))
 

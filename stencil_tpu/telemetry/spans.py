@@ -61,7 +61,9 @@ INTERIOR = "stencil.interior"
 SCOPES: Dict[str, str] = {
     RUN: "host span around Jacobi3D.run / Astaroth.run, attr `iters`: "
          "the host dispatch of one chunk of steps; a profile's device "
-         "idle gaps inside it are dispatch",
+         "idle gaps inside it are dispatch. On the Jacobi wrap path also "
+         "`loop_steps` (steps in the copy-free two-call loop body) and "
+         "`tail_steps` (the rest)",
     BLOCK: "host span around block(): the host waiting for the device; "
            "idle gaps inside it are the host waking up",
     KERNEL: "in-graph: the stencil arithmetic — every pallas_call "

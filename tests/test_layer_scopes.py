@@ -76,6 +76,34 @@ def test_layers_are_named(case):
     assert RUN not in text
 
 
+def test_wrap_run_span_counts_loop_and_tail_steps():
+    """On the one-chip wrap path (pair kernel, two calls per loop
+    iteration) `stencil.run` says how many steps the copy-free loop
+    body advanced and how many the tails; other paths say neither."""
+    from stencil_tpu.models.jacobi import Jacobi3D
+    from stencil_tpu.telemetry.spans import Tracer, set_tracer
+
+    j = Jacobi3D(16, 16, 16, mesh_shape=(1, 1, 1), dtype=np.float32,
+                 devices=jax.devices()[:1], kernel="wrap")
+    x = Jacobi3D(16, 16, 16, mesh_shape=(1, 1, 1), dtype=np.float32,
+                 devices=jax.devices()[:1], kernel="xla")
+    tracer = Tracer()
+    prev = set_tracer(tracer)
+    try:
+        for iters in (32, 5, 1):
+            j.run(iters)
+        x.run(1)
+    finally:
+        set_tracer(prev)
+    attrs = [sp.attrs for sp in tracer.finished() if sp.name == RUN]
+    assert attrs == [
+        {"iters": 32, "loop_steps": 32, "tail_steps": 0},
+        {"iters": 5, "loop_steps": 4, "tail_steps": 1},
+        {"iters": 1, "loop_steps": 0, "tail_steps": 1},
+        {"iters": 1},
+    ]
+
+
 def test_layer_scope_is_a_named_scope_only_for_layers():
     @layer_scope(EXCHANGE)
     def sweep(x, depth):

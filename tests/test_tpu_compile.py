@@ -44,20 +44,36 @@ def _spec(shape, sharding, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args, kernel: str):
+def _compile(fn, *args, kernel, donate=()):
+    """Compile ``fn`` for the described chip; every custom call in it
+    must be one of ``kernel`` (a name or a tuple of names)."""
+    kernels = (kernel,) if isinstance(kernel, str) else kernel
     # as deployed: 32-bit (conftest turns x64 on for the CPU suite, and
     # the kernels' index arithmetic does not lower for Mosaic in 64-bit)
     with jax.enable_x64(False):
-        compiled = jax.jit(fn).lower(*args).compile()
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     calls = [line for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls
     for line in calls:
         name = re.match(r"\s*(?:ROOT )?%([\w-]+)(\.\d+)? = ", line).group(1)
         op_name = re.search(r'op_name="([^"]*)"', line).group(1)
-        assert name == kernel and name not in ("body", "kern"), line[:120]
-        assert f"/stencil.kernel/{kernel}/pallas_call" in op_name, op_name
+        assert name in kernels and name not in ("body", "kern"), line[:120]
+        assert f"/stencil.kernel/{name}/pallas_call" in op_name, op_name
     return compiled
+
+
+def _computations(hlo_text):
+    """Compiled HLO text split into ``{computation name: its lines}``."""
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    return comps
 
 
 def test_jacobi_wrap_pair_512(one_chip):
@@ -72,6 +88,54 @@ def test_jacobi_wrap_pair_512(one_chip):
     _compile(lambda x: jacobi7_wrap2_pallas(x, tuple(hot), tuple(cold),
                                             sph_r, interpret=False),
              _spec((n, n, n), one_chip), kernel="jacobi7_wrapn")
+
+
+def test_jacobi_wrap_run_loop_512_has_no_carry_copy(one_chip):
+    """The program Jacobi3D(512^3).run(n) compiles on one chip: interior
+    sliced out of the padded field, the wrap run loop, interior written
+    back, ``n`` traced. Its loop body chains two pair-kernel calls, so
+    XLA needs no full-grid copy of the loop carry there (with one call
+    per body it copied 512^3 floats before every call)."""
+    from stencil_tpu.geometry import Dim3, Radius
+    from stencil_tpu.models.jacobi import sphere_geometry, wrap_run_loop
+    from stencil_tpu.ops.pallas_stencil import (jacobi7_wrap_pallas,
+                                                jacobi7_wrapn_pallas)
+    from stencil_tpu.ops.stencil_kernels import read_interior, write_interior
+
+    n = 512
+    hot, cold, sph_r = sphere_geometry(Dim3(n, n, n))
+    alloc_r = Radius.constant(1)
+
+    def single(q):
+        return jacobi7_wrap_pallas(q, tuple(hot), tuple(cold), sph_r,
+                                   interpret=False)
+
+    def pair(q):
+        return jacobi7_wrapn_pallas(q, tuple(hot), tuple(cold), sph_r,
+                                    steps=2, interpret=False)
+
+    def steps(p, k):
+        inner = read_interior(p, alloc_r, Dim3(n, n, n))
+        inner = wrap_run_loop(inner, k, pair, single, 2)
+        return write_interior(p, inner, alloc_r)
+
+    compiled = _compile(steps, _spec((n + 2,) * 3, one_chip),
+                        _spec((), one_chip, jnp.int32),
+                        kernel=("jacobi7_wrapn", "jacobi7_wrap"), donate=0)
+    comps = _computations(compiled.as_text())
+    bodies = {m.group(1) for lines in comps.values() for line in lines
+              for m in [re.search(r" while\(.*\bbody=%([\w.\-]+)", line)]
+              if m}
+
+    def pair_calls(name):
+        return sum(" custom-call(" in line and "%jacobi7_wrapn" in line
+                   for line in comps[name])
+
+    assert sorted(pair_calls(b) for b in bodies if pair_calls(b)) == [2]
+    grid = re.compile(r"= f32\[512,512,512\]\S* copy\(")
+    for name, lines in comps.items():
+        if pair_calls(name) >= 2:
+            assert not any(grid.search(line) for line in lines), name
 
 
 @pytest.mark.parametrize("substep", [0, 1])
