@@ -470,9 +470,10 @@ class Jacobi3D:
         #: temporal depth on the Pallas wrap/halo paths (2 = the pair
         #: kernel), the group depth on the XLA temporal path
         self.step_stride = 1
-        #: steps one kernel call of the wrap path's run loop advances
-        #: (0 on the other paths): sizes run()'s span attributes
-        self._loop_unit = 0
+        #: run()'s ``stencil.run`` span attributes beyond ``iters``, as a
+        #: function of ``iters``: set by ``_build_wrap_step`` and
+        #: ``_build_halo_step``
+        self._run_attrs = lambda iters: {}
         self._segment_decline = None
         dd = self.dd
         radius = dd.radius
@@ -745,7 +746,13 @@ class Jacobi3D:
             # exchange, and temperature() reads the interior only
             return write_interior(p, inner, alloc_r)
 
-        self._loop_unit = N if pair_ok else 1
+        unit = N if pair_ok else 1
+
+        def run_attrs(iters):
+            loop, tail = wrap_loop_split(iters, unit)
+            return {"loop_steps": loop, "tail_steps": tail}
+
+        self._run_attrs = run_attrs
 
         self._step_n = jax.jit(steps, donate_argnums=0)
         self._step = jax.jit(
@@ -909,6 +916,20 @@ class Jacobi3D:
                  per_iter_div=N) if pair_ok
             else dict(rz=1, ry=esub, radius_rows=1, y_z_extended=False,
                       per_iter_div=1))
+        # the span says how a run(iters) splits and what it puts on the
+        # wire: one radius-N round per pair call, one 1-row round per
+        # tail step
+        group_round = self._slab_round_bytes(N, True) if pair_ok else 0
+        tail_round = self._slab_round_bytes(1, False)
+
+        def run_attrs(iters):
+            group = iters // N * N if pair_ok else 0
+            tail = iters - group
+            return {"group_steps": group, "tail_steps": tail,
+                    "wire_bytes": group // N * group_round
+                    + tail * tail_round}
+
+        self._run_attrs = run_attrs
 
         def make_body(org):
             lens = jnp.stack([
@@ -991,23 +1012,17 @@ class Jacobi3D:
         ``exchange_bytes_total`` convention bench_exchange prints) and
         the exchange rounds per iteration (temporal blocking amortizes
         rounds below 1)."""
-        from ..parallel.exchange import interior_slab_bytes
-
-        counts = mesh_dim(self.dd.mesh)
-        local = self.dd.local_size
         path = self.kernel_path
         if path == "wrap":
             return {"path": path, "bytes_per_iteration": 0,
                     "rounds_per_iteration": 0.0}
         cfg = getattr(self, "_slab_exchange_cfg", None)
         if cfg is not None and path in ("halo", "overlap"):
-            per_shard = interior_slab_bytes(
-                (local.z, local.y, local.x), counts, cfg["radius_rows"],
-                jnp.dtype(self._dtype).itemsize, cfg["y_z_extended"])
-            n = counts.flatten()
             return {"path": path,
                     "bytes_per_iteration":
-                        per_shard * n / cfg["per_iter_div"],
+                        self._slab_round_bytes(cfg["radius_rows"],
+                                               cfg["y_z_extended"])
+                        / cfg["per_iter_div"],
                     "rounds_per_iteration": 1.0 / cfg["per_iter_div"]}
         d = self.dd.exchange_depths
         s = self.dd.exchange_every
@@ -1024,6 +1039,19 @@ class Jacobi3D:
                 "bytes_per_iteration":
                     float(self.dd.exchange_bytes_amortized_per_step()),
                 "rounds_per_iteration": rounds}
+
+    def _slab_round_bytes(self, radius_rows: int,
+                          y_z_extended: bool) -> int:
+        """Wire bytes of one ``exchange_interior_slabs`` round over the
+        whole mesh (``radius_rows`` rows a face)."""
+        from ..parallel.exchange import interior_slab_bytes
+
+        counts = mesh_dim(self.dd.mesh)
+        local = self.dd.local_size
+        return interior_slab_bytes(
+            (local.z, local.y, local.x), counts, radius_rows,
+            jnp.dtype(self._dtype).itemsize, y_z_extended) \
+            * counts.flatten()
 
     def measure_exchange_seconds(self, reps: int = 10) -> float:
         """Estimated exchange seconds per ITERATION of the built path,
@@ -1065,12 +1093,12 @@ class Jacobi3D:
         """``iters`` iterations in one XLA program (fori_loop — no
         per-iteration dispatch). On the wrap path the span also says
         how many of them the copy-free loop body advances
-        (``loop_steps``) and how many the tails (``tail_steps``)."""
-        attrs = {}
-        if self._loop_unit:
-            loop, tail = wrap_loop_split(iters, self._loop_unit)
-            attrs = {"loop_steps": loop, "tail_steps": tail}
-        with get_tracer().span(RUN, iters=iters, **attrs):
+        (``loop_steps``) and how many the tails (``tail_steps``); on the
+        halo path how many the pair-kernel calls advance
+        (``group_steps``), the single-step tail (``tail_steps``) and
+        the bytes the chunk puts on the wire, whole mesh
+        (``wire_bytes``)."""
+        with get_tracer().span(RUN, iters=iters, **self._run_attrs(iters)):
             self.dd.curr["temp"] = self._step_n(
                 self.dd.curr["temp"], jnp.asarray(iters, jnp.int32))
 

@@ -63,7 +63,11 @@ SCOPES: Dict[str, str] = {
          "the host dispatch of one chunk of steps; a profile's device "
          "idle gaps inside it are dispatch. On the Jacobi wrap path also "
          "`loop_steps` (steps in the copy-free two-call loop body) and "
-         "`tail_steps` (the rest)",
+         "`tail_steps` (the rest). On the Jacobi halo path also "
+         "`group_steps` (steps the pair-kernel calls advance), "
+         "`tail_steps` (the single-step tail) and `wire_bytes` (bytes "
+         "the chunk's slab exchanges put on the interconnect, summed "
+         "over the mesh)",
     BLOCK: "host span around block(): the host waiting for the device; "
            "idle gaps inside it are the host waking up",
     KERNEL: "in-graph: the stencil arithmetic — every pallas_call "
