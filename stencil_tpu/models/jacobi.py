@@ -195,22 +195,22 @@ def _chained_loop(x, count, fn):
     return lax.cond(count % 2 == 1, fn, lambda q: q, x)
 
 
-def wrap_run_loop(inner, n, pair, single, N: int):
-    """``n`` single-chip wrap steps (traced ``n``) on the unpadded
-    interior: ``n // N`` calls of ``pair`` (an ``N``-step kernel) as
-    chained pairs of calls, then ``n % N`` calls of ``single`` — the
-    same kernel applications in the same order as one call per loop
-    iteration, so bitwise the same result. ``pair=None`` runs ``n``
-    singles, chained the same way."""
-    if pair is None:
-        return _chained_loop(inner, n, single)
-    inner = _chained_loop(inner, n // N, pair)
-    return lax.fori_loop(0, n % N, lambda _, q: single(q), inner)
+def run_loop(inner, n, pair, single, N: int):
+    """``n`` Jacobi steps (traced ``n``) on an unpadded interior, the
+    run loop of every Pallas path (wrap, halo, overlap): ``n // N``
+    calls of ``pair`` (an ``N``-step kernel), then ``n % N`` calls of
+    ``single``, each run as chained pairs of calls — the same kernel
+    applications in the same order as one call per loop iteration, so
+    bitwise the same result. ``pair=None`` runs ``n`` singles."""
+    if pair is not None:
+        inner = _chained_loop(inner, n // N, pair)
+        n = n % N
+    return _chained_loop(inner, n, single)
 
 
-def wrap_loop_split(n: int, unit: int) -> Tuple[int, int]:
-    """``(loop_steps, tail_steps)`` of :func:`wrap_run_loop` for ``n``
-    steps when one kernel call advances ``unit`` steps: the steps the
+def loop_split(n: int, unit: int) -> Tuple[int, int]:
+    """``(loop_steps, tail_steps)`` of :func:`run_loop` for ``n`` steps
+    when one kernel call advances ``unit`` steps: the steps the
     copy-free two-call loop body advances, and the rest (the odd call
     and the single-step tail)."""
     loop = n // (2 * unit) * (2 * unit)
@@ -740,8 +740,8 @@ class Jacobi3D:
 
         def steps(p, n):
             inner = read_interior(p, alloc_r, local)
-            inner = wrap_run_loop(inner, n, pair if pair_ok else None,
-                                  single, N)
+            inner = run_loop(inner, n, pair if pair_ok else None,
+                             single, N)
             # halos go stale; nothing reads them before the next
             # exchange, and temperature() reads the interior only
             return write_interior(p, inner, alloc_r)
@@ -749,7 +749,7 @@ class Jacobi3D:
         unit = N if pair_ok else 1
 
         def run_attrs(iters):
-            loop, tail = wrap_loop_split(iters, unit)
+            loop, tail = loop_split(iters, unit)
             return {"loop_steps": loop, "tail_steps": tail}
 
         self._run_attrs = run_attrs
@@ -783,10 +783,10 @@ class Jacobi3D:
                                        Optional[str] = None) -> None:
         """Shared scaffolding for the interior-resident multi-device
         builders: slice the unpadded interior out of the padded shard,
-        fori_loop the per-iteration body from ``make_body(org)``, write
-        the interior back (halos go stale; nothing reads them before
-        the next exchange, and temperature() reads the interior only),
-        all inside one shard_map/jit with buffer donation.
+        run the body from ``make_body(org)`` through :func:`run_loop`,
+        write the interior back (halos go stale; nothing reads them
+        before the next exchange, and temperature() reads the interior
+        only), all inside one shard_map/jit with buffer donation.
 
         ``make_body(org)`` returns either a single-iteration body, or a
         ``(body, group_body, group_n)`` tuple — then ``n`` iterations
@@ -806,13 +806,9 @@ class Jacobi3D:
             made = make_body(org)
             if isinstance(made, tuple):
                 body, group_body, gn = made
-                inner = lax.fori_loop(0, n // gn,
-                                      lambda _, q: group_body(q), inner)
-                inner = lax.fori_loop(0, n % gn,
-                                      lambda _, q: body(q), inner)
+                inner = run_loop(inner, n, group_body, body, gn)
             else:
-                body = made
-                inner = lax.fori_loop(0, n, lambda _, q: body(q), inner)
+                inner = run_loop(inner, n, None, made, 1)
             return write_interior(p, inner, alloc_r)
 
         spec = P("z", "y", "x")
@@ -921,13 +917,15 @@ class Jacobi3D:
         # tail step
         group_round = self._slab_round_bytes(N, True) if pair_ok else 0
         tail_round = self._slab_round_bytes(1, False)
+        unit = N if pair_ok else 1
 
         def run_attrs(iters):
             group = iters // N * N if pair_ok else 0
             tail = iters - group
             return {"group_steps": group, "tail_steps": tail,
                     "wire_bytes": group // N * group_round
-                    + tail * tail_round}
+                    + tail * tail_round,
+                    "loop_steps": loop_split(iters, unit)[0]}
 
         self._run_attrs = run_attrs
 
@@ -1091,13 +1089,13 @@ class Jacobi3D:
 
     def run(self, iters: int) -> None:
         """``iters`` iterations in one XLA program (fori_loop — no
-        per-iteration dispatch). On the wrap path the span also says
-        how many of them the copy-free loop body advances
-        (``loop_steps``) and how many the tails (``tail_steps``); on the
-        halo path how many the pair-kernel calls advance
-        (``group_steps``), the single-step tail (``tail_steps``) and
-        the bytes the chunk puts on the wire, whole mesh
-        (``wire_bytes``)."""
+        per-iteration dispatch). On the wrap and halo paths the span
+        also says how many of them the copy-free loop body advances
+        (``loop_steps``). On the wrap path ``tail_steps`` counts the
+        rest; on the halo path ``group_steps`` counts the steps the
+        pair-kernel calls advance, ``tail_steps`` the single-step tail
+        and ``wire_bytes`` the bytes the chunk puts on the wire, whole
+        mesh."""
         with get_tracer().span(RUN, iters=iters, **self._run_attrs(iters)):
             self.dd.curr["temp"] = self._step_n(
                 self.dd.curr["temp"], jnp.asarray(iters, jnp.int32))

@@ -61,9 +61,10 @@ INTERIOR = "stencil.interior"
 SCOPES: Dict[str, str] = {
     RUN: "host span around Jacobi3D.run / Astaroth.run, attr `iters`: "
          "the host dispatch of one chunk of steps; a profile's device "
-         "idle gaps inside it are dispatch. On the Jacobi wrap path also "
-         "`loop_steps` (steps in the copy-free two-call loop body) and "
-         "`tail_steps` (the rest). On the Jacobi halo path also "
+         "idle gaps inside it are dispatch. On the Jacobi wrap and halo "
+         "paths also `loop_steps` (steps in the copy-free two-call loop "
+         "body of models.jacobi.run_loop). On the wrap path also "
+         "`tail_steps` (the rest). On the halo path also "
          "`group_steps` (steps the pair-kernel calls advance), "
          "`tail_steps` (the single-step tail) and `wire_bytes` (bytes "
          "the chunk's slab exchanges put on the interconnect, summed "

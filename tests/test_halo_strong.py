@@ -151,22 +151,25 @@ def compiled_text(fn, *args):
 
 
 def test_halo_span_and_exchange_stats_match_the_compiled_program():
-    """Per shard, the pair-kernel loop body receives two radius-2 z slabs
-    a call (2 steps) and the tail body two 1-row slabs a step; no other
-    collective runs. ``exchange_stats()`` prices the steady state from
-    the first, and each chunk's ``stencil.run`` span splits its steps
-    and counts its bytes from both."""
+    """Per shard, a pair call (2 steps) receives two radius-2 z slabs and
+    a single-step call two 1-row slabs; the run loop chains two calls an
+    iteration, so its pair-kernel body receives two such rounds and its
+    tail body two 1-row rounds; no other collective runs.
+    ``exchange_stats()`` prices the steady state from the pair call,
+    and each chunk's ``stencil.run`` span splits its steps and counts
+    its bytes from both calls."""
     j = solver()
     text = compiled_text(j._step_n, j.dd.curr["temp"],
                          jnp.asarray(32, jnp.int32))
     bodies = sorted(loop_bodies(text).values(), key=lambda b: -b[0])
     assert [k for _, k in bodies] == [{"jacobi7_halon"}, {"jacobi7_halo"}]
     (pair_bytes, _), (single_bytes, _) = bodies
-    assert pair_bytes == 2 * 2 * Y * X * 4
-    assert single_bytes == 2 * 1 * Y * X * 4
+    assert pair_bytes == 2 * (2 * 2 * Y * X * 4)
+    assert single_bytes == 2 * (2 * 1 * Y * X * 4)
+    pair_round, single_round = pair_bytes // 2, single_bytes // 2
 
     stats = j.exchange_stats()
-    assert stats["bytes_per_iteration"] == pair_bytes / 2 * CHIPS
+    assert stats["bytes_per_iteration"] == pair_round / 2 * CHIPS
     assert stats["rounds_per_iteration"] == 0.5
 
     tracer = Tracer()
@@ -181,9 +184,9 @@ def test_halo_span_and_exchange_stats_match_the_compiled_program():
     for iters in (32, 5, 1):
         group, tail = iters // 2 * 2, iters % 2
         want.append({"iters": iters, "group_steps": group,
-                     "tail_steps": tail,
-                     "wire_bytes": (group // 2 * pair_bytes
-                                    + tail * single_bytes) * CHIPS})
+                     "tail_steps": tail, "loop_steps": iters // 4 * 4,
+                     "wire_bytes": (group // 2 * pair_round
+                                    + tail * single_round) * CHIPS})
     assert got == want
     assert got[0]["wire_bytes"] == stats["bytes_per_iteration"] * 32
 

@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
+    """The described v5e 2x2, for the whole module."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -32,12 +33,16 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         cc.reset_cache()
         try:
-            topo = topologies.get_topology_desc(platform="tpu",
-                                                topology_name="v5e:2x2")
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topologies.get_topology_desc(platform="tpu",
+                                               topology_name="v5e:2x2")
         finally:
             jax.config.update("jax_enable_compilation_cache", prior)
             cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(shape, sharding, dtype=jnp.float32):
@@ -76,6 +81,21 @@ def _computations(hlo_text):
     return comps
 
 
+def _while_bodies(compiled):
+    """``(computations, names of the while loops' bodies)``."""
+    comps = _computations(compiled.as_text())
+    bodies = {m.group(1) for lines in comps.values() for line in lines
+              for m in [re.search(r" while\(.*\bbody=%([\w.\-]+)", line)]
+              if m}
+    return comps, bodies
+
+
+def _calls(lines, kernel):
+    """Custom calls of Pallas kernel ``kernel`` among ``lines``."""
+    call = re.compile(rf"%{kernel}(\.\d+)? = .* custom-call\(")
+    return sum(bool(call.search(line)) for line in lines)
+
+
 def test_jacobi_wrap_pair_512(one_chip):
     """The kernel Jacobi3D(512, 512, 512, kernel='auto') runs on one
     chip: the wrap pair (two steps per HBM pass)."""
@@ -97,7 +117,7 @@ def test_jacobi_wrap_run_loop_512_has_no_carry_copy(one_chip):
     XLA needs no full-grid copy of the loop carry there (with one call
     per body it copied 512^3 floats before every call)."""
     from stencil_tpu.geometry import Dim3, Radius
-    from stencil_tpu.models.jacobi import sphere_geometry, wrap_run_loop
+    from stencil_tpu.models.jacobi import run_loop, sphere_geometry
     from stencil_tpu.ops.pallas_stencil import (jacobi7_wrap_pallas,
                                                 jacobi7_wrapn_pallas)
     from stencil_tpu.ops.stencil_kernels import read_interior, write_interior
@@ -116,20 +136,16 @@ def test_jacobi_wrap_run_loop_512_has_no_carry_copy(one_chip):
 
     def steps(p, k):
         inner = read_interior(p, alloc_r, Dim3(n, n, n))
-        inner = wrap_run_loop(inner, k, pair, single, 2)
+        inner = run_loop(inner, k, pair, single, 2)
         return write_interior(p, inner, alloc_r)
 
     compiled = _compile(steps, _spec((n + 2,) * 3, one_chip),
                         _spec((), one_chip, jnp.int32),
                         kernel=("jacobi7_wrapn", "jacobi7_wrap"), donate=0)
-    comps = _computations(compiled.as_text())
-    bodies = {m.group(1) for lines in comps.values() for line in lines
-              for m in [re.search(r" while\(.*\bbody=%([\w.\-]+)", line)]
-              if m}
+    comps, bodies = _while_bodies(compiled)
 
     def pair_calls(name):
-        return sum(" custom-call(" in line and "%jacobi7_wrapn" in line
-                   for line in comps[name])
+        return _calls(comps[name], "jacobi7_wrapn")
 
     assert sorted(pair_calls(b) for b in bodies if pair_calls(b)) == [2]
     grid = re.compile(r"= f32\[512,512,512\]\S* copy\(")
@@ -188,6 +204,78 @@ def test_jacobi_halo_pair_4chip_shard(one_chip):
 
     _compile(shard, _spec((Z, Y, X), one_chip), slabs,
              _spec((3,), one_chip, jnp.int32), kernel="jacobi7_halon")
+
+
+def test_jacobi_halo_run_loop_4chip_has_no_carry_copy(topo):
+    """The program Jacobi3D(512^3).run(n) compiles on four chips as the
+    halo path builds it: mesh (1, 1, 4), each 128 x 512 x 512 interior
+    sliced out of its padded shard, radius-2 slab exchange and the halo
+    pair kernel through the shared run loop, interior written back,
+    ``n`` traced, the field donated. The loop body chains two pair
+    calls, so XLA needs no copy of the shard to carry the loop (with one
+    call per body it copied the shard before every call)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from stencil_tpu.geometry import Dim3, Radius
+    from stencil_tpu.models.jacobi import run_loop, sphere_geometry
+    from stencil_tpu.ops.pallas_halo import (fit_pair_halo_blocks,
+                                             jacobi7_halo_pallas,
+                                             jacobi7_halon_pallas)
+    from stencil_tpu.ops.stencil_kernels import read_interior, write_interior
+    from stencil_tpu.parallel.exchange import (exchange_interior_slabs,
+                                               shard_origin)
+    from stencil_tpu.parallel.mesh import make_mesh
+    from stencil_tpu.partition import partition_dims_even_xfree
+
+    n, chips, steps, tile = 512, 4, 2, 8
+    gsize = Dim3(n, n, n)
+    counts = partition_dims_even_xfree(gsize, chips)
+    assert tuple(counts) == (1, 1, 4)
+    local = Dim3(n // counts.x, n // counts.y, n // counts.z)
+    bz, by = fit_pair_halo_blocks(local.z, local.y, local.x, 4, steps)
+    hot, cold, sph_r = sphere_geometry(gsize)
+    alloc_r = Radius.constant(1)
+    mesh = make_mesh(counts, topo.devices[:chips])
+
+    def shard_steps(p, k):
+        ox, oy, oz = shard_origin(local, Dim3(0, 0, 0))
+        org = jnp.stack([oz, oy, ox]).astype(jnp.int32)
+
+        def single(q):
+            slabs = exchange_interior_slabs(q, counts, rz=1, ry=tile)
+            return jacobi7_halo_pallas(q, slabs, org, tuple(hot),
+                                       tuple(cold), sph_r, interpret=False)
+
+        def pair(q):
+            slabs = exchange_interior_slabs(q, counts, rz=bz, ry=tile,
+                                            radius_rows=steps,
+                                            y_z_extended=True)
+            return jacobi7_halon_pallas(q, slabs, org, (n, n, n),
+                                        tuple(hot), tuple(cold), sph_r,
+                                        steps=steps, block_z=bz,
+                                        block_y=by, interpret=False)
+
+        inner = read_interior(p, alloc_r, local)
+        inner = run_loop(inner, k, pair, single, steps)
+        return write_interior(p, inner, alloc_r)
+
+    spec = P("z", "y", "x")
+    program = jax.shard_map(shard_steps, mesh=mesh, in_specs=(spec, P()),
+                            out_specs=spec, check_vma=False)
+    field = (counts.z * (local.z + 2), local.y + 2, local.x + 2)
+    compiled = _compile(program,
+                        _spec(field, NamedSharding(mesh, spec)),
+                        _spec((), NamedSharding(mesh, P()), jnp.int32),
+                        kernel=("jacobi7_halon", "jacobi7_halo"), donate=0)
+    comps, bodies = _while_bodies(compiled)
+
+    def pair_calls(name):
+        return _calls(comps[name], "jacobi7_halon")
+
+    assert sorted(pair_calls(b) for b in bodies if pair_calls(b)) == [2]
+    shard = re.compile(r"= f32\[128,512,512\]\S* copy\(")
+    for name in bodies:
+        assert not any(shard.search(line) for line in comps[name]), name
 
 
 
