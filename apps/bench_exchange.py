@@ -15,8 +15,8 @@ costs. The amortized byte model (the same source the static analyzer
 cross-checks against HLO) is printed next to the measured numbers;
 ``--json-out`` archives the comparison (the CI bench-smoke artifact).
 
-Only ``csv_line`` rows go to stdout (scripts/run_campaign.sh captures
-stdout as the CSV artifact); the sweep commentary goes to stderr.
+Only ``csv_line`` rows go to stdout (the CSV artifact); the sweep
+commentary goes to stderr.
 """
 
 import argparse

@@ -9,7 +9,6 @@ bin/jacobi3d.cu:181-205); CSV result line
 """
 
 import argparse
-import os
 import sys
 
 from _common import (KERNEL_CHOICES, add_bench_record_flags,
@@ -76,10 +75,6 @@ def main() -> None:
     ap.add_argument("--period", type=int, default=0,
                     help="paraview dump every N samples")
     add_dtype_flags(ap)
-    ap.add_argument("--wrap-steps", type=int, default=0, metavar="N",
-                    help="temporal-blocking depth for the fused wrap "
-                         "and halo paths (N fused iterations per HBM "
-                         "pass / exchange; default 2)")
     ap.add_argument("--kernel", default="auto", choices=KERNEL_CHOICES,
                     help="compute path: fused Pallas (wrap: single-chip "
                          "periodic; halo: multi-chip slab layout), XLA "
@@ -156,8 +151,6 @@ def main() -> None:
     gx, gy, gz = (args.x * mesh_shape.x, args.y * mesh_shape.y,
                   args.z * mesh_shape.z)
     methods = methods_from_args(args)
-    if args.wrap_steps:
-        os.environ["STENCIL_WRAP_STEPS"] = str(args.wrap_steps)
     j = Jacobi3D(gx, gy, gz, mesh_shape=mesh_shape,
                  dtype=dtype,
                  methods=methods,

@@ -2294,20 +2294,6 @@ def _jacobi7_plane_vmem_spec(side: int = 8) -> VmemSpec:
     return VmemSpec(fn=fn, args=(_f32((g, g, g)),))
 
 
-def _laplace6_vmem_spec(side: int = 8) -> VmemSpec:
-    from ..geometry import Dim3, Radius
-    from ..ops.pallas_stencil import laplace6_pallas
-
-    radius = Radius.constant(3)
-    interior = Dim3(side, side, side)
-    g = side + 6
-
-    def fn(p):
-        return laplace6_pallas(p, radius, interior, interpret=False)
-
-    return VmemSpec(fn=fn, args=(_f32((g, g, g)),))
-
-
 def _jacobi_wrap_vmem_spec(steps: int, side: int = 16) -> VmemSpec:
     from ..ops.pallas_stencil import (jacobi7_wrap_pallas,
                                       jacobi7_wrapn_pallas)
@@ -2516,8 +2502,6 @@ def _tiling_targets() -> List[Target]:
 
     vmem_backed("ops.pallas_stencil.jacobi7_pallas",
                 _jacobi7_plane_vmem_spec)
-    vmem_backed("ops.pallas_stencil.laplace6_pallas",
-                _laplace6_vmem_spec)
     vmem_backed("ops.pallas_stencil.jacobi7_wrap_pallas",
                 lambda s: _jacobi_wrap_vmem_spec(1, s))
     vmem_backed("ops.pallas_stencil.jacobi7_wrapn_pallas[n=2]",
@@ -2560,8 +2544,6 @@ _TILING_SIDES = (256, 512)
 #: change this table in review
 _TILING_EXPECT = {
     "ops.pallas_stencil.jacobi7_pallas": {256: "legal", 512: "legal"},
-    "ops.pallas_stencil.laplace6_pallas": {256: "legal",
-                                           512: "infeasible"},
     "ops.pallas_stencil.jacobi7_wrap_pallas": {256: "legal",
                                                512: "legal"},
     "ops.pallas_stencil.jacobi7_wrapn_pallas[n=2]": {256: "legal",
@@ -3042,8 +3024,6 @@ def default_targets() -> List[Target]:
                    lambda: _vmem_from_kernel(_jacobi_halo_kernel_spec)),
         VmemTarget("ops.pallas_stencil.jacobi7_pallas",
                    _jacobi7_plane_vmem_spec),
-        VmemTarget("ops.pallas_stencil.laplace6_pallas",
-                   _laplace6_vmem_spec),
         VmemTarget("ops.pallas_stencil.jacobi7_wrap_pallas",
                    lambda: _jacobi_wrap_vmem_spec(1)),
         VmemTarget("ops.pallas_stencil.jacobi7_wrapn_pallas[n=2]",

@@ -37,7 +37,6 @@ Design notes vs the reference:
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Dict, Optional, Sequence
 
 import jax
@@ -50,6 +49,7 @@ from ..distributed import DistributedDomain
 from ..geometry import Dim3, Dim3Like, Radius
 from ..local_domain import zyx_shape
 from ..ops.fd6 import RADIUS, FieldData
+from ..ops.pallas_mhd import mhd_tile
 from ..ops.stencil_kernels import read_interior, write_interior
 from ..parallel.exchange import dispatch_exchange
 from ..parallel.mesh import mesh_dim
@@ -58,6 +58,9 @@ from ..telemetry.spans import BLOCK, KERNEL, RUN, get_tracer, layer_scope
 from ..utils.config import load_config
 
 FIELDS = ("lnrho", "uux", "uuy", "uuz", "ax", "ay", "az", "ss")
+
+#: (block_z, block_y) ceilings of the MHD Pallas kernels' block planner
+_BLOCK_CAPS = (8, 32)
 
 # Williamson (1980) low-storage RK3 (reference: integration.cuh:20-21)
 RK3_ALPHA = (0.0, -5.0 / 9.0, -153.0 / 128.0)
@@ -216,6 +219,35 @@ def mhd_rates(f: Dict[str, FieldData], prm: MhdParams, dtype):
 
     return {"lnrho": d_lnrho, "uux": d_uu[0], "uuy": d_uu[1], "uuz": d_uu[2],
             "ax": d_aa[0], "ay": d_aa[1], "az": d_aa[2], "ss": d_ss}
+
+
+def resident_loop(substep, pair=None):
+    """``loop(inner, w, n)``: ``n`` RK3 iterations (traced ``n``) of an
+    interior-resident Pallas path on unpadded fields.
+    ``substep(f, w, s, write_w=True)`` runs RK substep ``s`` and returns
+    ``(f, w)``; ``pair(f)``, when given, runs the fused substep-0+1
+    kernel in place of substeps 0 and 1.
+
+    Dead-w elision: substep 0 never reads w (alpha_0 == 0, w=None) and
+    nothing reads substep 2's w (the next iteration restarts at
+    alpha_0 == 0; write_w=False) — the carry keeps the last WRITTEN w
+    so the fori_loop structure is stable. Saves a full 8-field read +
+    write sweep per iteration vs the reference's unconditional w
+    traffic (astaroth/kernels.cu:63-90)."""
+    def body(_, fw):
+        f, wk = fw
+        if pair is not None:
+            f, wk = pair(f)
+        else:
+            f, wk = substep(f, None, 0)
+            f, wk = substep(f, wk, 1)
+        f, _ = substep(f, wk, 2, write_w=False)
+        return f, wk
+
+    def loop(inner, w, n):
+        return lax.fori_loop(0, n, body, (inner, w))
+
+    return loop
 
 
 class Astaroth:
@@ -417,7 +449,6 @@ class Astaroth:
         # single-chip fast path: the fused Pallas "solve" megakernel
         # with periodic wrap in-kernel (ops/pallas_mhd.py) — ~25x the
         # slicing formulation at 256^3
-        from ..ops.pallas_mhd import mhd_tile
         tile = mhd_tile(self._dtype)
         aligned_t = (rem == Dim3(0, 0, 0)
                      and local.z % tile == 0 and local.y % tile == 0)
@@ -447,19 +478,9 @@ class Astaroth:
             path); auto declines to the next path LOUDLY instead — the
             same catch-and-fall-back the Jacobi pair path got."""
             from ..analysis.tiling import TilingInfeasibleError
-            from ..ops.pallas_halo import mhd_halo_blocks
-            from ..ops.pallas_mhd import _fit_blocks
 
-            blk_z, blk_y = (getattr(self, "_halo_blocks", None)
-                            or (8, 32))
-            isz = np.dtype(self._dtype).itemsize
             try:
-                if path == "wrap":
-                    _fit_blocks(local.z, local.y, blk_z, blk_y, tile,
-                                X=local.x, itemsize=isz)
-                else:
-                    mhd_halo_blocks(local.z, local.y, blk_z, blk_y,
-                                    tile, X=local.x, itemsize=isz)
+                self._fast_path_blocks(path)
                 return True
             except TilingInfeasibleError as e:
                 from ..utils.logging import LOG_WARN
@@ -474,7 +495,7 @@ class Astaroth:
                         and _blocks_feasible("halo"))):
                 from ..utils.logging import LOG_INFO
                 self.kernel_path = "halo-overlap"
-                self._build_halo_overlap_step()
+                self._build_resident_step()
                 LOG_INFO("astaroth kernel path: halo-overlap "
                          "(in-kernel RDMA)")
                 return
@@ -509,7 +530,7 @@ class Astaroth:
                     f"multiples of the dtype sublane tile ({tile}), "
                     "overlap off")
             self.kernel_path = "wrap"
-            self._build_wrap_step()
+            self._build_resident_step()
             return
         if kernel == "halo":
             if not halo_ok:
@@ -519,7 +540,7 @@ class Astaroth:
                     f"({tile}), overlap off, periodic boundaries, "
                     "exchange_every <= 2")
             self.kernel_path = "halo"
-            self._build_halo_step()
+            self._build_resident_step()
             return
         if s_every > 1:
             self.kernel_path = (f"xla-temporal[s={s_every}]"
@@ -761,286 +782,149 @@ class Astaroth:
 
         self._set_segment_builder(advance_iters, stride=iters_per_period)
 
-    def _build_wrap_step(self) -> None:
-        """Single-chip fused substeps on interior views (see
-        ops/pallas_mhd.mhd_substep_wrap_pallas).
+    def _fast_path_blocks(self, path: str):
+        """(bz, by) the MHD Pallas kernels of ``path`` tile this shard
+        with: the wrap planner's on one chip, the halo planner's on the
+        halo paths. Raises ``TilingInfeasibleError`` when none fits."""
+        if path == "wrap":
+            from ..ops.pallas_mhd import _fit_blocks as fit
+        else:
+            from ..ops.pallas_halo import mhd_halo_blocks as fit
+        local = self.dd.local_size
+        return fit(local.z, local.y, *_BLOCK_CAPS, mhd_tile(self._dtype),
+                   X=local.x, itemsize=self._dtype.itemsize)
+
+    def _build_resident_step(self) -> None:
+        """The interior-resident MHD Pallas fast paths (``kernel_path``
+        wrap, halo or halo-overlap): fused substeps on interior views,
+        one Pallas megakernel per RK substep.
+
+        * wrap — single chip, periodic wrap in-kernel
+          (ops/pallas_mhd.mhd_substep_wrap_pallas);
+        * halo — multi-device: thin slab ppermutes feed the halo
+          megakernel (ops/pallas_halo.py), so an N-chip mesh keeps
+          single-chip per-chip throughput (the reference's fused solve
+          kernel at every scale, astaroth/astaroth.cu:552-646);
+        * halo-overlap — per substep ONE Pallas kernel issues the slab
+          RDMA and computes the interior behind the in-flight DMAs,
+          then thin strip kernels recompute the shard-edge blocks from
+          the landed slabs (ops/pallas_mhd_overlap.py; the reference's
+          interior/exchange/exterior choreography).
 
         Extract / substep-loop / insert are three SEPARATE jitted
-        programs: composing them into one jit makes XLA schedule the
-        Pallas loop an order of magnitude slower (measured 3.5s vs
+        programs (shard_map'ped on the halo paths, plain ``jax.jit`` on
+        the wrap path): composing them into one jit makes XLA schedule
+        the Pallas loop an order of magnitude slower (measured 3.5s vs
         ~110ms per iteration at 256^3), while the split pieces run at
-        full speed."""
-        from ..ops.pallas_mhd import mhd_substep_wrap_pallas
+        full speed. ``exchange_every == 2`` runs the fused substep-0+1
+        kernel (one HBM pass, and on the halo paths one radius-2R
+        exchange, for two of the three RK substeps; alpha_0 == 0 makes
+        the pair independent of the incoming w) wherever its 2R window
+        fits the shard."""
+        from ..utils.logging import LOG_INFO, LOG_WARN
 
         dd = self.dd
-        if dd.exchange_every > 1:
-            from ..utils.logging import LOG_WARN
+        path = self.kernel_path
+        alloc_r = dd.alloc_radius
+        local = dd.local_size
+        counts = mesh_dim(dd.mesh)
+        prm = self.prm
+        dt = prm.dt
+        tile = mhd_tile(self._dtype)   # 8 f32/f64, 16 bf16 slabs
+        bz, by = self._fast_path_blocks(path)
+        if path == "wrap":
+            from ..ops.pallas_mhd import (mhd_substep01_wrap_pallas,
+                                          mhd_substep_wrap_pallas)
+
+            def substep(f, w, s, write_w=True):
+                return mhd_substep_wrap_pallas(f, w, s, prm, dt,
+                                               write_w=write_w)
+
+            def pair(f):
+                return mhd_substep01_wrap_pallas(f, prm, dt)
+        else:
+            blk = dict(block_z=bz, block_y=by)
+            if path == "halo":
+                from ..ops.pallas_halo import (mhd_substep01_halo_pallas,
+                                               mhd_substep_halo_pallas)
+                from ..parallel.exchange import exchange_interior_slabs
+
+                def slabs(f, rows):
+                    return {q: exchange_interior_slabs(
+                        f[q], counts, rz=bz, ry=tile, radius_rows=rows,
+                        y_z_extended=True) for q in FIELDS}
+
+                def substep(f, w, s, write_w=True):
+                    return mhd_substep_halo_pallas(
+                        f, w, slabs(f, RADIUS), s, prm, dt,
+                        write_w=write_w, **blk)
+
+                def pair(f):
+                    return mhd_substep01_halo_pallas(
+                        f, slabs(f, 2 * RADIUS), prm, dt, **blk)
+            else:
+                from ..ops.pallas_mhd_overlap import mhd_substep_overlap
+
+                def substep(f, w, s, write_w=True):
+                    return mhd_substep_overlap(f, w, s, prm, dt, counts,
+                                               write_w=write_w, **blk)
+
+                def pair(f):
+                    return mhd_substep_overlap(f, None, 0, prm, dt,
+                                               counts, pair=True, **blk)
+
+        s_every = self._exchange_every
+        pair_on = s_every == 2 and 2 * RADIUS <= min(bz, tile)
+        if s_every == 2 and not pair_on:
+            LOG_WARN("exchange_every=2 requested but the fused "
+                     "substep-0+1 kernel cannot tile this shard; "
+                     "falling back to one kernel per substep")
+        elif s_every > 2:
+            # only the wrap path admits deeper blocking
             LOG_WARN("exchange_every has no effect on the single-chip "
                      "wrap path (it performs no exchange); fields still "
                      "carry the deepened allocation pads")
-        alloc_r = dd.alloc_radius
-        local = dd.local_size
-        prm = self.prm
-        dt = prm.dt
+        if pair_on:
+            LOG_INFO(f"astaroth {path} path: fused substep-0+1 kernel")
 
-        @jax.jit
         def extract(fields):
             return {q: read_interior(p, alloc_r, local)
                     for q, p in fields.items()}
 
-        # STENCIL_MHD_PAIR=1 opts into the fused substep-0+1 kernel
-        # (one HBM pass for two of the three RK substeps; alpha_0 == 0
-        # makes the pair independent of the incoming w) — experimental
-        # until hardware-measured, so default off
-        from ..utils.config import mhd_pair_requested
-        pair_on = mhd_pair_requested()
-        if pair_on:
-            from ..ops.pallas_mhd import mhd_substep01_wrap_pallas
-            from ..utils.logging import LOG_INFO
-            LOG_INFO("astaroth wrap path: fused substep-0+1 kernel")
+        loop = resident_loop(substep, pair if pair_on else None)
 
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def loop(inner, w, n):
-            # dead-w elision: substep 0 never reads w (alpha_0 == 0,
-            # w=None) and nothing reads substep 2's w (the next
-            # iteration restarts at alpha_0 == 0; write_w=False) — the
-            # carry keeps the last WRITTEN w so the fori_loop structure
-            # is stable. Saves a full 8-field read + write sweep per
-            # iteration vs the reference's unconditional w traffic
-            # (astaroth/kernels.cu:63-90).
-            def body(_, fw):
-                f, wk = fw
-                if pair_on:
-                    f, wk = mhd_substep01_wrap_pallas(f, prm, dt)
-                    f, _ = mhd_substep_wrap_pallas(f, wk, 2, prm, dt,
-                                                   write_w=False)
-                else:
-                    f, wk = mhd_substep_wrap_pallas(f, None, 0, prm, dt)
-                    f, wk = mhd_substep_wrap_pallas(f, wk, 1, prm, dt)
-                    f, _ = mhd_substep_wrap_pallas(f, wk, 2, prm, dt,
-                                                   write_w=False)
-                return f, wk
-            return lax.fori_loop(0, n, body, (inner, w))
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
         def insert(fields, inner):
             # halos go stale; nothing reads them before the next
             # exchange, and field() reads the interior only
             return {q: write_interior(fields[q], inner[q], alloc_r)
                     for q in fields}
 
+        if path != "wrap":
+            spec = {q: P("z", "y", "x") for q in FIELDS}
+
+            def sharded(fn, in_specs, out_specs):
+                return jax.shard_map(fn, mesh=dd.mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False)
+
+            extract = sharded(extract, (spec,), spec)
+            loop = sharded(loop, (spec, spec, P()), (spec, spec))
+            insert = sharded(insert, (spec, spec), spec)
+            # exchange accounting for exchange_stats(): per iteration
+            # the pair does one radius-2R + one radius-R extended slab
+            # round, the substep kernels three radius-R rounds (the
+            # overlap path issues the same rounds in-kernel)
+            self._slab_exchange_cfg = dict(rz=bz, ry=tile, pair=pair_on)
         # interior-resident state between calls: step()-per-iteration
         # loops would otherwise pay extract+insert (3 extra full-field
         # HBM passes) every iteration. dd.curr is materialized lazily
         # via sync_domain() when the padded domain is accessed.
-        self._insert = insert
-        self._install_inner_iter(extract, loop)
-
-    def _build_halo_step(self) -> None:
-        """Multi-device fused substeps: interior-resident shards, thin
-        slab ppermutes, one fused Pallas megakernel per substep — so an
-        N-chip mesh keeps single-chip per-chip throughput (the analog
-        of the reference's fused solve kernel running at every scale,
-        astaroth/astaroth.cu:552-646; see ops/pallas_halo.py).
-
-        Same extract / substep-loop / insert program split (and
-        interior-resident caching) as wrap mode, but each program is
-        shard_map'ped over the mesh."""
-        from ..ops.pallas_halo import (R as HALO_R, mhd_halo_blocks,
-                                       mhd_substep_halo_pallas)
-        from ..ops.pallas_mhd import mhd_tile
-        from ..parallel.exchange import exchange_interior_slabs
-
-        dd = self.dd
-        alloc_r = dd.alloc_radius
-        local = dd.local_size
-        counts = mesh_dim(dd.mesh)
-        prm = self.prm
-        dt = prm.dt
-        tile = mhd_tile(self._dtype)   # 8 f32/f64, 16 bf16 slabs
-        blk_z, blk_y = getattr(self, "_halo_blocks", None) or (8, 32)
-        bz, by = mhd_halo_blocks(local.z, local.y, blk_z, blk_y, tile,
-                                 X=local.x,
-                                 itemsize=np.dtype(self._dtype).itemsize)
-        spec = P("z", "y", "x")
-        fields_spec = {q: spec for q in FIELDS}
-
-        # STENCIL_MHD_PAIR=1: fused substep-0+1 kernel on the halo path
-        # too — one radius-2R exchange + one HBM pass covers two of the
-        # three RK substeps (same opt-in as the wrap path; needs the
-        # slabs to carry 2R valid rows, hence 2R <= min(bz, tile))
-        from ..utils.config import mhd_pair_requested
-        pair_on = ((mhd_pair_requested() or self._exchange_every == 2)
-                   and 2 * HALO_R <= min(bz, tile))
-        if self._exchange_every == 2 and not pair_on:
-            from ..utils.logging import LOG_WARN
-            LOG_WARN("exchange_every=2 requested but the fused "
-                     "substep-0+1 kernel cannot tile this shard; "
-                     "falling back to per-substep exchanges")
-        if pair_on:
-            from ..ops.pallas_halo import mhd_substep01_halo_pallas
-            from ..utils.logging import LOG_INFO
-            LOG_INFO("astaroth halo path: fused substep-0+1 kernel")
-
-        def extract_shard(fields):
-            return {q: read_interior(p, alloc_r, local)
-                    for q, p in fields.items()}
-
-        extract = jax.jit(jax.shard_map(
-            extract_shard, mesh=dd.mesh, in_specs=(fields_spec,),
-            out_specs=fields_spec, check_vma=False))
-
-        def exchange_all(f, radius_rows):
-            return {q: exchange_interior_slabs(
-                f[q], counts, rz=bz, ry=tile,
-                radius_rows=radius_rows, y_z_extended=True)
-                for q in FIELDS}
-
-        def loop_shard(inner, w, n):
-            # dead-w elision (see _build_wrap_step): substep 0 reads no
-            # w, substep 2 writes none; the carry keeps the last
-            # written w for fori_loop structural stability
-            def body(_, fw):
-                f, wk = fw
-                if pair_on:
-                    f, wk = mhd_substep01_halo_pallas(
-                        f, exchange_all(f, 2 * HALO_R), prm, dt,
-                        block_z=bz, block_y=by)
-                    f, _ = mhd_substep_halo_pallas(
-                        f, wk, exchange_all(f, HALO_R), 2, prm, dt,
-                        block_z=bz, block_y=by, write_w=False)
-                else:
-                    f, wk = mhd_substep_halo_pallas(
-                        f, None, exchange_all(f, HALO_R), 0, prm, dt,
-                        block_z=bz, block_y=by)
-                    f, wk = mhd_substep_halo_pallas(
-                        f, wk, exchange_all(f, HALO_R), 1, prm, dt,
-                        block_z=bz, block_y=by)
-                    f, _ = mhd_substep_halo_pallas(
-                        f, wk, exchange_all(f, HALO_R), 2, prm, dt,
-                        block_z=bz, block_y=by, write_w=False)
-                return f, wk
-            return lax.fori_loop(0, n, body, (inner, w))
-
-        loop = jax.jit(jax.shard_map(
-            loop_shard, mesh=dd.mesh,
-            in_specs=(fields_spec, fields_spec, P()),
-            out_specs=(fields_spec, fields_spec), check_vma=False),
-            donate_argnums=(0, 1))
-
-        def insert_shard(fields, inner):
-            return {q: write_interior(fields[q], inner[q], alloc_r)
-                    for q in fields}
-
-        self._insert = jax.jit(jax.shard_map(
-            insert_shard, mesh=dd.mesh, in_specs=(fields_spec, fields_spec),
-            out_specs=fields_spec, check_vma=False), donate_argnums=0)
-        # exchange accounting for exchange_stats(): per iteration the
-        # pair path does one radius-2R + one radius-R extended slab
-        # round; the sequential path three radius-R rounds
-        self._slab_exchange_cfg = dict(rz=bz, ry=tile, pair=pair_on)
-        self._install_inner_iter(extract, loop)
-
-    def _build_halo_overlap_step(self) -> None:
-        """Overlapped multi-device fused substeps: per substep, ONE
-        Pallas kernel issues the slab RDMA and computes the interior
-        behind the in-flight DMAs, then thin strip kernels recompute
-        the shard-edge blocks from the landed slabs (the reference's
-        per-substep interior/exchange/exterior choreography,
-        astaroth/astaroth.cu:552-646; see ops/pallas_mhd_overlap.py).
-        Same extract/loop/insert program split and interior-resident
-        caching as the halo path."""
-        from ..ops.pallas_halo import R as HALO_R, mhd_halo_blocks
-        from ..ops.pallas_mhd import mhd_tile
-        from ..ops.pallas_mhd_overlap import mhd_substep_overlap
-
-        dd = self.dd
-        alloc_r = dd.alloc_radius
-        local = dd.local_size
-        counts = mesh_dim(dd.mesh)
-        prm = self.prm
-        dt = prm.dt
-        tile = mhd_tile(self._dtype)   # 8 f32/f64, 16 bf16 slabs
-        blk_z, blk_y = getattr(self, "_halo_blocks", None) or (8, 32)
-        bz, by = mhd_halo_blocks(local.z, local.y, blk_z, blk_y, tile,
-                                 X=local.x,
-                                 itemsize=np.dtype(self._dtype).itemsize)
-        spec = P("z", "y", "x")
-        fields_spec = {q: spec for q in FIELDS}
-
-        def extract_shard(fields):
-            return {q: read_interior(p, alloc_r, local)
-                    for q, p in fields.items()}
-
-        extract = jax.jit(jax.shard_map(
-            extract_shard, mesh=dd.mesh, in_specs=(fields_spec,),
-            out_specs=fields_spec, check_vma=False))
-
-        # STENCIL_MHD_PAIR composes with the overlap path too: one
-        # radius-2R overlapped exchange + one fused pass covers RK
-        # substeps 0+1, then substep 2 runs overlapped as usual
-        from ..utils.config import mhd_pair_requested
-        pair_on = ((mhd_pair_requested() or self._exchange_every == 2)
-                   and 2 * HALO_R <= min(bz, tile))
-        if self._exchange_every == 2 and not pair_on:
-            from ..utils.logging import LOG_WARN
-            LOG_WARN("exchange_every=2 requested but the fused "
-                     "substep-0+1 kernel cannot tile this shard; "
-                     "falling back to per-substep exchanges")
-        if pair_on:
-            from ..utils.logging import LOG_INFO
-            LOG_INFO("astaroth halo-overlap path: fused substep-0+1")
-
-        def loop_shard(inner, w, n):
-            # dead-w elision (see _build_wrap_step): substep 0 reads no
-            # w, substep 2 writes none; the carry keeps the last
-            # written w for fori_loop structural stability
-            def body(_, fw):
-                f, wk = fw
-                if pair_on:
-                    f, wk = mhd_substep_overlap(f, None, 0, prm, dt,
-                                                counts, block_z=bz,
-                                                block_y=by, pair=True)
-                    f, _ = mhd_substep_overlap(f, wk, 2, prm, dt,
-                                               counts, block_z=bz,
-                                               block_y=by,
-                                               write_w=False)
-                else:
-                    f, wk = mhd_substep_overlap(f, None, 0, prm, dt,
-                                                counts, block_z=bz,
-                                                block_y=by)
-                    f, wk = mhd_substep_overlap(f, wk, 1, prm, dt,
-                                                counts, block_z=bz,
-                                                block_y=by)
-                    f, _ = mhd_substep_overlap(f, wk, 2, prm, dt,
-                                               counts, block_z=bz,
-                                               block_y=by,
-                                               write_w=False)
-                return f, wk
-            return lax.fori_loop(0, n, body, (inner, w))
-
-        loop = jax.jit(jax.shard_map(
-            loop_shard, mesh=dd.mesh,
-            in_specs=(fields_spec, fields_spec, P()),
-            out_specs=(fields_spec, fields_spec), check_vma=False),
-            donate_argnums=(0, 1))
-
-        def insert_shard(fields, inner):
-            return {q: write_interior(fields[q], inner[q], alloc_r)
-                    for q in fields}
-
-        self._insert = jax.jit(jax.shard_map(
-            insert_shard, mesh=dd.mesh, in_specs=(fields_spec, fields_spec),
-            out_specs=fields_spec, check_vma=False), donate_argnums=0)
-        # same wire traffic as the sequential halo path (pair: one
-        # radius-2R + one radius-R round; else 3 radius-R rounds per
-        # iteration), issued in-kernel
-        self._slab_exchange_cfg = dict(rz=bz, ry=tile, pair=pair_on)
-        self._install_inner_iter(extract, loop)
+        self._insert = jax.jit(insert, donate_argnums=(0,))
+        self._install_inner_iter(jax.jit(extract),
+                                 jax.jit(loop, donate_argnums=(0, 1)))
 
     def _install_inner_iter(self, extract, loop) -> None:
-        """Shared interior-resident iteration protocol for the wrap and
-        halo fast paths: ``self._inner`` caches the interior state
+        """Interior-resident iteration protocol of the Pallas fast
+        paths: ``self._inner`` caches the interior state
         between calls; ``sync_domain()`` flushes it into ``dd.curr``
         (and runs automatically before any ``dd.set_interior``)."""
         def iteration_n(fields, w, n):
@@ -1056,7 +940,7 @@ class Astaroth:
         # the interior-resident fast paths keep their state OUTSIDE
         # dd.curr in a three-program extract/loop/insert split (fusing
         # extract+loop+insert into one program measured an order of
-        # magnitude slower — see _build_wrap_step); a megastep over
+        # magnitude slower — see _build_resident_step); a megastep over
         # dd.curr would advance stale state, so the path declines
         # loudly and the driver runs its already-fused loop stepwise
         from ..parallel.megastep import DECLINE_INTERIOR_RESIDENT_STATE

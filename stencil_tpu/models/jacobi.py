@@ -165,22 +165,17 @@ def _dcn_request_kwargs(dd) -> dict:
             "dcn_groups": dd._dcn_groups}
 
 
-def _wrap_steps(tile: int, requested: int = 0) -> int:
-    """Temporal-blocking depth for the Pallas fast paths: an explicit
-    ``exchange_every`` request wins; else STENCIL_WRAP_STEPS (default
-    2). Clamped to [1, sublane tile] — shared by the wrap and halo step
-    builders (one tunable, two kernel families)."""
-    import os
+#: in-kernel temporal-blocking depth of the Pallas wrap and halo paths
+#: when ``exchange_every`` is not given
+DEFAULT_FAST_PATH_DEPTH = 2
 
-    if requested:
-        return min(max(int(requested), 1), tile)
-    try:
-        n = int(os.environ.get("STENCIL_WRAP_STEPS", "2") or 2)
-    except ValueError:
-        from ..utils.logging import LOG_WARN
-        LOG_WARN("STENCIL_WRAP_STEPS is not an integer; using 2")
-        n = 2
-    return min(max(n, 1), tile)
+
+def _wrap_steps(tile: int, requested: int = 0) -> int:
+    """Temporal-blocking depth for the Pallas fast paths:
+    ``exchange_every`` when given, else :data:`DEFAULT_FAST_PATH_DEPTH`,
+    clamped to [1, sublane tile] — shared by the wrap and halo step
+    builders (one depth, two kernel families)."""
+    return min(max(int(requested or DEFAULT_FAST_PATH_DEPTH), 1), tile)
 
 
 def _chained_loop(x, count, fn):
@@ -319,7 +314,7 @@ class Jacobi3D:
         self.dd.set_radius(1)
         self.dd.set_methods(methods)
         # temporal blocking: None = unset (fast paths keep their
-        # STENCIL_WRAP_STEPS default); an explicit s pins the depth —
+        # DEFAULT_FAST_PATH_DEPTH); an explicit s pins the depth —
         # deep-carry allocations + one deep exchange per s steps on the
         # XLA path (parallel/temporal.py), the in-kernel step count on
         # the Pallas wrap/halo paths (s == 1 forces per-step exchange).
@@ -712,15 +707,12 @@ class Jacobi3D:
         """Single-chip fused steps on the interior view: iterations run
         in groups of N through the temporally-blocked multi-step kernel
         (ops/pallas_stencil.jacobi7_wrapn_pallas — ~1/N the HBM traffic
-        per iteration; N=2 default, STENCIL_WRAP_STEPS to tune) with a
+        per iteration; N = ``exchange_every``, default 2) with a
         single-step tail; grids the blocked kernel can't tile fall back
         to single steps."""
-        import os
-
         from ..ops.pallas_stencil import (jacobi7_wrapn_pallas,
                                           jacobi7_wrap_pallas,
                                           sublane_tile)
-        from ..utils.config import wrap2_disabled
 
         dd = self.dd
         alloc_r = dd.alloc_radius
@@ -729,8 +721,7 @@ class Jacobi3D:
         hot, cold, sph_r = sphere_geometry(gsize)
         tile = sublane_tile(self._dtype)
         N = _wrap_steps(tile, self._exchange_every)
-        pair_ok = (local.y % tile == 0 and N > 1
-                   and not wrap2_disabled())
+        pair_ok = local.y % tile == 0 and N > 1
 
         def single(q):
             return jacobi7_wrap_pallas(q, hot, cold, sph_r)
@@ -855,21 +846,18 @@ class Jacobi3D:
         astaroth/astaroth.cu:552-646; see ops/pallas_halo.py).
 
         Even grids run iterations in groups of N through the
-        temporally-blocked kernel (``jacobi7_halon_pallas``, N=2
-        default / STENCIL_WRAP_STEPS): one radius-N exchange feeds N
+        temporally-blocked kernel (``jacobi7_halon_pallas``, N =
+        ``exchange_every``, default 2): one radius-N exchange feeds N
         fused steps, dividing per-iteration HBM traffic AND exchange
         count by ~N (the slab-layout counterpart of the wrap-path
         kernel), with a single-step tail. Uneven (+-1) grids and grids
         the blocked kernel can't tile keep the single-step kernel."""
-        import os
-
         from ..ops.pallas_halo import (fit_pair_halo_blocks,
                                        jacobi7_halon_pallas,
                                        jacobi7_halo_pallas)
         from ..ops.pallas_stencil import sublane_tile
         from ..parallel.exchange import (exchange_interior_slabs,
                                          shard_interior_len)
-        from ..utils.config import wrap2_disabled
 
         dd = self.dd
         local = dd.local_size
@@ -880,8 +868,7 @@ class Jacobi3D:
         tile = sublane_tile(self._dtype)
         esub = tile if local.y % tile == 0 else 1
         N = _wrap_steps(tile, self._exchange_every)
-        pair_ok = (rem == Dim3(0, 0, 0) and N > 1 and esub == tile
-                   and not wrap2_disabled())
+        pair_ok = rem == Dim3(0, 0, 0) and N > 1 and esub == tile
         if pair_ok:
             from ..analysis.tiling import TilingInfeasibleError
 

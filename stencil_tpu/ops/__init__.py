@@ -33,8 +33,6 @@ PUBLIC_OPS: Dict[str, str] = {
         "ops.pallas_stencil.jacobi7_wrapn_pallas",
     "ops.pallas_stencil.jacobi7_wrap2_pallas":
         "ops.pallas_stencil.jacobi7_wrapn_pallas",  # steps=2 alias
-    "ops.pallas_stencil.laplace6_pallas":
-        "ops.pallas_stencil.laplace6_pallas",
     "ops.pallas_mhd.mhd_substep_wrap_pallas":
         "ops.pallas_mhd.mhd_substep_wrap_pallas",
     "ops.pallas_mhd.mhd_substep01_wrap_pallas":

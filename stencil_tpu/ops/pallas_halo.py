@@ -578,16 +578,12 @@ def jacobi7_halo2_pallas(interior: jnp.ndarray,
 def _mhd_halo_elems(esub: int, rr: int = R, nf: int = 8):
     """Per-lane-column element model of one MHD halo-kernel grid step
     (``_mhd_window_plan`` segments x ``nf`` fields, the worst-case
-    substep: w read + both output sweeps). Thin-z (default): main +
-    2rr in-shard single rows + 2rr slab single rows + 4 esub-col y
-    slabs + 12 esub^2 corner segments per field; tiled
-    (STENCIL_MHD_THINZ=0) swaps the single rows for esub tiles."""
-    from .pallas_mhd import _thin_z
-
-    zrows = 2 * rr if _thin_z() else 2 * esub
+    substep: w read + both output sweeps): main + 2rr in-shard single
+    rows + 2rr slab single rows + 4 esub-col y slabs + 12 esub^2
+    corner segments per field."""
 
     def elems(bz: int, by: int):
-        per_field = (bz * by + 2 * zrows * by + 4 * bz * esub
+        per_field = (bz * by + 4 * rr * by + 4 * bz * esub
                      + 12 * esub * esub)
         ein = nf * (per_field + bz * by)     # fields + w
         return ein, 2 * nf * bz * by, 0      # f and w outputs
@@ -651,23 +647,18 @@ def _mhd_window_plan(Z: int, Y: int, X: int, bz: int, by: int,
     constant block when their grid row cannot need them (Pallas's
     revisit cache then skips the fetch).
 
-    Default (thin-z, 29 specs/field): the full-width z-neighbor
-    segments are SINGLE ROWS at exactly the radius (z is the majormost,
-    untiled dim) — at (8, 64) blocks this cuts per-block read
-    amplification from ~4.5x to ~2.2x. STENCIL_MHD_THINZ=0 (tiled, 21
-    specs/field) restores esub-row z tiles (the round-3
-    hardware-measured layout, kept for A/B). Corner segments always
-    stay at esub granularity (a small fraction of the traffic).
+    The full-width z-neighbor segments are SINGLE ROWS at exactly the
+    radius (z is the majormost, untiled dim; 29 specs/field at rr=3) —
+    at (8, 64) blocks this reads ~2.2x the block where esub-row z
+    tiles would read ~4.5x. Corner segments stay at esub granularity
+    (a small fraction of the traffic).
 
     Index-map geometry: the interior array A is (Z, Y, X); z slabs
     (bz, Y, X) with the adjacent planes at zlo[-1] / zhi[0]; y slabs
     (Z + 2*bz, ry=esub, X), z origin at -bz (z-extended so yz corner
     data rides along).
     """
-    from .pallas_mhd import _thin_z
-
     assert rr <= esub and rr <= bz, (rr, esub, bz)
-    thin = _thin_z()
     bzb = bz // esub
     byb = by // esub
     nzb8 = Z // esub
@@ -702,38 +693,25 @@ def _mhd_window_plan(Z: int, Y: int, X: int, bz: int, by: int,
         return len(specs) - 1
 
     i_main = add("f", (bz, by, X), lambda kz, ky: (kz, ky, 0))
-    if thin:
-        # zm_y0: exact-radius single rows z = kz*bz + o, o in -rr..-1
-        # (in-shard clamped), with zlo slab rows bz+o fetched at kz==0;
-        # zp_y0: rows kz*bz + bz + j with zhi slab rows j at the z end
-        i_zm_in = [add("f", (1, by, X),
-                       lambda kz, ky, o=o: (jnp.clip(kz * bz + o, 0,
-                                                     Z - 1), ky, 0))
-                   for o in range(-rr, 0)]
-        i_zm_zs = [add("zlo", (1, by, X),
-                       lambda kz, ky, o=o: (bz + o,
-                                            jnp.where(kz == 0, ky, 0),
-                                            0))
-                   for o in range(-rr, 0)]
-        i_zp_in = [add("f", (1, by, X),
-                       lambda kz, ky, j=j: (jnp.clip(kz * bz + bz + j,
-                                                     0, Z - 1), ky, 0))
-                   for j in range(rr)]
-        i_zp_zs = [add("zhi", (1, by, X),
-                       lambda kz, ky, j=j: (j, jnp.where(kz == nzg - 1,
-                                                         ky, 0), 0))
-                   for j in range(rr)]
-    else:
-        i_zm0_in = add("f", (esub, by, X),
-                       lambda kz, ky: (clampz(kz), ky, 0))
-        i_zm0_zs = add("zlo", (esub, by, X),
-                       lambda kz, ky: (bzb - 1,
-                                       jnp.where(kz == 0, ky, 0), 0))
-        i_zp0_in = add("f", (esub, by, X),
-                       lambda kz, ky: (clampZ(kz), ky, 0))
-        i_zp0_zs = add("zhi", (esub, by, X),
-                       lambda kz, ky: (0, jnp.where(kz == nzg - 1,
-                                                    ky, 0), 0))
+    # zm_y0: exact-radius single rows z = kz*bz + o, o in -rr..-1
+    # (in-shard clamped), with zlo slab rows bz+o fetched at kz==0;
+    # zp_y0: rows kz*bz + bz + j with zhi slab rows j at the z end
+    i_zm_in = [add("f", (1, by, X),
+                   lambda kz, ky, o=o: (jnp.clip(kz * bz + o, 0, Z - 1),
+                                        ky, 0))
+               for o in range(-rr, 0)]
+    i_zm_zs = [add("zlo", (1, by, X),
+                   lambda kz, ky, o=o: (bz + o,
+                                        jnp.where(kz == 0, ky, 0), 0))
+               for o in range(-rr, 0)]
+    i_zp_in = [add("f", (1, by, X),
+                   lambda kz, ky, j=j: (jnp.clip(kz * bz + bz + j, 0,
+                                                 Z - 1), ky, 0))
+               for j in range(rr)]
+    i_zp_zs = [add("zhi", (1, by, X),
+                   lambda kz, ky, j=j: (j, jnp.where(kz == nzg - 1,
+                                                     ky, 0), 0))
+               for j in range(rr)]
     # z0_ym / z0_yp: rows y in [ky*by-8, ky*by) / [ky*by+by, +8)
     i_ym_in = add("f", (bz, esub, X),
                   lambda kz, ky: (kz, clampy(ky), 0))
@@ -810,19 +788,10 @@ def _mhd_window_plan(Z: int, Y: int, X: int, bz: int, by: int,
                 v = jnp.where(at_yedge, refs[i_ys][...], v)
             return v
 
-        if thin:
-            zm_rows = [sel(i_zm_in[i], i_zm_zs[i], at_zlo)
-                       for i in range(rr)]
-            zp_rows = [sel(i_zp_in[i], i_zp_zs[i], at_zhi)
-                       for i in range(rr)]
-        else:
-            # tiled esub blocks: the adjacent rr rows sit at the tile
-            # end (zm) / start (zp)
-            zm_y0 = sel(i_zm0_in, i_zm0_zs, at_zlo)
-            zp_y0 = sel(i_zp0_in, i_zp0_zs, at_zhi)
-            zm_rows = [zm_y0[esub - rr + i:esub - rr + i + 1]
-                       for i in range(rr)]
-            zp_rows = [zp_y0[i:i + 1] for i in range(rr)]
+        zm_rows = [sel(i_zm_in[i], i_zm_zs[i], at_zlo)
+                   for i in range(rr)]
+        zp_rows = [sel(i_zp_in[i], i_zp_zs[i], at_zhi)
+                   for i in range(rr)]
         z0_ym = sel(i_ym_in, i_ym_ys, at_ylo)
         z0_yp = sel(i_yp_in, i_yp_ys, at_yhi)
         zm_ym = sel3(i_mm, at_zlo, at_ylo)
@@ -915,7 +884,7 @@ def mhd_substep_halo_pallas(fields: Dict[str, jnp.ndarray],
     nyg = Y // by
     field_specs, inputs_for_field, select_window = _mhd_window_plan(
         Z, Y, X, bz, by, esub=esub)
-    nseg = len(field_specs)    # layout-dependent; kern slicing derives from it
+    nseg = len(field_specs)
     nf = len(FIELDS)
     nw = 0 if w is None else nf
     nwo = nf if write_w else 0

@@ -56,16 +56,6 @@ def mhd_tile(dtype) -> int:
     return sublane_tile(dtype)
 
 
-def _thin_z() -> bool:
-    """STENCIL_MHD_THINZ=0 restores the tiled (ESUB-row) z-neighbor
-    segments — the hardware-proven round-3 layout — for A/B runs; the
-    default is the exact-radius single-row scheme (see _window_plan)."""
-    import os
-
-    return os.environ.get("STENCIL_MHD_THINZ", "1").lower() not in (
-        "0", "false", "no")
-
-
 _YSEGS = (-1, 0, 1)
 
 
@@ -79,41 +69,25 @@ def _window_plan(Z: int, Y: int, X: int, bz: int, by: int,
     ``x_wrap`` mode). ``esub`` is the dtype's sublane tile (8 f32 /
     16 bf16): the y edge-slab granularity.
 
-    Default (thin-z) plan: 2rr+1 z segments (rr wrapped single rows
-    below, the main bz-row block, rr above — exact-radius fetches,
-    since the majormost dim has no tile granularity) x 3 y segments
-    (preceding esub-slab, main, following esub-slab); per-block read
-    amplification (1 + 2rr/bz) * (1 + 2*esub/by).
-
-    STENCIL_MHD_THINZ=0 plan: 3 z segments (esub-row tile below, main,
-    esub-row tile above) x 3 y segments = 9 specs; amplification
-    (1 + 2*esub/bz) * (1 + 2*esub/by) — more traffic, but fewer/fatter
-    DMAs (the round-3 layout, kept for hardware A/B).
+    2rr+1 z segments (rr wrapped single rows below, the main bz-row
+    block, rr above — exact-radius fetches, since the majormost dim
+    has no tile granularity) x 3 y segments (preceding esub-slab,
+    main, following esub-slab); per-block read amplification
+    (1 + 2rr/bz) * (1 + 2*esub/by).
     """
     assert rr <= esub, (rr, esub)   # y slabs are one esub tile wide
     nyb = Y // esub
     byb = by // esub
-    thin = _thin_z()
-    if thin:
-        zsegs = tuple(range(-rr, 0)) + (0,) + tuple(range(1, rr + 1))
-    else:
-        assert bz % esub == 0 and Z % esub == 0, (Z, bz)
-        zsegs = (-1, 0, 1)
-        bzb = bz // esub
-        nzb = Z // esub
+    zsegs = tuple(range(-rr, 0)) + (0,) + tuple(range(1, rr + 1))
 
     def zy(zseg: int, yseg: int):
         if zseg == 0:
             zshape, zidx = bz, (lambda kz: kz)
-        elif thin:
+        else:
             # single wrapped row at element offset kz*bz + zseg (below)
             # or kz*bz + bz + zseg - 1 (above); block units == elements
             off = zseg if zseg < 0 else bz + zseg - 1
             zshape, zidx = 1, (lambda kz, o=off: (kz * bz + o) % Z)
-        elif zseg < 0:
-            zshape, zidx = esub, (lambda kz: (kz * bzb - 1) % nzb)
-        else:
-            zshape, zidx = esub, (lambda kz: (kz * bzb + bzb) % nzb)
         if yseg == 0:
             yshape, yidx = by, (lambda ky: ky)
         elif yseg < 0:
@@ -131,17 +105,10 @@ def _window_plan(Z: int, Y: int, X: int, bz: int, by: int,
         """(bz+2rr, by+2rr, X) periodic window from the segment refs
         (z segments outer, y in _YSEGS inner)."""
         rows = []
-        for zi, zs in enumerate(zsegs):
+        for zi in range(len(zsegs)):
             ym, y0, yp = refs[3 * zi:3 * zi + 3]
-            if thin or zs == 0:
-                zslice = slice(None)
-            elif zs < 0:          # tiled: last rr rows of the esub tile
-                zslice = slice(esub - rr, None)
-            else:                 # tiled: first rr rows
-                zslice = slice(None, rr)
             rows.append(jnp.concatenate(
-                [ym[zslice, esub - rr:], y0[zslice], yp[zslice, :rr]],
-                axis=1))
+                [ym[:, esub - rr:], y0[:], yp[:, :rr]], axis=1))
         return jnp.concatenate(rows, axis=0)
 
     return specs, assemble
@@ -151,12 +118,10 @@ def _wrap_mhd_elems(esub: int, rr: int = R, nf: int = 8):
     """Per-lane-column element model of one MHD wrap-kernel grid step
     for the block planner (the ``_window_plan`` segment cross product
     x ``nf`` fields, worst-case substep: w read + both output sweeps):
-    per field ``(bz + zextra) * (by + 2*esub)`` streamed in, where
-    ``zextra`` is 2rr single rows (thin-z) or two esub tiles."""
-    zextra = 2 * rr if _thin_z() else 2 * esub
+    per field ``(bz + 2rr) * (by + 2*esub)`` streamed in."""
 
     def elems(bz: int, by: int):
-        per_field = (bz + zextra) * (by + 2 * esub)
+        per_field = (bz + 2 * rr) * (by + 2 * esub)
         ein = nf * (per_field + bz * by)     # field windows + w
         return ein, 2 * nf * bz * by, 0      # f and w outputs
 
@@ -364,7 +329,7 @@ def mhd_substep01_wrap_pallas(fields: Dict[str, jnp.ndarray],
     a w read) with one fatter read and the same writes. Per-point op
     order matches two ``mhd_substep_wrap_pallas`` calls exactly (the
     ring is recomputed, not approximated), so results are
-    bit-compatible. Opt-in path (STENCIL_MHD_PAIR=1 in the model): the
+    bit-compatible. The model runs it when ``exchange_every == 2``: the
     compute/VMEM pressure doubles per grid step, and the trade is
     unmeasured on hardware. Reference semantics:
     astaroth/kernels.cu:63-90 applied for substeps 0 and 1.

@@ -87,8 +87,9 @@ def mhd_substep_overlap_pallas(fields: Dict[str, jnp.ndarray],
     astaroth/astaroth.cu:552-646 (interior launch + transports in
     flight), compressed into one kernel.
 
-    ``pair=True`` fuses RK substeps 0+1 into the pass (the
-    STENCIL_MHD_PAIR temporal blocking, ``pallas_mhd.mhd_pair_update``):
+    ``pair=True`` fuses RK substeps 0+1 into the pass (the model's
+    ``exchange_every == 2`` temporal blocking,
+    ``pallas_mhd.mhd_pair_update``):
     ``s`` and the incoming ``w`` are ignored (alpha_0 == 0), the
     windows and the RDMA carry radius 2R, and the slabs come back with
     2R valid rows.

@@ -423,60 +423,8 @@ def jacobi7_wrap2_pallas(interior: jnp.ndarray,
                          interpret: Optional[bool] = None) -> jnp.ndarray:
     """Two fused iterations per HBM pass — ``jacobi7_wrapn_pallas``
     with steps=2. Kept as a stable named entry for kernel-level tests
-    and external callers; the model builder and the tuning harness
-    patch ``jacobi7_wrapn_pallas`` directly."""
+    and external callers; the model builder calls
+    ``jacobi7_wrapn_pallas`` directly."""
     return jacobi7_wrapn_pallas(interior, hot_c, cold_c, sph_r, steps=2,
                                 block_z=block_z, block_y=block_y,
                                 interpret=interpret)
-
-
-# 6th-order central second-derivative coefficients (see ops/fd6.py)
-_D2_C = -49.0 / 18.0
-_D2 = (3.0 / 2.0, -3.0 / 20.0, 1.0 / 90.0)
-
-
-@layer_scope(KERNEL)
-def laplace6_pallas(padded: jnp.ndarray, radius: Radius, interior: Dim3,
-                    inv_ds: Tuple[float, float, float] = (1.0, 1.0, 1.0),
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Fused 6th-order Laplacian (the Astaroth-family hot derivative,
-    reference: astaroth/user_kernels.h:49-62 second_derivative summed
-    over axes) on a radius-3-padded shard, z-plane-pipelined: 7 planes
-    resident in VMEM per grid step."""
-    if interpret is None:
-        interpret = default_interpret()
-    lo = radius.pad_lo()
-    Z, Y, X = interior.z, interior.y, interior.x
-    Zp, Yp, Xp = padded.shape
-    ly, lx = lo.y, lo.x
-    dt = jnp.dtype(padded.dtype)
-    ix2 = dt.type(inv_ds[0] * inv_ds[0])
-    iy2 = dt.type(inv_ds[1] * inv_ds[1])
-    iz2 = dt.type(inv_ds[2] * inv_ds[2])
-
-    def kern(m3, m2, m1, c0, p1, p2, p3, out):
-        c = c0[0]
-        ctr = c[ly:ly + Y, lx:lx + X]
-        accx = dt.type(_D2_C) * ctr
-        accy = accx
-        accz = dt.type(_D2_C) * ctr
-        planes = {-3: m3, -2: m2, -1: m1, 1: p1, 2: p2, 3: p3}
-        for i, w in enumerate(_D2, start=1):
-            wc = dt.type(w)
-            accx = accx + wc * (c[ly:ly + Y, lx + i:lx + i + X]
-                                + c[ly:ly + Y, lx - i:lx - i + X])
-            accy = accy + wc * (c[ly + i:ly + i + Y, lx:lx + X]
-                                + c[ly - i:ly - i + Y, lx:lx + X])
-            accz = accz + wc * (planes[i][0, ly:ly + Y, lx:lx + X]
-                                + planes[-i][0, ly:ly + Y, lx:lx + X])
-        out[0] = accx * ix2 + accy * iy2 + accz * iz2
-
-    return pl.pallas_call(
-        kern,
-        name="laplace6",
-        grid=(Z,),
-        in_specs=_plane_specs(7, lo.z, Yp, Xp),
-        out_specs=pl.BlockSpec((1, Y, X), lambda k: (k, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Z, Y, X), padded.dtype),
-        interpret=interpret,
-    )(*([padded] * 7))

@@ -10,6 +10,8 @@ separate tables like AcMeshInfo's int_params/real_params.
 from __future__ import annotations
 
 import os
+import re
+from typing import Dict, Tuple
 
 
 def apply_fake_cpu(n: int) -> None:
@@ -55,26 +57,6 @@ def enable_compile_cache() -> str:
     # cache every program that takes noticeable compile time
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     return cache
-
-
-def mhd_pair_requested() -> bool:
-    """STENCIL_MHD_PAIR=1 opts the MHD fast paths (wrap, halo, and
-    halo-overlap) into the fused RK substep-0+1 pair kernels — the ONE
-    parse of the flag, shared by every builder that gates on it."""
-    return (os.environ.get("STENCIL_MHD_PAIR", "").lower()
-            in ("1", "true", "yes"))
-
-
-def wrap2_disabled() -> bool:
-    """STENCIL_DISABLE_WRAP2=1 is the kill-switch harnesses use to fall
-    back from the temporally-blocked pair kernels to the hardware-proven
-    single-step kernels ("0" and unset both leave pairs on). Shared by
-    the wrap and halo step builders (models/jacobi.py)."""
-    return (os.environ.get("STENCIL_DISABLE_WRAP2", "").lower()
-            in ("1", "true", "yes"))
-
-import re
-from typing import Dict, Tuple
 
 
 def load_config(path: str) -> Tuple[Dict[str, int], Dict[str, float]]:

@@ -30,7 +30,6 @@ def make_pair(size=(16, 16, 16), iters=2, dtype=np.float64):
 
 
 class TestDistributedParity:
-    @pytest.mark.slow
     def test_multi_matches_single_device(self):
         single, multi = make_pair()
         for q in FIELDS:
@@ -38,7 +37,6 @@ class TestDistributedParity:
             b = multi.field(q)
             np.testing.assert_allclose(a, b, rtol=0, atol=1e-12, err_msg=q)
 
-    @pytest.mark.slow
     def test_slab_method_matches(self):
         size = (16, 16, 16)
         a = Astaroth(*size, mesh_shape=(2, 2, 2), dtype=np.float64,
@@ -53,26 +51,30 @@ class TestDistributedParity:
 
 
 class TestStability:
-    @pytest.mark.slow
-    @pytest.mark.parametrize("thinz,pair", [
-        ("1", "0"), ("0", "0"),
-        # fused substep-0+1 kernel (STENCIL_MHD_PAIR=1 opt-in), under
-        # both window plans (tiled-z at rr=6 slices the ESUB tile
-        # differently than the rr=3 single-substep path)
-        ("1", "1"), ("0", "1")])
-    def test_wrap_megakernel_matches_xla(self, thinz, pair, monkeypatch):
+    @pytest.mark.parametrize("pair", [False, True])
+    def test_wrap_megakernel_matches_xla(self, pair, monkeypatch):
         """The fused Pallas substep megakernel (ops/pallas_mhd.py,
-        single-chip fast path) against the slicing formulation — under
-        BOTH window plans (exact-radius thin-z default and the
-        STENCIL_MHD_THINZ=0 tiled-z A/B control) and with the fused
-        substep-0+1 pair kernel opted in."""
-        monkeypatch.setenv("STENCIL_MHD_THINZ", thinz)
-        monkeypatch.setenv("STENCIL_MHD_PAIR", pair)
+        single-chip fast path) against the slicing formulation — per
+        substep, and with ``exchange_every=2`` on the fused substep-0+1
+        pair kernel (which must then be the kernel that runs)."""
+        from stencil_tpu.ops import pallas_mhd
+
+        pair_calls = []
+        pair_kernel = pallas_mhd.mhd_substep01_wrap_pallas
+
+        def counted(*args, **kw):
+            pair_calls.append(1)
+            return pair_kernel(*args, **kw)
+
+        monkeypatch.setattr(pallas_mhd, "mhd_substep01_wrap_pallas",
+                            counted)
         size = (16, 16, 16)
         a = Astaroth(*size, mesh_shape=(1, 1, 1), dtype=np.float64,
                      devices=jax.devices()[:1], kernel="xla")
         b = Astaroth(*size, mesh_shape=(1, 1, 1), dtype=np.float64,
-                     devices=jax.devices()[:1], kernel="wrap")
+                     devices=jax.devices()[:1], kernel="wrap",
+                     exchange_every=2 if pair else None)
+        assert b.kernel_path == "wrap"
         for m in (a, b):
             m.init()
             m.step()
@@ -80,6 +82,7 @@ class TestStability:
         for q in FIELDS:
             np.testing.assert_allclose(b.field(q), a.field(q),
                                        rtol=1e-11, atol=1e-13, err_msg=q)
+        assert bool(pair_calls) == pair
 
     def test_fields_stay_finite(self):
         m = Astaroth(16, 16, 16, mesh_shape=(2, 2, 2), dtype=np.float64)
@@ -117,7 +120,6 @@ class TestDeadWElision:
         wz = {q: np.zeros(size, np.float64) for q in FIELDS}
         return f, wz
 
-    @pytest.mark.slow
     def test_wrap_kernel_elision_bit_identical(self):
         from stencil_tpu.ops.pallas_mhd import mhd_substep_wrap_pallas
 
@@ -232,33 +234,31 @@ class TestBfloat16:
             assert err < tol, (label, q, err)
 
     @pytest.mark.slow
-    @pytest.mark.parametrize("thinz,pair", [
-        ("1", "0"), ("0", "0"), ("1", "1")])
-    def test_wrap_bf16_matches_f32_oracle(self, thinz, pair, monkeypatch):
+    @pytest.mark.parametrize("pair", [False, True])
+    def test_wrap_bf16_matches_f32_oracle(self, pair):
         import jax.numpy as jnp
-        monkeypatch.setenv("STENCIL_MHD_THINZ", thinz)
-        monkeypatch.setenv("STENCIL_MHD_PAIR", pair)
         size = (32, 32, 32)
         ref = self._f32_oracle(size)
         b = Astaroth(*size, mesh_shape=(1, 1, 1), dtype=jnp.bfloat16,
-                     devices=jax.devices()[:1], kernel="wrap")
+                     devices=jax.devices()[:1], kernel="wrap",
+                     exchange_every=2 if pair else None)
         assert b.kernel_path == "wrap"
         b.init()
         b.step()
         b.step()
-        self._assert_close(b, ref, f"wrap thinz={thinz} pair={pair}")
+        self._assert_close(b, ref, f"wrap pair={pair}")
 
     @pytest.mark.slow
-    @pytest.mark.parametrize("pair", ["0", "1"])
-    def test_halo_bf16_matches_f32_oracle(self, pair, monkeypatch):
+    @pytest.mark.parametrize("pair", [False, True])
+    def test_halo_bf16_matches_f32_oracle(self, pair):
         """Multi-device slab layout: 16-row (bf16-tile) slab exchange +
         the halo megakernel, on an x-unsharded (1,2,2) mesh."""
         import jax.numpy as jnp
-        monkeypatch.setenv("STENCIL_MHD_PAIR", pair)
         size = (32, 32, 32)
         ref = self._f32_oracle(size)
         c = Astaroth(*size, mesh_shape=(1, 2, 2), dtype=jnp.bfloat16,
-                     devices=jax.devices()[:4], kernel="halo")
+                     devices=jax.devices()[:4], kernel="halo",
+                     exchange_every=2 if pair else None)
         assert c.kernel_path == "halo"
         c.init()
         c.step()
@@ -290,17 +290,16 @@ class TestBfloat16:
         assert m.kernel_path == "halo-overlap"
 
     @pytest.mark.slow
-    @pytest.mark.parametrize("pair", ["0", "1"])
-    def test_overlap_bf16_matches_f32_oracle(self, pair, monkeypatch):
+    @pytest.mark.parametrize("pair", [False, True])
+    def test_overlap_bf16_matches_f32_oracle(self, pair):
         """The overlapped (in-kernel RDMA) path in bf16, alone and
         composed with the substep-0+1 pair."""
         import jax.numpy as jnp
-        monkeypatch.setenv("STENCIL_MHD_PAIR", pair)
         size = (32, 32, 32)
         ref = self._f32_oracle(size)
         c = Astaroth(*size, mesh_shape=(1, 2, 2), dtype=jnp.bfloat16,
                      devices=jax.devices()[:4], kernel="halo",
-                     overlap=True)
+                     overlap=True, exchange_every=2 if pair else None)
         assert c.kernel_path == "halo-overlap"
         c.init()
         c.step()

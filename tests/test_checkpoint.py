@@ -72,7 +72,6 @@ def test_checkpoint_rejects_mismatched_dtype(tmp_path):
         restore_domain(b.dd, str(tmp_path / "ckpt"))
 
 
-@pytest.mark.slow
 def test_astaroth_checkpoint_with_accumulators(tmp_path):
     from stencil_tpu.models.astaroth import Astaroth, MhdParams
 

@@ -209,8 +209,6 @@ def test_fast_path_exchange_stats():
     and (c) produce a positive standalone timing — the honest fast-path
     stats the orchestrator counters cannot provide (reference:
     src/stencil.cu:1005-1008,1174-1181)."""
-    import os
-
     import jax
 
     from stencil_tpu.models.astaroth import FIELDS, Astaroth
@@ -218,16 +216,8 @@ def test_fast_path_exchange_stats():
     from stencil_tpu.parallel.exchange import interior_slab_bytes
     from stencil_tpu.parallel.mesh import mesh_dim
 
-    prior = os.environ.get("STENCIL_MHD_PAIR")
-    os.environ["STENCIL_MHD_PAIR"] = "1"
-    try:
-        a = Astaroth(16, 8, 16, mesh_shape=(1, 1, 2), dtype=np.float64,
-                     devices=jax.devices()[:2], kernel="halo")
-    finally:
-        if prior is None:
-            os.environ.pop("STENCIL_MHD_PAIR", None)
-        else:
-            os.environ["STENCIL_MHD_PAIR"] = prior
+    a = Astaroth(16, 8, 16, mesh_shape=(1, 1, 2), dtype=np.float64,
+                 devices=jax.devices()[:2], kernel="halo", exchange_every=2)
     b = Astaroth(16, 8, 16, mesh_shape=(1, 1, 2), dtype=np.float64,
                  devices=jax.devices()[:2], kernel="halo")
     sa, sb = a.exchange_stats(), b.exchange_stats()

@@ -212,32 +212,30 @@ def test_jacobi3d_model_halo_kernel(mesh_shape):
     np.testing.assert_allclose(j.temperature(), want, rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow
 class TestAstarothHalo:
     """MHD halo megakernel (mhd_substep_halo_pallas) parity and the
     interior-resident state protocol."""
 
-    @pytest.mark.parametrize("mesh_shape,thinz,pair", [
-        ((1, 2, 4), "1", "0"), ((1, 1, 1), "1", "0"),
-        # tiled-z control: the (1,1,1) case has nzg=4, exercising the
-        # tiled IN-SHARD z segments that edge-only shards never select
-        ((1, 2, 4), "0", "0"), ((1, 1, 1), "0", "0"),
-        # fused substep-0+1 pair (STENCIL_MHD_PAIR=1): the (1,2,4) case
+    @pytest.mark.parametrize("mesh_shape,pair", [
+        ((1, 2, 4), False),
+        pytest.param((1, 1, 1), False, marks=pytest.mark.slow),
+        # fused substep-0+1 pair (exchange_every=2): the (1,2,4) case
         # has nzg=nyg=1 (every block slab-fed on all four sides at the
-        # rr=2R window), the (1,1,1) case exercises in-shard rr=6 rows
-        # under the tiled-z plan
-        ((1, 2, 4), "1", "1"), ((1, 1, 1), "0", "1")])
-    def test_halo_matches_xla(self, mesh_shape, thinz, pair, monkeypatch):
+        # rr=2R window), the (1,1,1) case (nzg=4) exercises in-shard
+        # rr=6 rows
+        ((1, 2, 4), True),
+        pytest.param((1, 1, 1), True, marks=pytest.mark.slow)])
+    def test_halo_matches_xla(self, mesh_shape, pair):
         from stencil_tpu.models.astaroth import FIELDS, Astaroth
 
-        monkeypatch.setenv("STENCIL_MHD_THINZ", thinz)
-        monkeypatch.setenv("STENCIL_MHD_PAIR", pair)
         size = (16, 16, 32)   # (nx, ny, nz): local z/y stay multiples of 8
         ndev = mesh_shape[0] * mesh_shape[1] * mesh_shape[2]
         a = Astaroth(*size, mesh_shape=(1, 1, 1), dtype=np.float64,
                      devices=jax.devices()[:1], kernel="xla")
         b = Astaroth(*size, mesh_shape=mesh_shape, dtype=np.float64,
-                     devices=jax.devices()[:ndev], kernel="halo")
+                     devices=jax.devices()[:ndev], kernel="halo",
+                     exchange_every=2 if pair else None)
+        assert b._slab_exchange_cfg["pair"] == pair
         for m in (a, b):
             m.init()
             m.step()

@@ -80,10 +80,9 @@ def reference(state, n, N):
 
 @pytest.mark.parametrize("N,n", [(2, 1), (2, 2), (2, 3), (2, 4), (2, 5),
                                  (2, 6), (2, 9), (2, 32), (1, 5)])
-def test_halo_run_is_the_one_call_per_iteration_schedule(monkeypatch, N, n):
-    monkeypatch.setenv("STENCIL_WRAP_STEPS", str(N))
+def test_halo_run_is_the_one_call_per_iteration_schedule(N, n):
     j = Jacobi3D(X, Y, Z, dtype=np.float32, devices=jax.devices()[:CHIPS],
-                 kernel="halo")
+                 kernel="halo", exchange_every=N)
     assert j.kernel_path == "halo" and j.step_stride == N
     assert list(j.dd.placement.dim()) == list(COUNTS)
     rng = np.random.default_rng(2000 + 10 * N + n)

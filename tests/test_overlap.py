@@ -104,25 +104,19 @@ def test_jacobi_overlap_kernel_in_kernel_rdma():
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("mesh_shape,size,thinz,pair", [
+@pytest.mark.parametrize("mesh_shape,size,pair", [
     # (1,2,2) on (16,16,48): local (16,8,24) -> nzg=3, exercising BOTH
     # fix-up strips (z edges + the middle y strip); (1,1,2) on
     # (16,16,32): local z=16 -> nzg=2, z strips cover everything and
-    # the y axis is a local wrap; the thinz=0 case runs the slabless
-    # interior plan AND the fix-up plan in tiled-z mode
-    ((1, 2, 2), (16, 16, 48), "1", "0"),
-    ((1, 1, 2), (16, 16, 32), "1", "0"),
-    ((1, 1, 2), (16, 16, 32), "0", "0"),
-    # tiled-z through BOTH fix-up strips (nzg=3 -> the y strip's
-    # tiled z-segment remap is exercised too)
-    ((1, 2, 2), (16, 16, 48), "0", "0"),
-    # fused substep-0+1 pair composed with the overlap path: one
-    # radius-2R overlapped exchange per pair, both fix-up strips —
-    # under both window plans (tiled-z slices rr=6 differently)
-    ((1, 2, 2), (16, 16, 48), "1", "1"),
-    ((1, 1, 2), (16, 16, 32), "0", "1")])
-def test_astaroth_rdma_overlap_matches_xla(mesh_shape, size, thinz,
-                                           pair, monkeypatch):
+    # the y axis is a local wrap
+    ((1, 2, 2), (16, 16, 48), False),
+    ((1, 1, 2), (16, 16, 32), False),
+    # fused substep-0+1 pair composed with the overlap path
+    # (exchange_every=2): one radius-2R overlapped exchange per pair,
+    # both fix-up strips, and the z-strip-only (1,1,2) layout
+    ((1, 2, 2), (16, 16, 48), True),
+    ((1, 1, 2), (16, 16, 32), True)])
+def test_astaroth_rdma_overlap_matches_xla(mesh_shape, size, pair):
     """The in-kernel RDMA overlap path (ops/pallas_mhd_overlap.py):
     slab RDMA behind the fused interior compute + strip fix-ups must
     match the XLA oracle exactly like the sequential halo path does
@@ -131,19 +125,16 @@ def test_astaroth_rdma_overlap_matches_xla(mesh_shape, size, thinz,
 
     from stencil_tpu.models.astaroth import FIELDS, Astaroth
 
-    monkeypatch.setenv("STENCIL_MHD_THINZ", thinz)
-    monkeypatch.setenv("STENCIL_MHD_PAIR", pair)
-
     ndev = mesh_shape[0] * mesh_shape[1] * mesh_shape[2]
     a = Astaroth(*size, mesh_shape=(1, 1, 1), dtype=np.float64,
                  devices=jax.devices()[:1], kernel="xla")
     b = Astaroth(*size, mesh_shape=mesh_shape, dtype=np.float64,
                  devices=jax.devices()[:ndev], kernel="halo",
-                 overlap=True)
+                 overlap=True, exchange_every=2 if pair else None)
     assert b.kernel_path == "halo-overlap", b.kernel_path
     # the pair cases must actually engage pair mode (guard against the
     # gate silently falling back to the already-covered non-pair path)
-    assert b._slab_exchange_cfg["pair"] == (pair == "1")
+    assert b._slab_exchange_cfg["pair"] == pair
     for m in (a, b):
         m.init()
         m.step()

@@ -174,6 +174,34 @@ def test_mhd_wrap_substep_256(one_chip, substep):
     _compile(sub, fields, w, kernel="mhd_substep_wrap")
 
 
+def test_mhd_wrap_run_loop_256_program(one_chip):
+    """The loop program Astaroth(256^3).run(n) compiles on one chip (the
+    wrap path, no pair kernel): the shared interior-resident RK3 loop,
+    ``n`` traced, fields and w donated. Its while body holds the three
+    substep calls, and no copy of a 256^3 field appears anywhere."""
+    from stencil_tpu.models.astaroth import (FIELDS, MhdParams,
+                                             resident_loop)
+    from stencil_tpu.ops.pallas_mhd import mhd_substep_wrap_pallas
+
+    n = 256
+    prm = MhdParams()
+
+    def substep(f, w, s, write_w=True):
+        return mhd_substep_wrap_pallas(f, w, s, prm, prm.dt,
+                                       write_w=write_w, interpret=False)
+
+    fields = {q: _spec((n, n, n), one_chip) for q in FIELDS}
+    compiled = _compile(resident_loop(substep), fields, dict(fields),
+                        _spec((), one_chip, jnp.int32),
+                        kernel="mhd_substep_wrap", donate=(0, 1))
+    comps, bodies = _while_bodies(compiled)
+    assert sorted(_calls(comps[b], "mhd_substep_wrap") for b in bodies) \
+        == [3]
+    grid = re.compile(r"= f32\[256,256,256\]\S* copy\(")
+    for name, lines in comps.items():
+        assert not any(grid.search(line) for line in lines), name
+
+
 def test_jacobi_halo_pair_4chip_shard(one_chip):
     """The kernel each chip runs for Jacobi3D(512^3) over four chips:
     auto picks the x-free mesh (1, 1, 4), so one shard is 512 x 512 x
@@ -293,7 +321,7 @@ def test_jacobi_wrap_single_step(one_chip):
 
 
 def test_mhd_wrap_pair_substep(one_chip):
-    """The fused substep-0+1 MHD wrap kernel (STENCIL_MHD_PAIR=1)."""
+    """The fused substep-0+1 MHD wrap kernel (exchange_every=2)."""
     from stencil_tpu.models.astaroth import FIELDS, MhdParams
     from stencil_tpu.ops.pallas_mhd import mhd_substep01_wrap_pallas
 

@@ -164,7 +164,6 @@ def test_auto_partition_falls_back_to_uneven():
     dd.exchange()
 
 
-@pytest.mark.slow
 def test_uneven_astaroth_matches_single_device():
     """MHD on an uneven grid must match the 1-device run (regression:
     substeps once dropped dd.rem, silently corrupting wrap halos)."""
@@ -220,7 +219,6 @@ def test_uneven_set_get_roundtrip():
     np.testing.assert_array_equal(dd.interior_to_host("q"), vals)
 
 
-@pytest.mark.slow
 def test_uneven_mhd_radius3_matches_oracle():
     """Radius-3, 8-field MHD on +-1 shards (18 over 4 -> 5,5,4,4):
     the uneven exchange at a 3-deep halo with multiple quantities —

@@ -37,10 +37,10 @@ def reference(state, n, N):
 
 @pytest.mark.parametrize("N,n", [(2, 1), (2, 2), (2, 3), (2, 4), (2, 5),
                                  (2, 6), (2, 9), (3, 7), (1, 5)])
-def test_run_is_the_one_call_per_iteration_schedule(monkeypatch, N, n):
-    monkeypatch.setenv("STENCIL_WRAP_STEPS", str(N))
+def test_run_is_the_one_call_per_iteration_schedule(N, n):
     j = Jacobi3D(SIZE, SIZE, SIZE, mesh_shape=(1, 1, 1), dtype=np.float32,
-                 kernel="wrap", devices=jax.devices()[:1])
+                 kernel="wrap", devices=jax.devices()[:1],
+                 exchange_every=N)
     assert j.kernel_path == "wrap" and j.step_stride == N
     rng = np.random.default_rng(1000 + 10 * N + n)
     state = rng.random((SIZE,) * 3, dtype=np.float32)
