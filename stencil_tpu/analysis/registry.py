@@ -2461,23 +2461,30 @@ def _jacobi_halon_tiling_spec(side: int) -> TilingSpec:
                                    slabs["yhi"], org))
 
 
-def _mhd_halo_tiling_spec(pair: bool, side: int) -> TilingSpec:
+def _mhd_halo_tiling_spec(pair: bool, side: int,
+                          y_local: bool = False) -> TilingSpec:
     """The MHD halo kernels at a production per-device shape, direct
     call. ``mhd_halo_blocks`` (the same fit the model and the slab
-    exchange share) raises at these sizes — the full-lane corner
-    segments bind — so the registered expectation is the refusal."""
+    exchange share) raises for the y-sharded window at these sizes —
+    the full-lane corner segments bind — so that registered
+    expectation is the refusal; the y-local window (z slabs only, the
+    (1, 1, N) mesh) tiles at 256^3 and is refused at 512^3."""
     from ..models.astaroth import FIELDS, MhdParams
     from ..ops.pallas_halo import (mhd_halo_blocks,
                                    mhd_substep01_halo_pallas,
                                    mhd_substep_halo_pallas)
 
     S = side
-    bz, _by = mhd_halo_blocks(S, S, 8, 32, 8, X=S, itemsize=4)
+    bz, _by = mhd_halo_blocks(S, S, 8, 32, 8, X=S, itemsize=4,
+                              y_local=y_local)
     prm = MhdParams()
     fields = {q: _f32((S, S, S)) for q in FIELDS}
-    slabs = {q: {"zlo": _f32((bz, S, S)), "zhi": _f32((bz, S, S)),
-                 "ylo": _f32((S + 2 * bz, 8, S)),
-                 "yhi": _f32((S + 2 * bz, 8, S))} for q in FIELDS}
+    slabs = {q: {"zlo": _f32((bz, S, S)), "zhi": _f32((bz, S, S))}
+             for q in FIELDS}
+    if not y_local:
+        for q in FIELDS:
+            slabs[q].update(ylo=_f32((S + 2 * bz, 8, S)),
+                            yhi=_f32((S + 2 * bz, 8, S)))
 
     def fn(fields, slabs):
         if pair:
@@ -2527,13 +2534,16 @@ def _tiling_targets() -> List[Target]:
             lambda s=side: _jacobi_halon_tiling_spec(s),
             expect=_TILING_EXPECT[
                 "ops.pallas_halo.jacobi7_halon_pallas[n=2]"][side]))
-        for pair, key in ((False, "ops.pallas_halo.mhd_substep_halo_pallas"),
-                          (True,
-                           "ops.pallas_halo.mhd_substep01_halo_pallas")):
-            targets.append(TilingTarget(
-                f"analysis.tiling.{key}[{side}]",
-                lambda p=pair, s=side: _mhd_halo_tiling_spec(p, s),
-                expect=_TILING_EXPECT[key][side]))
+        for pair in (False, True):
+            for y_local in (False, True):
+                key = ("ops.pallas_halo.mhd_substep"
+                       + ("01" if pair else "") + "_halo_pallas"
+                       + ("[y-local]" if y_local else ""))
+                targets.append(TilingTarget(
+                    f"analysis.tiling.{key}[{side}]",
+                    lambda p=pair, s=side, y=y_local:
+                        _mhd_halo_tiling_spec(p, s, y),
+                    expect=_TILING_EXPECT[key][side]))
     return targets
 
 
@@ -2561,6 +2571,12 @@ _TILING_EXPECT = {
                                                 512: "infeasible"},
     "ops.pallas_halo.mhd_substep01_halo_pallas": {256: "infeasible",
                                                   512: "infeasible"},
+    # the y-local window (y wrapped in-kernel, z slabs only): what the
+    # halo path runs on a (1, 1, N) mesh
+    "ops.pallas_halo.mhd_substep_halo_pallas[y-local]": {
+        256: "legal", 512: "infeasible"},
+    "ops.pallas_halo.mhd_substep01_halo_pallas[y-local]": {
+        256: "legal", 512: "infeasible"},
     # the RDMA overlap kernel stages its slab exchange buffers as
     # block-independent VMEM scratch: ~42 MB at 512^3/device — no
     # block shape can fix that; lane re-tiling is the named follow-up

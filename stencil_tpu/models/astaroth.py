@@ -492,7 +492,7 @@ class Astaroth:
             if (kernel == "halo"
                     or (kernel == "auto" and on_tpu()
                         and _fast_dtype_ok(self._dtype)
-                        and _blocks_feasible("halo"))):
+                        and _blocks_feasible("halo-overlap"))):
                 from ..utils.logging import LOG_INFO
                 self.kernel_path = "halo-overlap"
                 self._build_resident_step()
@@ -785,14 +785,25 @@ class Astaroth:
     def _fast_path_blocks(self, path: str):
         """(bz, by) the MHD Pallas kernels of ``path`` tile this shard
         with: the wrap planner's on one chip, the halo planner's on the
-        halo paths. Raises ``TilingInfeasibleError`` when none fits."""
+        halo paths — the y-local window's on the halo path when the
+        mesh's y axis has one device (``_halo_y_local``). Raises
+        ``TilingInfeasibleError`` when none fits."""
         if path == "wrap":
             from ..ops.pallas_mhd import _fit_blocks as fit
+            window = {}
         else:
             from ..ops.pallas_halo import mhd_halo_blocks as fit
+            window = {"y_local": self._halo_y_local(path)}
         local = self.dd.local_size
         return fit(local.z, local.y, *_BLOCK_CAPS, mhd_tile(self._dtype),
-                   X=local.x, itemsize=self._dtype.itemsize)
+                   X=local.x, itemsize=self._dtype.itemsize, **window)
+
+    def _halo_y_local(self, path: str) -> bool:
+        """Whether the halo path's kernels wrap y in-kernel and take z
+        slabs only: on a mesh whose y axis has one device, where y
+        slabs would cross no wire. The RDMA overlap kernels keep the
+        y-sharded window."""
+        return path == "halo" and mesh_dim(self.dd.mesh).y == 1
 
     def _build_resident_step(self) -> None:
         """The interior-resident MHD Pallas fast paths (``kernel_path``
@@ -832,6 +843,7 @@ class Astaroth:
         dt = prm.dt
         tile = mhd_tile(self._dtype)   # 8 f32/f64, 16 bf16 slabs
         bz, by = self._fast_path_blocks(path)
+        y_local = self._halo_y_local(path)
         if path == "wrap":
             from ..ops.pallas_mhd import (mhd_substep01_wrap_pallas,
                                           mhd_substep_wrap_pallas)
@@ -852,7 +864,8 @@ class Astaroth:
                 def slabs(f, rows):
                     return {q: exchange_interior_slabs(
                         f[q], counts, rz=bz, ry=tile, radius_rows=rows,
-                        y_z_extended=True) for q in FIELDS}
+                        y_z_extended=True, z_only=y_local)
+                        for q in FIELDS}
 
                 def substep(f, w, s, write_w=True):
                     return mhd_substep_halo_pallas(
@@ -913,7 +926,8 @@ class Astaroth:
             # the pair does one radius-2R + one radius-R extended slab
             # round, the substep kernels three radius-R rounds (the
             # overlap path issues the same rounds in-kernel)
-            self._slab_exchange_cfg = dict(rz=bz, ry=tile, pair=pair_on)
+            self._slab_exchange_cfg = dict(rz=bz, ry=tile, pair=pair_on,
+                                           z_only=y_local)
         # interior-resident state between calls: step()-per-iteration
         # loops would otherwise pay extract+insert (3 extra full-field
         # HBM passes) every iteration. dd.curr is materialized lazily
@@ -971,6 +985,8 @@ class Astaroth:
             n = counts.flatten() * len(FIELDS)
 
             def rnd(r):
+                # z-only slabs (cfg["z_only"]) run on a one-device y
+                # axis, whose y slabs this already prices at zero
                 return interior_slab_bytes(shard, counts, r, item,
                                            y_z_extended=True) * n
 
@@ -1020,7 +1036,8 @@ class Astaroth:
                     self.dd.mesh, self.dd.local_size, self._dtype,
                     rz=cfg["rz"], ry=cfg.get("ry", ESUB),
                     radius_rows=r,
-                    y_z_extended=True, nfields=len(FIELDS), reps=reps)
+                    y_z_extended=True, nfields=len(FIELDS), reps=reps,
+                    z_only=cfg["z_only"])
 
             if cfg["pair"]:
                 return rnd(2 * HALO_R) + rnd(HALO_R)

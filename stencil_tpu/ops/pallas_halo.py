@@ -26,7 +26,9 @@ Layout contract (all even-grid; ESUB = 8 sublane tile):
   minus-neighbor's top rz rows, hi slab the plus-neighbor's bottom rz);
 * y slabs: (Z, ry, X) for Jacobi, (Z + 2*rz, ry, X) for MHD — the MHD
   variant is z-extended so yz edge/corner data rides along (the
-  sequential-sweep corner rule, SURVEY.md section 7 step 3);
+  sequential-sweep corner rule, SURVEY.md section 7 step 3). Where the
+  mesh's y axis has one device the MHD kernels take no y slabs and
+  wrap y in-kernel (the y-local window, ``mhd_substep_haloz``);
 * x is NOT mesh-sharded (mesh x-axis must be 1): the lane dimension is
   the worst axis to cut on TPU, so the orchestrator prefers z/y
   decompositions and the periodic x wrap stays in-kernel
@@ -575,16 +577,22 @@ def jacobi7_halo2_pallas(interior: jnp.ndarray,
                                 interpret=interpret)
 
 
-def _mhd_halo_elems(esub: int, rr: int = R, nf: int = 8):
+def _mhd_halo_elems(esub: int, rr: int = R, nf: int = 8,
+                    y_local: bool = False):
     """Per-lane-column element model of one MHD halo-kernel grid step
     (``_mhd_window_plan`` segments x ``nf`` fields, the worst-case
-    substep: w read + both output sweeps): main + 2rr in-shard single
-    rows + 2rr slab single rows + 4 esub-col y slabs + 12 esub^2
-    corner segments per field."""
+    substep: w read + both output sweeps). Per field: main + 2rr
+    in-shard single rows + 2rr slab single rows + 4 esub-col y slabs +
+    12 esub^2 corner segments; with ``y_local``, the wrap kernel's
+    (bz + 2rr)(by + 2esub) window plus the 2rr slab rows at the same
+    width, (bz + 4rr)(by + 2esub)."""
 
     def elems(bz: int, by: int):
-        per_field = (bz * by + 4 * rr * by + 4 * bz * esub
-                     + 12 * esub * esub)
+        if y_local:
+            per_field = (bz + 4 * rr) * (by + 2 * esub)
+        else:
+            per_field = (bz * by + 4 * rr * by + 4 * bz * esub
+                         + 12 * esub * esub)
         ein = nf * (per_field + bz * by)     # fields + w
         return ein, 2 * nf * bz * by, 0      # f and w outputs
 
@@ -594,7 +602,8 @@ def _mhd_halo_elems(esub: int, rr: int = R, nf: int = 8):
 def mhd_halo_blocks(Z: int, Y: int, block_z: int = 8,
                     block_y: int = 32, esub: int = ESUB,
                     X: "int | None" = None,
-                    itemsize: int = 4) -> Tuple[int, int]:
+                    itemsize: int = 4,
+                    y_local: bool = False) -> Tuple[int, int]:
     """The (bz, by) blocking the MHD halo kernel will use for a
     (Z, Y, ·) shard — exposed so the slab exchange can size its z slabs
     to match (zlo/zhi must be (bz, Y, X); see mhd_substep_halo_pallas).
@@ -603,22 +612,42 @@ def mhd_halo_blocks(Z: int, Y: int, block_z: int = 8,
     against the halo window plan's own byte model (the radius-R
     worst-case substep; the 2R pair kernels reuse the SAME blocks so
     slab shapes stay substep-invariant — their extra VMEM pressure is
-    pinned by the ``analysis.tiling`` production-size targets). Pass
-    ``X``/``itemsize`` to apply the VMEM budget; without ``X`` (legacy
-    callers) only alignment/divisibility constrain, which at budget-
-    irrelevant sizes chooses identical shapes."""
+    pinned by the ``analysis.tiling`` production-size targets).
+    ``y_local`` prices the window that wraps y in-kernel (z slabs
+    only; a mesh whose y axis has one device). Pass ``X``/``itemsize``
+    to apply the VMEM budget; without ``X`` (legacy callers) only
+    alignment/divisibility constrain, which at budget-irrelevant sizes
+    chooses identical shapes."""
     from ..analysis.tiling import plan_blocks
 
     budget_x = X if X is not None else 1  # X=1: budget never binds
-    return plan_blocks("mhd_substep_halo_pallas", Z, Y, budget_x,
-                       itemsize, _mhd_halo_elems(esub),
+    name = "mhd_substep_haloz_pallas" if y_local \
+        else "mhd_substep_halo_pallas"
+    return plan_blocks(name, Z, Y, budget_x, itemsize,
+                       _mhd_halo_elems(esub, y_local=y_local),
                        n_streams=8, sublane_z=esub, sublane_y=esub,
                        cap_z=block_z, cap_y=block_y).blocks()
 
 
+def _mhd_slab_blocks(slabs, Z: int, Y: int, X: int, esub: int,
+                     itemsize: int, block_z: int, block_y: int
+                     ) -> Tuple[bool, int, int]:
+    """(y_local, bz, by) of an MHD halo kernel call: slabs without
+    ``ylo``/``yhi`` select the y-local window; the blocks are the ones
+    the exchange sized its slabs by, which the asserts hold it to."""
+    y_local = not any("ylo" in s for s in slabs.values())
+    bz, by = mhd_halo_blocks(Z, Y, block_z, block_y, esub, X=X,
+                             itemsize=itemsize, y_local=y_local)
+    for s in slabs.values():
+        assert s["zlo"].shape == (bz, Y, X), s["zlo"].shape
+        if not y_local:
+            assert s["ylo"].shape == (Z + 2 * bz, esub, X), s["ylo"].shape
+    return y_local, bz, by
+
+
 def _mhd_window_plan(Z: int, Y: int, X: int, bz: int, by: int,
                      rr: int = R, slabless: bool = False,
-                     esub: int = ESUB):
+                     esub: int = ESUB, y_local: bool = False):
     """One closed unit (specs, inputs_for_field, select_window) for the
     MHD halo kernel's per-field stencil neighborhood on the slab
     layout — the spec list, the matching input ordering, and the
@@ -626,6 +655,13 @@ def _mhd_window_plan(Z: int, Y: int, X: int, bz: int, by: int,
     is registered once with its source kind and index), so they cannot
     desynchronize. Mirrors ops/pallas_mhd._window_plan for the wrap
     kernel.
+
+    ``y_local`` (the shard's y mesh axis has one device, so its y
+    neighbours are its own wrapped edge): y wraps in-kernel through
+    the wrap kernel's modular y segments, and only the 2rr single
+    z-neighbour rows keep a slab source (``zlo``/``zhi``, each with
+    its own three y segments) — no y slabs, no corner sources. The
+    rest of this docstring describes the y-sharded plan.
 
     ``rr`` is the window radius: R for one substep, 2R for the fused
     substep-0+1 pair (ring recompute). Needs rr <= esub (slab buffers
@@ -692,6 +728,60 @@ def _mhd_window_plan(Z: int, Y: int, X: int, bz: int, by: int,
         kinds.append(kind)
         return len(specs) - 1
 
+    def inputs_for_field(f, slabs=None):
+        """Input arrays matching ``specs`` order (``slabs`` unused —
+        and optional — in slabless mode)."""
+        return [f if k == "f" else slabs[k] for k in kinds]
+
+    if y_local:
+        from .pallas_mhd import _YSEGS, y_segment
+
+        ysegs = [y_segment(ys, Y, by, esub) for ys in _YSEGS]
+
+        def row3(kind, zrows, zf, edge=None):
+            """The three y segments of z rows ``zf(kz)``; a slab row
+            (``edge`` set) pins to y block 0 off its edge grid row."""
+            return [add(kind, (zrows, yr, X),
+                        lambda kz, ky, yf=yf: (
+                            zf(kz), yf(ky) if edge is None
+                            else jnp.where(kz == edge, yf(ky), 0), 0))
+                    for yr, yf in ysegs]
+
+        # (in-shard, slab) row triples below the block, the block's
+        # own rows, then the pairs above (z order of the window)
+        zm = [(row3("f", 1, lambda kz, o=o: jnp.clip(kz * bz + o, 0,
+                                                      Z - 1)),
+               row3("zlo", 1, lambda kz, o=o: bz + o, edge=0))
+              for o in range(-rr, 0)]
+        z0 = row3("f", bz, lambda kz: kz)
+        zp = [(row3("f", 1, lambda kz, j=j: jnp.clip(kz * bz + bz + j, 0,
+                                                      Z - 1)),
+               row3("zhi", 1, lambda kz, j=j: j, edge=nzg - 1))
+              for j in range(rr)]
+
+        def select_local(refs) -> jnp.ndarray:
+            """(bz+2rr, by+2rr, X) window: y wrapped by the segment
+            maps, the z rows past the shard edge from the slabs."""
+            kz = pl.program_id(0)
+
+            def seg(i, s, at_edge):
+                v = refs[i][...]
+                return v if s is None else jnp.where(at_edge,
+                                                     refs[s][...], v)
+
+            def row(idx3, slab3=(None,) * 3, at_edge=None):
+                ym, y0, yp = (seg(i, s, at_edge)
+                              for i, s in zip(idx3, slab3))
+                return jnp.concatenate(
+                    [ym[:, esub - rr:], y0, yp[:, :rr]], axis=1)
+
+            rows = [row(i3, s3, kz == 0) for i3, s3 in zm]
+            rows.append(row(z0))
+            rows.extend(row(i3, s3, kz == nzg - 1) for i3, s3 in zp)
+            return jnp.concatenate(rows, axis=0)
+
+        return specs, inputs_for_field, select_local
+
     i_main = add("f", (bz, by, X), lambda kz, ky: (kz, ky, 0))
     # zm_y0: exact-radius single rows z = kz*bz + o, o in -rr..-1
     # (in-shard clamped), with zlo slab rows bz+o fetched at kz==0;
@@ -748,11 +838,6 @@ def _mhd_window_plan(Z: int, Y: int, X: int, bz: int, by: int,
                                              clampY(ky), 0), 0)),
             add("yhi", (esub, esub, X),
                 lambda kz, ky: ((kz + 2) * bzb, 0, 0)))
-
-    def inputs_for_field(f, slabs=None):
-        """Input arrays matching ``specs`` order (``slabs`` unused —
-        and optional — in slabless mode)."""
-        return [f if k == "f" else slabs[k] for k in kinds]
 
     def select_window(refs, kz=None, ky=None) -> jnp.ndarray:
         """Assemble one field's (bz+2rr, by+2rr, X) stencil window from
@@ -847,7 +932,11 @@ def mhd_substep_halo_pallas(fields: Dict[str, jnp.ndarray],
     itemsize=...)`` — pass the SAME ``X``/``itemsize`` the kernel sees
     (it recomputes the blocking internally with them; a budget-bound
     fit without them would size the slabs differently and trip the
-    shape asserts). Returns (new_fields, new_w).
+    shape asserts). Slabs without ``ylo``/``yhi`` (``z_only=True`` at
+    the exchange, for a mesh whose y axis has one device) select the
+    y-local window: y wraps in-kernel, blocks from
+    ``mhd_halo_blocks(..., y_local=True)``, and the call is named
+    ``mhd_substep_haloz``. Returns (new_fields, new_w).
 
     Dead-w elision as in ``mhd_substep_wrap_pallas``: ``w=None`` drops
     the w read sweep (only valid at alpha_s == 0, i.e. substep 0);
@@ -866,12 +955,9 @@ def mhd_substep_halo_pallas(fields: Dict[str, jnp.ndarray],
     dtype = fields[FIELDS[0]].dtype
     esub = mhd_tile(dtype)
     comp = compute_dtype(dtype)
-    bz, by = mhd_halo_blocks(Z, Y, block_z, block_y, esub, X=X,
-                             itemsize=jnp.dtype(dtype).itemsize)
-    for q in FIELDS:
-        assert slabs[q]["zlo"].shape == (bz, Y, X), slabs[q]["zlo"].shape
-        assert slabs[q]["ylo"].shape == (Z + 2 * bz, esub, X), \
-            slabs[q]["ylo"].shape
+    y_local, bz, by = _mhd_slab_blocks(slabs, Z, Y, X, esub,
+                                       jnp.dtype(dtype).itemsize,
+                                       block_z, block_y)
     inv_ds = (1.0 / prm.dsx, 1.0 / prm.dsy, 1.0 / prm.dsz)
     alpha = float(RK3_ALPHA[s])
     beta = float(RK3_BETA[s])
@@ -883,7 +969,7 @@ def mhd_substep_halo_pallas(fields: Dict[str, jnp.ndarray],
     nzg = Z // bz
     nyg = Y // by
     field_specs, inputs_for_field, select_window = _mhd_window_plan(
-        Z, Y, X, bz, by, esub=esub)
+        Z, Y, X, bz, by, esub=esub, y_local=y_local)
     nseg = len(field_specs)
     nf = len(FIELDS)
     nw = 0 if w is None else nf
@@ -927,7 +1013,7 @@ def mhd_substep_halo_pallas(fields: Dict[str, jnp.ndarray],
 
     outs = pl.pallas_call(
         kern,
-        name="mhd_substep_halo",
+        name="mhd_substep_haloz" if y_local else "mhd_substep_halo",
         grid=(nzg, nyg),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -970,7 +1056,8 @@ def mhd_substep01_halo_pallas(fields: Dict[str, jnp.ndarray],
     counts, rz=bz, ry=esub, radius_rows=2*R, y_z_extended=True)`` —
     2R valid rows, not R (the window reaches 2R across shard edges) —
     with bz from ``mhd_halo_blocks(..., X=X, itemsize=...)`` exactly
-    as the single-substep kernel documents.
+    as the single-substep kernel documents, which also says how z-only
+    slabs select the y-local window (``mhd_substep01_haloz``).
     Needs 2R <= min(bz, esub) (6 <= 8). Returns (new_fields, new_w).
     """
     from ..models.astaroth import FIELDS
@@ -982,17 +1069,14 @@ def mhd_substep01_halo_pallas(fields: Dict[str, jnp.ndarray],
     dtype = fields[FIELDS[0]].dtype
     from .pallas_mhd import mhd_tile
     esub = mhd_tile(dtype)
-    bz, by = mhd_halo_blocks(Z, Y, block_z, block_y, esub, X=X,
-                             itemsize=jnp.dtype(dtype).itemsize)
+    y_local, bz, by = _mhd_slab_blocks(slabs, Z, Y, X, esub,
+                                       jnp.dtype(dtype).itemsize,
+                                       block_z, block_y)
     assert R2 <= esub and R2 <= bz, (R2, esub, bz)
-    for q in FIELDS:
-        assert slabs[q]["zlo"].shape == (bz, Y, X), slabs[q]["zlo"].shape
-        assert slabs[q]["ylo"].shape == (Z + 2 * bz, esub, X), \
-            slabs[q]["ylo"].shape
     nzg = Z // bz
     nyg = Y // by
     field_specs, inputs_for_field, select_window = _mhd_window_plan(
-        Z, Y, X, bz, by, rr=R2, esub=esub)
+        Z, Y, X, bz, by, rr=R2, esub=esub, y_local=y_local)
     nseg = len(field_specs)
     nf = len(FIELDS)
 
@@ -1022,7 +1106,7 @@ def mhd_substep01_halo_pallas(fields: Dict[str, jnp.ndarray],
 
     outs = pl.pallas_call(
         kern,
-        name="mhd_substep01_halo",
+        name="mhd_substep01_haloz" if y_local else "mhd_substep01_halo",
         grid=(nzg, nyg),
         in_specs=in_specs,
         out_specs=out_specs,

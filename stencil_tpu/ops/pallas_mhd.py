@@ -59,6 +59,21 @@ def mhd_tile(dtype) -> int:
 _YSEGS = (-1, 0, 1)
 
 
+def y_segment(yseg: int, Y: int, by: int, esub: int):
+    """(rows, block index map over ky) of one periodic y segment of a
+    (by)-row block: the esub-row slab before it (``yseg`` -1), the
+    block itself (0), or the esub-row slab after it (+1), wrapped
+    modulo ``Y`` — the y half of every window plan that wraps y
+    in-kernel."""
+    nyb = Y // esub
+    byb = by // esub
+    if yseg == 0:
+        return by, (lambda ky: ky)
+    if yseg < 0:
+        return esub, (lambda ky: (ky * byb - 1) % nyb)
+    return esub, (lambda ky: (ky * byb + byb) % nyb)
+
+
 def _window_plan(Z: int, Y: int, X: int, bz: int, by: int,
                  rr: int = R, esub: int = ESUB):
     """(specs, assemble) for one field's (bz+2rr, by+2rr, X)
@@ -76,8 +91,6 @@ def _window_plan(Z: int, Y: int, X: int, bz: int, by: int,
     (1 + 2rr/bz) * (1 + 2*esub/by).
     """
     assert rr <= esub, (rr, esub)   # y slabs are one esub tile wide
-    nyb = Y // esub
-    byb = by // esub
     zsegs = tuple(range(-rr, 0)) + (0,) + tuple(range(1, rr + 1))
 
     def zy(zseg: int, yseg: int):
@@ -88,12 +101,7 @@ def _window_plan(Z: int, Y: int, X: int, bz: int, by: int,
             # or kz*bz + bz + zseg - 1 (above); block units == elements
             off = zseg if zseg < 0 else bz + zseg - 1
             zshape, zidx = 1, (lambda kz, o=off: (kz * bz + o) % Z)
-        if yseg == 0:
-            yshape, yidx = by, (lambda ky: ky)
-        elif yseg < 0:
-            yshape, yidx = esub, (lambda ky: (ky * byb - 1) % nyb)
-        else:
-            yshape, yidx = esub, (lambda ky: (ky * byb + byb) % nyb)
+        yshape, yidx = y_segment(yseg, Y, by, esub)
         return pl.BlockSpec(
             (zshape, yshape, X),
             functools.partial(lambda kz, ky, zf, yf: (zf(kz), yf(ky), 0),

@@ -409,7 +409,8 @@ def accumulate_shard(arr: jnp.ndarray, radius: Radius,
 def exchange_interior_slabs(p: jnp.ndarray, mesh_counts: Dim3,
                             rz: int, ry: int, radius_rows: int = 0,
                             y_z_extended: bool = False,
-                            rem: Dim3 = Dim3(0, 0, 0)
+                            rem: Dim3 = Dim3(0, 0, 0),
+                            z_only: bool = False
                             ) -> Dict[str, jnp.ndarray]:
     """Exchange halo SLABS of one interior-resident (unpadded) shard —
     the data plane of the fused halo kernels (ops/pallas_halo.py).
@@ -442,6 +443,10 @@ def exchange_interior_slabs(p: jnp.ndarray, mesh_counts: Dim3,
     come from the shard's ACTUAL last interior rows (dynamic slice at
     ``shard_interior_len - r``, the partition.hpp:55-69 rule); lo-edge
     sends start at 0 regardless. Not supported with ``y_z_extended``.
+
+    ``z_only``: return ``zlo``/``zhi`` alone, for a caller that wraps
+    y in-core (the MHD halo kernels' y-local window); needs a y mesh
+    axis of one device, where the y slabs would cross no wire.
     """
     Z = p.shape[0]
     Y = p.shape[1]
@@ -453,6 +458,7 @@ def exchange_interior_slabs(p: jnp.ndarray, mesh_counts: Dim3,
     uneven = rem != Dim3(0, 0, 0)
     assert not (uneven and y_z_extended), \
         "uneven shards unsupported with z-extended y slabs"
+    assert not z_only or ny == 1, "z-only slabs need an unsharded y axis"
     dt = p.dtype
 
     def zfill(n, yext):
@@ -473,6 +479,16 @@ def exchange_interior_slabs(p: jnp.ndarray, mesh_counts: Dim3,
         ztop = lax.slice_in_dim(p, Z - r, Z, axis=0)
     zlo_r = _shift_from_minus(ztop, "z", nz)
     zhi_r = _shift_from_plus(lax.slice_in_dim(p, 0, r, axis=0), "z", nz)
+
+    def zembed(recv, align_hi: bool):
+        if rz == r:
+            return recv
+        pad = zfill(rz - r, Y)
+        return jnp.concatenate([pad, recv] if align_hi else [recv, pad],
+                               axis=0)
+
+    if z_only:
+        return {"zlo": zembed(zlo_r, True), "zhi": zembed(zhi_r, False)}
     if y_z_extended:
         # this shard's y-edge columns spanning z in [-r, Z+r): own
         # interior plus the just-received z slabs (corner ride-along)
@@ -498,11 +514,8 @@ def exchange_interior_slabs(p: jnp.ndarray, mesh_counts: Dim3,
         zoff = 0
     ylo_r = _shift_from_minus(ysrc_hi(), "y", ny)
     yhi_r = _shift_from_plus(ysrc_lo(), "y", ny)
-
-    zlo = (zlo_r if rz == r
-           else jnp.concatenate([zfill(rz - r, Y), zlo_r], axis=0))
-    zhi = (zhi_r if rz == r
-           else jnp.concatenate([zhi_r, zfill(rz - r, Y)], axis=0))
+    zlo = zembed(zlo_r, True)
+    zhi = zembed(zhi_r, False)
 
     def yembed(recv, align_hi: bool):
         out = recv
@@ -859,7 +872,8 @@ def interior_slab_bytes(shard_zyx: Sequence[int], mesh_counts: Dim3,
 def measure_slab_exchange_seconds(mesh: Mesh, local: Dim3, dtype,
                                   rz: int, ry: int, radius_rows: int,
                                   y_z_extended: bool, nfields: int = 1,
-                                  reps: int = 10) -> float:
+                                  reps: int = 10,
+                                  z_only: bool = False) -> float:
     """Time ONE standalone ``exchange_interior_slabs`` round for
     ``nfields`` interior-resident fields over ``mesh`` — the honest
     exchange-cost estimate for the fused fast paths, which perform
@@ -882,24 +896,26 @@ def measure_slab_exchange_seconds(mesh: Mesh, local: Dim3, dtype,
     make = jax.jit(lambda: jnp.zeros((dim.z, dim.y, dim.x), dtype),
                    out_shardings=sharding)
     fields = [make() for _ in range(nfields)]
+    nslabs = 2 if z_only else 4
 
     def shard_fn(*fs):
         outs = []
         for f in fs:
             s = exchange_interior_slabs(f, counts, rz=rz, ry=ry,
                                         radius_rows=radius_rows,
-                                        y_z_extended=y_z_extended)
-            # ALL four slabs are outputs: returning only zlo would let
+                                        y_z_extended=y_z_extended,
+                                        z_only=z_only)
+            # ALL slabs are outputs: returning only zlo would let
             # XLA dead-code-eliminate the y-axis ppermutes (zlo depends
             # on the z shift alone) and the timing would silently drop
             # the y-face traffic
-            outs.extend([s["zlo"], s["zhi"], s["ylo"], s["yhi"]])
+            outs.extend(s.values())
         return tuple(outs)
 
     spec = P("z", "y", "x")
     fn = jax.jit(jax.shard_map(shard_fn, mesh=mesh,
                                in_specs=(spec,) * nfields,
-                               out_specs=(spec,) * (4 * nfields),
+                               out_specs=(spec,) * (nslabs * nfields),
                                check_vma=False))
     out = fn(*fields)
     device_sync(out[0])

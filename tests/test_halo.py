@@ -224,7 +224,11 @@ class TestAstarothHalo:
         # rr=2R window), the (1,1,1) case (nzg=4) exercises in-shard
         # rr=6 rows
         ((1, 2, 4), True),
-        pytest.param((1, 1, 1), True, marks=pytest.mark.slow)])
+        pytest.param((1, 1, 1), True, marks=pytest.mark.slow),
+        # the benchmark's mesh: y local, so the y-local window wraps y
+        # in-kernel and reads z slabs only (mhd_substep_haloz)
+        ((1, 1, 4), False),
+        ((1, 1, 4), True)])
     def test_halo_matches_xla(self, mesh_shape, pair):
         from stencil_tpu.models.astaroth import FIELDS, Astaroth
 
@@ -236,6 +240,7 @@ class TestAstarothHalo:
                      devices=jax.devices()[:ndev], kernel="halo",
                      exchange_every=2 if pair else None)
         assert b._slab_exchange_cfg["pair"] == pair
+        assert b._slab_exchange_cfg["z_only"] == (mesh_shape[1] == 1)
         for m in (a, b):
             m.init()
             m.step()

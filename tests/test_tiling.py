@@ -170,11 +170,35 @@ def _mhd_wrap_fn(side, dtype):
     return fn, tuple(_f((side, side, side), dtype) for _ in FIELDS)
 
 
+def _mhd_haloz_fn(side, dtype):
+    """The MHD halo kernel's y-local window (z slabs only) in its
+    worst-case substep: w read and written."""
+    from stencil_tpu.models.astaroth import FIELDS, MhdParams
+    from stencil_tpu.ops.pallas_halo import (mhd_halo_blocks,
+                                             mhd_substep_halo_pallas)
+    from stencil_tpu.ops.pallas_mhd import mhd_tile
+
+    prm = MhdParams()
+    bz, _by = mhd_halo_blocks(side, side, 8, 32, mhd_tile(dtype),
+                              X=side, itemsize=jnp.dtype(dtype).itemsize,
+                              y_local=True)
+    field = _f((side, side, side), dtype)
+    slab = _f((bz, side, side), dtype)
+
+    def fn(fields, w, slabs):
+        return mhd_substep_halo_pallas(fields, w, slabs, 1, prm, prm.dt,
+                                       interpret=False)
+
+    return fn, ({q: field for q in FIELDS}, {q: field for q in FIELDS},
+                {q: {"zlo": slab, "zhi": slab} for q in FIELDS})
+
+
 _FAMILIES = {
     "wrap": lambda side, dtype: _wrap_fn(side, dtype, 1),
     "wrapn2": lambda side, dtype: _wrap_fn(side, dtype, 2),
     "halo": _halo_fn,
     "mhd_wrap": _mhd_wrap_fn,
+    "mhd_haloz": _mhd_haloz_fn,
 }
 
 
@@ -188,9 +212,9 @@ def test_planner_shapes_round_trip_through_vmem_audit(family, side,
     PHYSICAL budget (declared vmem_limit raises ignored); where the
     planner refuses, the refusal names its binding constraint — never
     a silent shrink, never an audit failure."""
-    if family in ("wrapn2", "mhd_wrap") and side == 17:
+    if family in ("wrapn2", "mhd_wrap", "mhd_haloz") and side == 17:
         pytest.skip("kernel requires sublane-divisible Y (model gates)")
-    if family == "mhd_wrap" and side == 8:
+    if family in ("mhd_wrap", "mhd_haloz") and side == 8:
         side = 16  # 8^3 leaves no room for the radius-3 window ring
     from stencil_tpu.ops.pallas_stencil import sublane_tile
 
@@ -274,6 +298,51 @@ def test_snippets_512_failure_flagged_and_prescription_clean():
     reset_warnings()
     assert audit(16, 128), "the SNIPPETS shape must be flagged"
     assert audit(None, None) == [], "the prescribed shape must be clean"
+
+
+def test_mhd_halo_y_local_window_fits_at_256():
+    """At a 256^3 float32 shard the y-sharded MHD halo window (y slabs
+    and yz corner segments for every field) stages more than the
+    selection budget; the y-local window (y wrapped in-kernel, z slabs
+    only) tiles at (8, 8) in (8 + 4R)(8 + 16) elements a field a lane
+    column: 11,010,048 B. At 512^3 neither fits."""
+    from stencil_tpu.ops.pallas_halo import (_mhd_halo_elems,
+                                             mhd_halo_blocks)
+
+    S = 256
+    assert mhd_halo_blocks(S, S, 8, 32, 8, X=S, itemsize=4,
+                           y_local=True) == (8, 8)
+    ein, eout, held = _mhd_halo_elems(8, y_local=True)(8, 8)
+    footprint = 4 * S * (2 * (ein + eout) + held)
+    assert footprint == 11_010_048 <= TILE_SELECT_BUDGET_BYTES
+    with pytest.raises(TilingInfeasibleError, match="binding constraint"):
+        mhd_halo_blocks(S, S, 8, 32, 8, X=S, itemsize=4)
+    for y_local in (False, True):
+        with pytest.raises(TilingInfeasibleError):
+            mhd_halo_blocks(512, 512, 8, 32, 8, X=512, itemsize=4,
+                            y_local=y_local)
+
+
+def test_astaroth_halo_blocks_on_the_benchmark_mesh():
+    """``Astaroth._fast_path_blocks`` at the four-chip weak cell's
+    256x256x1024 grid on a (1, 1, 4) mesh: the halo path finds the
+    y-local window's blocks, so ``kernel="auto"`` takes it; the RDMA
+    overlap kernels keep the y-sharded window and stay infeasible.
+    Built without realizing the domain (no 256^3 fields)."""
+    from types import SimpleNamespace
+
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.models.astaroth import Astaroth
+    from stencil_tpu.parallel.mesh import make_mesh
+
+    m = object.__new__(Astaroth)
+    m.dd = SimpleNamespace(local_size=Dim3(256, 256, 256),
+                           mesh=make_mesh((1, 1, 4), jax.devices()[:4]))
+    m._dtype = np.dtype(np.float32)
+    assert m._halo_y_local("halo")
+    assert m._fast_path_blocks("halo") == (8, 8)
+    with pytest.raises(TilingInfeasibleError):
+        m._fast_path_blocks("halo-overlap")
 
 
 def test_block_shape_never_changes_numerics():

@@ -306,6 +306,57 @@ def test_jacobi_halo_run_loop_4chip_has_no_carry_copy(topo):
         assert not any(shard.search(line) for line in comps[name]), name
 
 
+def test_mhd_halo_run_loop_4chip_program(topo):
+    """The loop program Astaroth(256 x 256 x 1024).run(n) compiles on
+    four chips as the halo path builds it: mesh (1, 1, 4), y local, so
+    each substep exchanges radius-3 z slabs only and runs the y-local
+    window (``mhd_substep_haloz``, blocks (8, 8)) on each 256^3 shard.
+    The while body holds the three substep calls and no copy of a
+    shard."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.models.astaroth import (FIELDS, MhdParams,
+                                             resident_loop)
+    from stencil_tpu.ops.pallas_halo import (R, mhd_halo_blocks,
+                                             mhd_substep_halo_pallas)
+    from stencil_tpu.parallel.exchange import exchange_interior_slabs
+    from stencil_tpu.parallel.mesh import make_mesh
+    from stencil_tpu.partition import partition_dims_even_xfree
+
+    n, chips, tile = 256, 4, 8
+    counts = partition_dims_even_xfree(Dim3(n, n, n * chips), chips)
+    assert tuple(counts) == (1, 1, 4)
+    bz, by = mhd_halo_blocks(n, n, 8, 32, tile, X=n, itemsize=4,
+                             y_local=True)
+    assert (bz, by) == (8, 8)
+    prm = MhdParams()
+    mesh = make_mesh(counts, topo.devices[:chips])
+
+    def substep(f, w, s, write_w=True):
+        slabs = {q: exchange_interior_slabs(f[q], counts, rz=bz, ry=tile,
+                                            radius_rows=R, z_only=True)
+                 for q in FIELDS}
+        return mhd_substep_halo_pallas(f, w, slabs, s, prm, prm.dt,
+                                       write_w=write_w, interpret=False)
+
+    spec = {q: P("z", "y", "x") for q in FIELDS}
+    program = jax.shard_map(resident_loop(substep), mesh=mesh,
+                            in_specs=(spec, spec, P()),
+                            out_specs=(spec, spec), check_vma=False)
+    sharding = NamedSharding(mesh, P("z", "y", "x"))
+    fields = {q: _spec((n * chips, n, n), sharding) for q in FIELDS}
+    compiled = _compile(program, fields, dict(fields),
+                        _spec((), NamedSharding(mesh, P()), jnp.int32),
+                        kernel="mhd_substep_haloz", donate=(0, 1))
+    comps, bodies = _while_bodies(compiled)
+    assert sorted(_calls(comps[b], "mhd_substep_haloz") for b in bodies) \
+        == [3]
+    shard = re.compile(r"= f32\[256,256,256\]\S* copy\(")
+    for name in bodies:
+        assert not any(shard.search(line) for line in comps[name]), name
+
+
 
 def test_jacobi_wrap_single_step(one_chip):
     """The single-step wrap kernel (the tail of an odd run(n))."""
