@@ -2295,20 +2295,27 @@ def _jacobi7_plane_vmem_spec(side: int = 8) -> VmemSpec:
 
 
 def _jacobi_wrap_vmem_spec(steps: int, side: int = 16) -> VmemSpec:
+    """The wrap kernels as the model runs them on a side^3 grid: a side
+    that is no multiple of the sublane tile is stepped in the stored
+    extent ``wrap_layout`` pads it to, wrapped at ``side``."""
+    import jax.numpy as jnp
+
     from ..ops.pallas_stencil import (jacobi7_wrap_pallas,
-                                      jacobi7_wrapn_pallas)
+                                      jacobi7_wrapn_pallas, wrap_layout)
 
     hot = (side // 4, side // 2, side // 2)
     cold = (3 * side // 4, side // 2, side // 2)
     r = side // 8
+    stored_y, _n = wrap_layout(side, side, side, jnp.float32, steps)
 
     def fn(q):
         if steps == 1:
-            return jacobi7_wrap_pallas(q, hot, cold, r, interpret=False)
+            return jacobi7_wrap_pallas(q, hot, cold, r, interpret=False,
+                                       y_extent=side)
         return jacobi7_wrapn_pallas(q, hot, cold, r, steps=steps,
-                                    interpret=False)
+                                    interpret=False, y_extent=side)
 
-    return VmemSpec(fn=fn, args=(_f32((side, side, side)),))
+    return VmemSpec(fn=fn, args=(_f32((side, stored_y, side)),))
 
 
 def _mhd_wrap_vmem_spec(pair: bool, side: int = 16) -> VmemSpec:
@@ -2499,8 +2506,8 @@ def _mhd_halo_tiling_spec(pair: bool, side: int,
 def _tiling_targets() -> List[Target]:
     targets: List[Target] = []
 
-    def vmem_backed(prefix: str, build_for_side):
-        for side in _TILING_SIDES:
+    def vmem_backed(prefix: str, build_for_side, sides=_TILING_SIDES):
+        for side in sides:
             targets.append(TilingTarget(
                 f"analysis.tiling.{prefix}[{side}]",
                 lambda b=build_for_side, s=side:
@@ -2510,11 +2517,14 @@ def _tiling_targets() -> List[Target]:
     vmem_backed("ops.pallas_stencil.jacobi7_pallas",
                 _jacobi7_plane_vmem_spec)
     vmem_backed("ops.pallas_stencil.jacobi7_wrap_pallas",
-                lambda s: _jacobi_wrap_vmem_spec(1, s))
+                lambda s: _jacobi_wrap_vmem_spec(1, s),
+                _TILING_SIDES + _UNALIGNED_SIDES)
     vmem_backed("ops.pallas_stencil.jacobi7_wrapn_pallas[n=2]",
-                lambda s: _jacobi_wrap_vmem_spec(2, s))
+                lambda s: _jacobi_wrap_vmem_spec(2, s),
+                _TILING_SIDES + _UNALIGNED_SIDES)
     vmem_backed("ops.pallas_stencil.jacobi7_wrapn_pallas[n=4]",
-                lambda s: _jacobi_wrap_vmem_spec(4, s))
+                lambda s: _jacobi_wrap_vmem_spec(4, s),
+                _TILING_SIDES + _UNALIGNED_SIDES)
     vmem_backed("ops.pallas_halo.jacobi7_halo_pallas",
                 _jacobi_halo_kernel_spec)
     vmem_backed("ops.pallas_mhd.mhd_substep_wrap_pallas",
@@ -2548,6 +2558,9 @@ def _tiling_targets() -> List[Target]:
 
 
 _TILING_SIDES = (256, 512)
+#: the reference's weak-scaling share, 750^3 a rank: no multiple of the
+#: (8, 128) tile, so the Jacobi wrap kernels step it padded in y
+_UNALIGNED_SIDES = (750,)
 
 #: the registered per-size verdicts (see the block comment above);
 #: probed on this image and pinned — a kernel whose story changes must
@@ -2555,11 +2568,14 @@ _TILING_SIDES = (256, 512)
 _TILING_EXPECT = {
     "ops.pallas_stencil.jacobi7_pallas": {256: "legal", 512: "legal"},
     "ops.pallas_stencil.jacobi7_wrap_pallas": {256: "legal",
-                                               512: "legal"},
+                                               512: "legal",
+                                               750: "legal"},
     "ops.pallas_stencil.jacobi7_wrapn_pallas[n=2]": {256: "legal",
-                                                     512: "legal"},
+                                                     512: "legal",
+                                                     750: "legal"},
     "ops.pallas_stencil.jacobi7_wrapn_pallas[n=4]": {256: "legal",
-                                                     512: "legal"},
+                                                     512: "legal",
+                                                     750: "legal"},
     "ops.pallas_halo.jacobi7_halo_pallas": {256: "legal", 512: "legal"},
     "ops.pallas_halo.jacobi7_halon_pallas[n=2]": {256: "legal",
                                                   512: "legal"},

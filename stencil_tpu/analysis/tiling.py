@@ -12,7 +12,10 @@ it enumerates every legal candidate block shape —
   dim stays the full array extent in every shipped kernel, so lane
   alignment is the array's own;
 * grid-divisible: ``block_z | Z`` and ``block_y | Y`` (no ragged tail
-  tiles on the hot path);
+  tiles on the hot path); a kernel that wraps y at its logical extent
+  may instead step a grid whose Y is no multiple of the sublane tile
+  in a stored extent padded up to a multiple of ``block_y``
+  (``pad_y``), priced by the padded rows it moves;
 * double-buffer footprint under budget: streamed blocks x2 pipeline
   buffers (+ held in-kernel windows where the kernel's model declares
   them) within the PHYSICAL per-core VMEM (a raised
@@ -77,8 +80,12 @@ class ShapeOption:
     block_y: int
     footprint_bytes: int
     #: modeled HBM read amplification: streamed input bytes per
-    #: main-stream output element (1.0 = every input byte read once)
+    #: main-stream output element (1.0 = every input byte read once),
+    #: per logical row where the stored extent is padded
     amplification: float
+    #: the y extent the kernel steps: Y, or Y padded up to a multiple
+    #: of ``block_y`` (``plan_blocks(pad_y=True)``)
+    stored_y: int
 
     def to_dict(self) -> Dict:
         return dataclasses.asdict(self)
@@ -105,6 +112,12 @@ class TilingPlan:
     @property
     def best(self) -> Optional[ShapeOption]:
         return self.options[0] if self.options else None
+
+    @property
+    def stored_y(self) -> int:
+        """The prescribed stored y extent; raises like :meth:`blocks`."""
+        self.blocks()
+        return self.options[0].stored_y
 
     def blocks(self) -> Tuple[int, int]:
         """The prescribed (block_z, block_y); raises
@@ -139,7 +152,8 @@ def plan_blocks(kernel: str, Z: int, Y: int, X: int, itemsize: int,
                 cap_z: Optional[int] = None, cap_y: Optional[int] = None,
                 budget: int = TILE_SELECT_BUDGET_BYTES,
                 scratch_bytes: int = 0,
-                max_options: int = 8) -> TilingPlan:
+                max_options: int = 8,
+                pad_y: bool = False) -> TilingPlan:
     """Synthesize the ranked legal block shapes for one kernel.
 
     ``elems(bz, by) -> (in_elems, out_elems, held_elems)`` is the
@@ -156,15 +170,29 @@ def plan_blocks(kernel: str, Z: int, Y: int, X: int, itemsize: int,
     ceiling); ``sublane_*``/``min_z`` bound them below. An empty legal
     set yields ``options=[]`` with the binding constraint named in
     ``infeasible`` (:meth:`TilingPlan.blocks` raises it).
+
+    ``pad_y`` is for kernels that wrap y at the logical extent ``Y``
+    and step a stored array whose extra rows they never read as a
+    neighbour: where ``Y`` is no multiple of the sublane tile, every
+    aligned ``block_y`` is a candidate, with the stored extent
+    ``Y`` padded up to a multiple of it and the amplification scaled
+    by the padded rows (``ShapeOption.stored_y``). An aligned ``Y``
+    plans as without it.
     """
     esub = sublane_y if sublane_y is not None else sublane_tile(itemsize)
+    ragged = pad_y and Y % max(esub, 1) != 0
+    ylim = -(-Y // esub) * esub if ragged else Y
     # a ceiling below the alignment floor means "the smallest legal
     # shape" (bf16 doubles the sublane tile past the f32-sized caps)
     cz = min(max(cap_z, sublane_z, min_z), Z) if cap_z else Z
-    cy = min(max(cap_y, esub), Y) if cap_y else Y
+    cy = min(max(cap_y, esub), ylim) if cap_y else ylim
     bzs = [d for d in _divisors(Z)
            if d % max(sublane_z, 1) == 0 and min_z <= d <= cz]
-    bys = [d for d in _divisors(Y) if d % max(esub, 1) == 0 and d <= cy]
+    if ragged:
+        bys = list(range(esub, cy + 1, esub))
+    else:
+        bys = [d for d in _divisors(Y)
+               if d % max(esub, 1) == 0 and d <= cy]
     plan = TilingPlan(kernel=kernel, array_zyx=(Z, Y, X),
                       itemsize=itemsize, budget_bytes=int(budget),
                       options=[])
@@ -195,8 +223,12 @@ def plan_blocks(kernel: str, Z: int, Y: int, X: int, itemsize: int,
                 if best_over is None or footprint < best_over[0]:
                     best_over = (footprint, bz, by)
                 continue
+            stored = -(-Y // by) * by
             amp = float(ein) / float(max(n_streams, 1) * bz * by)
-            scored.append(ShapeOption(bz, by, footprint, round(amp, 4)))
+            if stored != Y:
+                amp *= stored / Y
+            scored.append(ShapeOption(bz, by, footprint, round(amp, 4),
+                                      stored))
     plan.over_budget = over
     if not scored:
         fp, bz, by = best_over  # at least one aligned candidate existed

@@ -516,13 +516,29 @@ class Jacobi3D:
         wrap_ok = (counts == Dim3(1, 1, 1) and rem == Dim3(0, 0, 0)
                    and not self._overlap and radius_ok and not nonper
                    and not asym)
+        # the wrap kernels' planner: the stored extent and in-kernel
+        # depth for this grid, or why no block shape fits it (the
+        # one-chip halo kernels tile no better, so such a grid runs XLA)
+        wrap_refusal = None
+        if wrap_ok:
+            from ..analysis.tiling import TilingInfeasibleError
+            from ..ops.pallas_stencil import sublane_tile, wrap_layout
+            try:
+                self._wrap_layout = wrap_layout(
+                    local.z, local.y, local.x, self._dtype,
+                    _wrap_steps(sublane_tile(self._dtype),
+                                self._exchange_every))
+            except TilingInfeasibleError as e:
+                wrap_ok = False
+                wrap_refusal = str(e)
         # the multi-device fast path: interior-resident shards + slab
         # exchange + fused halo kernel (ops/pallas_halo.py); uneven
         # (+-1) z/y shards supported via the kernel's interior-length
         # overlay (x is never sharded here, so rem.x is always 0)
         halo_ok = (counts.x == 1 and not self._overlap and radius_ok
                    and not nonper and not wire_narrows
-                   and not irr_layout and not asym)
+                   and not irr_layout and not asym
+                   and wrap_refusal is None)
         # the overlapped fast path: in-kernel RDMA slab exchange hidden
         # behind the interior compute (ops/pallas_overlap.py) — the
         # reference's interior/exchange/exterior choreography as one
@@ -560,9 +576,15 @@ class Jacobi3D:
                     blockers.append("overlap requested")
                 if not radius_ok:
                     blockers.append("radius != 1")
+                if wrap_refusal is not None:
+                    blockers.append(f"no wrap block shape fits: "
+                                    f"{wrap_refusal}")
                 why = f" (fast paths unavailable: {', '.join(blockers)})"
             LOG_INFO(f"jacobi kernel path: {kernel}{why}")
         if kernel == "wrap":
+            if wrap_refusal is not None:
+                raise ValueError(f"kernel='wrap' cannot tile this grid: "
+                                 f"{wrap_refusal}")
             if not wrap_ok:
                 raise ValueError("kernel='wrap' needs a (1,1,1) mesh, "
                                  "radius 1, even grid, overlap off")
@@ -709,39 +731,45 @@ class Jacobi3D:
         (ops/pallas_stencil.jacobi7_wrapn_pallas — ~1/N the HBM traffic
         per iteration; N = ``exchange_every``, default 2) with a
         single-step tail; grids the blocked kernel can't tile fall back
-        to single steps."""
+        to single steps. A y extent that is no multiple of the sublane
+        tile is stepped in a stored extent padded up to a multiple of
+        the kernels' block height (``wrap_layout``), wrapped at the
+        logical extent; the padding lives only inside ``run()``."""
         from ..ops.pallas_stencil import (jacobi7_wrapn_pallas,
-                                          jacobi7_wrap_pallas,
-                                          sublane_tile)
+                                          jacobi7_wrap_pallas)
 
         dd = self.dd
         alloc_r = dd.alloc_radius
         local = dd.local_size
         gsize = dd.size
         hot, cold, sph_r = sphere_geometry(gsize)
-        tile = sublane_tile(self._dtype)
-        N = _wrap_steps(tile, self._exchange_every)
-        pair_ok = local.y % tile == 0 and N > 1
+        Ys, N = self._wrap_layout
+        pair_ok = N > 1
+        stored = Dim3(local.x, Ys, local.z)
 
         def single(q):
-            return jacobi7_wrap_pallas(q, hot, cold, sph_r)
+            return jacobi7_wrap_pallas(q, hot, cold, sph_r,
+                                       y_extent=local.y)
 
         def pair(q):
-            return jacobi7_wrapn_pallas(q, hot, cold, sph_r, steps=N)
+            return jacobi7_wrapn_pallas(q, hot, cold, sph_r, steps=N,
+                                        y_extent=local.y)
 
         def steps(p, n):
-            inner = read_interior(p, alloc_r, local)
+            inner = read_interior(p, alloc_r, local, stored)
             inner = run_loop(inner, n, pair if pair_ok else None,
                              single, N)
             # halos go stale; nothing reads them before the next
             # exchange, and temperature() reads the interior only
-            return write_interior(p, inner, alloc_r)
+            return write_interior(p, inner, alloc_r, local)
 
         unit = N if pair_ok else 1
+        stored_zyx = f"{stored.z}x{stored.y}x{stored.x}"
 
         def run_attrs(iters):
             loop, tail = loop_split(iters, unit)
-            return {"loop_steps": loop, "tail_steps": tail}
+            return {"loop_steps": loop, "tail_steps": tail,
+                    "stored_zyx": stored_zyx}
 
         self._run_attrs = run_attrs
 
@@ -754,14 +782,13 @@ class Jacobi3D:
             # multi-step kernel as ONE pallas launch; c == 1 tail steps
             # run the single-step kernel. Interior is sliced out and
             # written back per chunk (the probe reads the padded state)
-            inner = read_interior(p, alloc_r, local)
+            inner = read_interior(p, alloc_r, local, stored)
             if pair_ok and c == N:
-                inner = jacobi7_wrapn_pallas(inner, hot, cold, sph_r,
-                                             steps=N)
+                inner = pair(inner)
             else:
                 for _ in range(c):
-                    inner = jacobi7_wrap_pallas(inner, hot, cold, sph_r)
-            return write_interior(p, inner, alloc_r)
+                    inner = single(inner)
+            return write_interior(p, inner, alloc_r, local)
 
         self._set_segment_builder(shard_advance,
                                   stride=N if pair_ok else 1)
@@ -1079,7 +1106,8 @@ class Jacobi3D:
         per-iteration dispatch). On the wrap and halo paths the span
         also says how many of them the copy-free loop body advances
         (``loop_steps``). On the wrap path ``tail_steps`` counts the
-        rest; on the halo path ``group_steps`` counts the steps the
+        rest and ``stored_zyx`` gives the extent the kernels step; on
+        the halo path ``group_steps`` counts the steps the
         pair-kernel calls advance, ``tail_steps`` the single-step tail
         and ``wire_bytes`` the bytes the chunk puts on the wire, whole
         mesh."""

@@ -133,13 +133,89 @@ def _wrap_elems(esub: int, n_steps: int = 0):
     return elems
 
 
+def _y_slab_maps(Y: int, Ys: int, by: int, esub: int):
+    """Index maps, in ``esub``-row slabs of the stored extent ``Ys``,
+    of the slab just before and just after y block ``ky``, periodic at
+    the logical extent ``Y``: block 0's slab before holds row ``Y - 1``
+    and the slab after the block holding row ``Y - 1`` is slab 0.
+    Blocks past that one only step padding rows."""
+    byb = by // esub
+    nys = Ys // esub
+    if Y == Ys:
+        return (lambda ky: (ky * byb - 1) % nys,
+                lambda ky: (ky * byb + byb) % nys)
+    last = (Y - 1) // by
+    return (lambda ky: jnp.where(ky == 0, (Y - 1) // esub, ky * byb - 1),
+            lambda ky: jnp.where(ky == last, 0, (ky * byb + byb) % nys))
+
+
+def _stored_extent(kernel: str, Ys: int, y_extent: Optional[int],
+                   esub: int, rows: int) -> int:
+    """The logical y extent of a kernel's stored (Z, Ys, X) array,
+    checked: ``Ys`` a multiple of the sublane tile holding it, and the
+    ``rows`` rows before row 0 in one ``esub``-row slab."""
+    Y = Ys if y_extent is None else int(y_extent)
+    if Ys % esub or not 0 < Y <= Ys:
+        raise ValueError(
+            f"{kernel} kernel needs a stored Y % {esub} == 0 holding the "
+            f"logical y extent, got stored Y={Ys} for logical Y={Y}; pad "
+            f"it as wrap_layout() says")
+    if (Y - 1) % esub + 1 < rows:
+        raise ValueError(
+            f"{kernel} kernel needs the last {rows} rows of the logical "
+            f"y extent in one {esub}-row slab, (Y - 1) % {esub} + 1 >= "
+            f"{rows}, got Y={Y}")
+    return Y
+
+
+def wrap_layout(Z: int, Y: int, X: int, dtype,
+                steps: int = 2) -> Tuple[int, int]:
+    """``(stored y extent, steps a kernel call advances)`` of the wrap
+    path for a logical (Z, Y, X) grid asking for ``steps``-step
+    temporal blocking. A Y that is a multiple of the dtype's sublane
+    tile is stored as it is; any other is padded up to a multiple of
+    the block height the planner prices cheapest per logical row. The
+    ``steps``-step kernel runs where it tiles and the logical extent's
+    last ``steps`` rows share a sublane slab; otherwise single steps
+    (1), with a warning where the planner declined. Raises
+    :class:`~stencil_tpu.analysis.tiling.TilingInfeasibleError` with
+    the planner's reason where even the single-step kernel cannot tile
+    the grid."""
+    from ..analysis.tiling import TilingInfeasibleError, plan_blocks
+
+    esub = sublane_tile(dtype)
+    isz = jnp.dtype(dtype).itemsize
+
+    def plan(n, y):
+        if n > 1:
+            return plan_blocks(
+                f"jacobi7_wrapn_pallas[n={n}]", Z, y, X, isz,
+                _wrap_elems(esub, n), sublane_y=esub,
+                cap_z=_WRAPN_CAPS[0], cap_y=_WRAPN_CAPS[1], pad_y=True)
+        return plan_blocks(
+            "jacobi7_wrap_pallas", Z, y, X, isz, _wrap_elems(esub),
+            sublane_y=esub, cap_z=_WRAP_CAPS[0], cap_y=_WRAP_CAPS[1],
+            pad_y=True)
+
+    if 1 < steps <= esub and (Y - 1) % esub + 1 >= steps:
+        try:
+            Ys = plan(steps, Y).stored_y
+            plan(1, Ys).blocks()    # the tail's kernel, same extent
+            return Ys, steps
+        except TilingInfeasibleError as e:
+            from ..utils.logging import LOG_WARN
+            LOG_WARN(f"wrap temporal blocking declined: {e}")
+    return plan(1, Y).stored_y, 1
+
+
 @layer_scope(KERNEL)
 def jacobi7_wrap_pallas(interior: jnp.ndarray,
                         hot_c: Tuple[int, int, int],
                         cold_c: Tuple[int, int, int], sph_r: int,
                         block_z: Optional[int] = None,
                         block_y: Optional[int] = None,
-                        interpret: Optional[bool] = None) -> jnp.ndarray:
+                        interpret: Optional[bool] = None,
+                        y_extent: Optional[int] = None) -> jnp.ndarray:
     """Fully-fused periodic Jacobi step for a single-shard axis layout:
     7-point update + Dirichlet sphere sources on an UNPADDED (Z, Y, X)
     array, with the periodic wrap done inside the kernel — z/y wrap via
@@ -158,26 +234,31 @@ def jacobi7_wrap_pallas(interior: jnp.ndarray,
     blocks are snapped to alignment with a one-shot warning when
     replaced (budget deliberately unchecked — sweeps measure what they
     asked for).
+
+    ``y_extent`` is the logical y extent where the array's own is a
+    stored one padded up to a multiple of the sublane tile
+    (:func:`wrap_layout`): y wraps at ``y_extent``, and the padding
+    rows are stepped but never read as a neighbour. x is the full lane
+    extent at any size, so it never needs padding.
     """
     from ..analysis.tiling import plan_blocks, snap_blocks
 
     if interpret is None:
         interpret = default_interpret()
-    Z, Y, X = interior.shape
+    Z, Ys, X = interior.shape
     dt_i = jnp.dtype(interior.dtype)
     # y edge slabs are esub rows: the dtype's min sublane tile (8 f32 /
-    # 16 bf16) when Y allows, else single rows (small/interpret grids)
+    # 16 bf16)
     esub = sublane_tile(interior.dtype)
-    if Y % esub:
-        esub = 1
+    Y = _stored_extent("jacobi7_wrap", Ys, y_extent, esub, 1)
     if block_z is None and block_y is None:
         bz, by = plan_blocks(
-            "jacobi7_wrap_pallas", Z, Y, X, dt_i.itemsize,
+            "jacobi7_wrap_pallas", Z, Ys, X, dt_i.itemsize,
             _wrap_elems(esub), sublane_y=esub,
             cap_z=_WRAP_CAPS[0], cap_y=_WRAP_CAPS[1]).blocks()
     else:
         bz, by = snap_blocks(
-            "jacobi7_wrap_pallas", Z, Y,
+            "jacobi7_wrap_pallas", Z, Ys,
             block_z if block_z is not None else _WRAP_CAPS[0],
             block_y if block_y is not None else _WRAP_CAPS[1],
             sublane_y=esub)
@@ -185,6 +266,12 @@ def jacobi7_wrap_pallas(interior: jnp.ndarray,
     hx, hy, hz = hot_c
     cx, cy, cz = cold_c
     r2 = sph_r * sph_r
+    # the block holding row Y - 1, its logical rows, and row Y - 1's
+    # place in its slab (block 0's wrapped neighbour row)
+    last = (Y - 1) // by
+    nlast = Y - last * by
+    o0 = (Y - 1) % esub
+    ym_map, yp_map = _y_slab_maps(Y, Ys, by, esub)
 
     def kern(zprev, main, znext, yprev, ynext, out):
         kz = pl.program_id(0)
@@ -192,33 +279,51 @@ def jacobi7_wrap_pallas(interior: jnp.ndarray,
         c = main[...]                            # (bz, by, X)
         # the wrapped neighbor row is the last row of the preceding
         # edge slab / first row of the following one
-        ext = jnp.concatenate([yprev[:, esub - 1:esub], c, ynext[:, 0:1]],
-                              axis=1)
-        ym = ext[:, :by]                         # row j-1 (wrapped)
-        yp = ext[:, 2:]
-        xm = pltpu.roll(c, 1, 2)
-        xp = pltpu.roll(c, X - 1, 2)
-        lat = ym + yp + xm + xp
-        gy = (ky * by
-              + jax.lax.broadcasted_iota(jnp.int32, (by, X), 0))
-        gx = jax.lax.broadcasted_iota(jnp.int32, (by, X), 1)
-        d2yx_h = (gx - hx) ** 2 + (gy - hy) ** 2
-        d2yx_c = (gx - cx) ** 2 + (gy - cy) ** 2
-        for r in range(bz):
-            zm = zprev[0] if r == 0 else c[r - 1]
-            zp = znext[0] if r == bz - 1 else c[r + 1]
-            new = (lat[r] + zm + zp) * dt.type(1.0 / 6.0)
-            gz = kz * bz + r
-            new = jnp.where(d2yx_h + (gz - jnp.int32(hz)) ** 2 <= r2,
-                            dt.type(1.0), new)
-            new = jnp.where(d2yx_c + (gz - jnp.int32(cz)) ** 2 <= r2,
-                            dt.type(0.0), new)
-            out[r] = new
+        lo = yprev[:, esub - 1:esub]
+        if o0 != esub - 1:
+            lo = jnp.where(ky == 0, yprev[:, o0:o0 + 1], lo)
+
+        def finish(ext):
+            ym = ext[:, :by]                     # row j-1 (wrapped)
+            yp = ext[:, 2:]
+            xm = pltpu.roll(c, 1, 2)
+            xp = pltpu.roll(c, X - 1, 2)
+            lat = ym + yp + xm + xp
+            gy = (ky * by
+                  + jax.lax.broadcasted_iota(jnp.int32, (by, X), 0))
+            gx = jax.lax.broadcasted_iota(jnp.int32, (by, X), 1)
+            d2yx_h = (gx - hx) ** 2 + (gy - hy) ** 2
+            d2yx_c = (gx - cx) ** 2 + (gy - cy) ** 2
+            for r in range(bz):
+                zm = zprev[0] if r == 0 else c[r - 1]
+                zp = znext[0] if r == bz - 1 else c[r + 1]
+                new = (lat[r] + zm + zp) * dt.type(1.0 / 6.0)
+                gz = kz * bz + r
+                new = jnp.where(d2yx_h + (gz - jnp.int32(hz)) ** 2 <= r2,
+                                dt.type(1.0), new)
+                new = jnp.where(d2yx_c + (gz - jnp.int32(cz)) ** 2 <= r2,
+                                dt.type(0.0), new)
+                out[r] = new
+
+        if nlast == by:
+            finish(jnp.concatenate([lo, c, ynext[:, 0:1]], axis=1))
+            return
+
+        # the block holding row Y - 1 reads row 0 after it: its own
+        # rows past it are padding
+        @pl.when(ky == last)
+        def _():
+            finish(jnp.concatenate([lo, c[:, :nlast], ynext[:, 0:1],
+                                    c[:, nlast:]], axis=1))
+
+        @pl.when(ky != last)
+        def _():
+            finish(jnp.concatenate([lo, c, ynext[:, 0:1]], axis=1))
 
     return pl.pallas_call(
         kern,
         name="jacobi7_wrap",
-        grid=(Z // bz, Y // by),
+        grid=(Z // bz, Ys // by),
         in_specs=[
             # plane before this z block, periodic
             pl.BlockSpec((1, by, X),
@@ -229,16 +334,12 @@ def jacobi7_wrap_pallas(interior: jnp.ndarray,
                          lambda kz, ky: ((kz * bz + bz) % Z, ky, 0)),
             # esub-row y slabs just outside this block, periodic
             pl.BlockSpec((bz, esub, X),
-                         lambda kz, ky: (kz,
-                                         (ky * (by // esub) - 1)
-                                         % (Y // esub), 0)),
+                         lambda kz, ky: (kz, ym_map(ky), 0)),
             pl.BlockSpec((bz, esub, X),
-                         lambda kz, ky: (kz,
-                                         (ky * (by // esub) + by // esub)
-                                         % (Y // esub), 0)),
+                         lambda kz, ky: (kz, yp_map(ky), 0)),
         ],
         out_specs=pl.BlockSpec((bz, by, X), lambda kz, ky: (kz, ky, 0)),
-        out_shape=jax.ShapeDtypeStruct((Z, Y, X), interior.dtype),
+        out_shape=jax.ShapeDtypeStruct((Z, Ys, X), interior.dtype),
         # allow larger-than-default blockings in tuning sweeps (Mosaic's
         # default scoped-VMEM ceiling is 16 MiB)
         compiler_params=pltpu.CompilerParams(
@@ -254,7 +355,8 @@ def jacobi7_wrapn_pallas(interior: jnp.ndarray,
                          steps: int = 2,
                          block_z: Optional[int] = None,
                          block_y: Optional[int] = None,
-                         interpret: Optional[bool] = None) -> jnp.ndarray:
+                         interpret: Optional[bool] = None,
+                         y_extent: Optional[int] = None) -> jnp.ndarray:
     """``steps`` fused periodic Jacobi iterations (+ sphere sources
     after each) in ONE HBM pass — temporal blocking. The single-step
     kernel is bandwidth-bound at ~2.4 HBM passes per iteration;
@@ -262,10 +364,11 @@ def jacobi7_wrapn_pallas(interior: jnp.ndarray,
     VMEM (recomputing an edge ring of step-k values at block borders)
     costs the same traffic per *pass* but advances ``steps``
     iterations, dividing per-iteration traffic by ~``steps`` at the
-    price of ring recompute that grows with ``steps``. Bit-identical
-    to ``steps`` ``jacobi7_wrap_pallas`` calls (same op order per
-    point; the ring is recomputed, not approximated). Reference
-    semantics: bin/jacobi3d.cu:40-85 applied ``steps`` times.
+    price of ring recompute that grows with ``steps``. Equal to
+    ``steps`` ``jacobi7_wrap_pallas`` calls up to the last bits: the
+    ring is recomputed, not approximated, but a point's six neighbours
+    are summed in another order. Reference semantics:
+    bin/jacobi3d.cu:40-85 applied ``steps`` times.
 
     Each (bz, by, X) output block reads a wrapped (bz+2N, by+2N, X)
     window assembled from a main block, 2N single-row z segments, 2
@@ -273,29 +376,30 @@ def jacobi7_wrapn_pallas(interior: jnp.ndarray,
     ``pltpu.roll``; z is the majormost dim, so single-row fetches are
     exact-radius). Needs Z % bz == 0, Y and by multiples of the
     dtype's sublane tile (8 f32 / 16 bf16), and steps <= that tile.
+    ``y_extent`` is the logical y extent of a padded stored array, as
+    for :func:`jacobi7_wrap_pallas`; its last ``steps`` rows must lie in
+    one sublane-tile slab.
     """
     from ..analysis.tiling import plan_blocks, snap_blocks
 
     if interpret is None:
         interpret = default_interpret()
     N = int(steps)
-    Z, Y, X = interior.shape
+    Z, Ys, X = interior.shape
     esub = sublane_tile(interior.dtype)
     if N < 1 or N > esub:
         raise ValueError(f"wrapN kernel needs 1 <= steps <= {esub}, "
                          f"got steps={N}")
-    if Y % esub:
-        raise ValueError(f"wrap{N} kernel needs Y % {esub} == 0, "
-                         f"got Y={Y}")
+    Y = _stored_extent(f"wrap{N}", Ys, y_extent, esub, N)
     isz = jnp.dtype(interior.dtype).itemsize
     if block_z is None and block_y is None:
         bz, by = plan_blocks(
-            f"jacobi7_wrapn_pallas[n={N}]", Z, Y, X, isz,
+            f"jacobi7_wrapn_pallas[n={N}]", Z, Ys, X, isz,
             _wrap_elems(esub, N), sublane_y=esub,
             cap_z=_WRAPN_CAPS[0], cap_y=_WRAPN_CAPS[1]).blocks()
     else:
         bz, by = snap_blocks(
-            f"jacobi7_wrapn_pallas[n={N}]", Z, Y,
+            f"jacobi7_wrapn_pallas[n={N}]", Z, Ys,
             block_z if block_z is not None else _WRAPN_CAPS[0],
             block_y if block_y is not None else _WRAPN_CAPS[1],
             sublane_y=esub)
@@ -307,8 +411,12 @@ def jacobi7_wrapn_pallas(interior: jnp.ndarray,
     hx, hy, hz = hot_c
     cx, cy, cz = cold_c
     r2 = sph_r * sph_r
-    byb = by // esub       # y index maps use esub-col granularity
-    nyb8 = Y // esub
+    # the block holding row Y - 1, its logical rows, and where the N
+    # rows before row 0 start in their slab
+    last = (Y - 1) // by
+    nlast = Y - last * by
+    o0 = (Y - N) % esub
+    ym_map, yp_map = _y_slab_maps(Y, Ys, by, esub)
 
     def sources(vals, z0, y0, nz, ny):
         """Re-impose Dirichlet spheres on a (nz, ny, X) region whose
@@ -352,27 +460,44 @@ def jacobi7_wrapn_pallas(interior: jnp.ndarray,
         y0 = ky * by
         eN = esub - N
 
-        def row(zref, cm, cp):
-            return jnp.concatenate([cm[:, eN:], zref[...], cp[:, :N]],
-                                   axis=1)
+        def lo(cm):
+            # block 0's rows before it end at row Y - 1 of their slab
+            if o0 == eN:
+                return cm[:, eN:]
+            return jnp.where(ky == 0, cm[:, o0:o0 + N], cm[:, eN:])
 
-        rows = [row(zms[i], corners[2 * i], corners[2 * i + 1])
-                for i in range(nzseg)]
-        rows.append(jnp.concatenate([ym[:, eN:], main[...], yp[:, :N]],
-                                    axis=1))
-        rows.extend(row(zps[i], corners[2 * nzseg + 2 * i],
-                        corners[2 * nzseg + 2 * i + 1])
-                    for i in range(nzseg))
-        w = jnp.concatenate(rows, axis=0)     # (bz+2N, by+2N, X)
-        for k in range(N):
-            w = jstep(w)                      # ring shrinks by 1 each
-            ring = N - 1 - k
-            w = sources(w, z0 - ring, y0 - ring, bz + 2 * ring,
-                        by + 2 * ring)
-        out[...] = w
+        def row(zref, cm, cp, holds_last):
+            if not holds_last:
+                return jnp.concatenate([lo(cm), zref[...], cp[:, :N]],
+                                       axis=1)
+            # row 0 follows row Y - 1; the block's rows past it are
+            # padding, kept at the end so the window keeps its shape
+            c = zref[...]
+            return jnp.concatenate([lo(cm), c[:, :nlast], cp[:, :N],
+                                    c[:, nlast:]], axis=1)
 
-    ym_map = lambda ky: (ky * byb - 1) % nyb8
-    yp_map = lambda ky: (ky * byb + byb) % nyb8
+        def step(holds_last):
+            rows = [row(zms[i], corners[2 * i], corners[2 * i + 1],
+                        holds_last)
+                    for i in range(nzseg)]
+            rows.append(row(main, ym, yp, holds_last))
+            rows.extend(row(zps[i], corners[2 * nzseg + 2 * i],
+                            corners[2 * nzseg + 2 * i + 1], holds_last)
+                        for i in range(nzseg))
+            w = jnp.concatenate(rows, axis=0)     # (bz+2N, by+2N, X)
+            for k in range(N):
+                w = jstep(w)                      # ring shrinks by 1 each
+                ring = N - 1 - k
+                w = sources(w, z0 - ring, y0 - ring, bz + 2 * ring,
+                            by + 2 * ring)
+            out[...] = w
+
+        if nlast == by:
+            step(False)
+        else:
+            pl.when(ky == last)(lambda: step(True))
+            pl.when(ky != last)(lambda: step(False))
+
     if slabbed:
         # N-row z segments in N-row block units (bz % N == 0 makes the
         # maps integral; matches the original wrap2 structure at N=2)
@@ -405,10 +530,10 @@ def jacobi7_wrapn_pallas(interior: jnp.ndarray,
     return pl.pallas_call(
         kern,
         name="jacobi7_wrapn",
-        grid=(Z // bz, Y // by),
+        grid=(Z // bz, Ys // by),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bz, by, X), lambda kz, ky: (kz, ky, 0)),
-        out_shape=jax.ShapeDtypeStruct((Z, Y, X), interior.dtype),
+        out_shape=jax.ShapeDtypeStruct((Z, Ys, X), interior.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,

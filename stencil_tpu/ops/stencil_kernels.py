@@ -9,7 +9,7 @@ Pallas version of the hot kernels lives in ``pallas_stencil.py``.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 from jax import lax
@@ -89,19 +89,47 @@ def central_diff(padded: jnp.ndarray, axis: int, radius: Radius,
 
 @layer_scope(INTERIOR)
 def read_interior(padded: jnp.ndarray, radius: Radius,
-                  interior: Dim3) -> jnp.ndarray:
-    """The interior of a padded shard, halos dropped."""
-    return shifted(padded, (0, 0, 0), radius.pad_lo(), interior)
+                  interior: Dim3,
+                  stored: Optional[Dim3] = None) -> jnp.ndarray:
+    """The interior of a padded shard, halos dropped; with ``stored``,
+    the interior at the low corner of an array of that larger extent
+    (the aligned layout a kernel steps, ``pallas_stencil.wrap_layout``)
+    in one pass, its rows past the interior the shard's high halo, then
+    zeros."""
+    if stored is None or stored == interior:
+        return shifted(padded, (0, 0, 0), radius.pad_lo(), interior)
+    return lax.pad(padded, jnp.zeros((), padded.dtype),
+                   _crop(padded.shape, radius, stored))
 
 
 @layer_scope(INTERIOR)
 def write_interior(padded: jnp.ndarray, interior_vals: jnp.ndarray,
-                   radius: Radius) -> jnp.ndarray:
+                   radius: Radius,
+                   interior: Optional[Dim3] = None) -> jnp.ndarray:
     """Place interior-shaped values into a padded shard (halos keep
-    their previous contents)."""
+    their previous contents). Values in a larger stored layout
+    (``read_interior(stored=...)``) with ``interior`` given make the
+    whole shard anew in one pass instead: the interior from them, the
+    halos from their padding rows or zeros (stale either way)."""
     lo = radius.pad_lo()
+    if interior is not None and interior_vals.shape != (
+            interior.z, interior.y, interior.x):
+        stored = Dim3(*interior_vals.shape[::-1])
+        return lax.pad(interior_vals.astype(padded.dtype),
+                       jnp.zeros((), padded.dtype),
+                       [(-a, -b, 0) for a, b, _ in
+                        _crop(padded.shape, radius, stored)])
     return lax.dynamic_update_slice(padded, interior_vals.astype(padded.dtype),
                                     (lo.z, lo.y, lo.x))
+
+
+def _crop(padded_zyx, radius: Radius, stored: Dim3):
+    """``lax.pad`` config taking a padded shard of ``padded_zyx`` to a
+    ``stored`` extent whose origin is the interior's first cell."""
+    lo = radius.pad_lo()
+    return [(-l, s - (p - l), 0) for p, l, s in
+            zip(padded_zyx, (lo.z, lo.y, lo.x),
+                (stored.z, stored.y, stored.x))]
 
 
 def global_coords(origin_xyz, interior: Dim3):

@@ -79,7 +79,8 @@ def test_layers_are_named(case):
 def test_wrap_run_span_counts_loop_and_tail_steps():
     """On the one-chip wrap path (pair kernel, two calls per loop
     iteration) `stencil.run` says how many steps the copy-free loop
-    body advanced and how many the tails; other paths say neither."""
+    body advanced, how many the tails, and the extent the kernels step
+    (`stored_zyx`); other paths say none of it."""
     from stencil_tpu.models.jacobi import Jacobi3D
     from stencil_tpu.telemetry.spans import Tracer, set_tracer
 
@@ -96,10 +97,12 @@ def test_wrap_run_span_counts_loop_and_tail_steps():
     finally:
         set_tracer(prev)
     attrs = [sp.attrs for sp in tracer.finished() if sp.name == RUN]
+    grid = "16x16x16"
     assert attrs == [
-        {"iters": 32, "loop_steps": 32, "tail_steps": 0},
-        {"iters": 5, "loop_steps": 4, "tail_steps": 1},
-        {"iters": 1, "loop_steps": 0, "tail_steps": 1},
+        {"iters": 32, "loop_steps": 32, "tail_steps": 0,
+         "stored_zyx": grid},
+        {"iters": 5, "loop_steps": 4, "tail_steps": 1, "stored_zyx": grid},
+        {"iters": 1, "loop_steps": 0, "tail_steps": 1, "stored_zyx": grid},
         {"iters": 1},
     ]
 

@@ -251,7 +251,8 @@ def test_planner_shapes_round_trip_through_vmem_audit(family, side,
     except TilingInfeasibleError as e:
         assert "no legal block shape" in str(e)
     except ValueError as e:
-        # the N-step kernels refuse non-sublane-divisible Y outright
+        # the wrap kernels refuse a stored Y that is no multiple of the
+        # sublane tile (the model pads it first: wrap_layout)
         assert degraded and "== 0" in str(e), e
 
 

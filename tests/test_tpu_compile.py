@@ -154,6 +154,52 @@ def test_jacobi_wrap_run_loop_512_has_no_carry_copy(one_chip):
             assert not any(grid.search(line) for line in lines), name
 
 
+def test_jacobi_wrap_run_loop_750_unaligned(one_chip):
+    """The program Jacobi3D(750^3).run(n) compiles on one chip: 750 is
+    no multiple of the (8, 128) tile, so the interior is read into the
+    y-padded stored extent ``wrap_layout`` gives, stepped by the pair
+    kernel wrapping at 750, and cut back on the write. The chip's
+    compiler holds the blocks to the tile rule that interpret mode
+    skips; the loop body chains two pair calls with no copy of the
+    carry."""
+    from stencil_tpu.geometry import Dim3, Radius
+    from stencil_tpu.models.jacobi import run_loop, sphere_geometry
+    from stencil_tpu.ops.pallas_stencil import (jacobi7_wrap_pallas,
+                                                jacobi7_wrapn_pallas,
+                                                wrap_layout)
+    from stencil_tpu.ops.stencil_kernels import read_interior, write_interior
+
+    n = 750
+    local = Dim3(n, n, n)
+    stored_y, N = wrap_layout(n, n, n, jnp.float32, 2)
+    assert N == 2 and stored_y % 8 == 0 and stored_y > n
+    hot, cold, sph_r = sphere_geometry(local)
+    alloc_r = Radius.constant(1)
+    kw = dict(hot_c=tuple(hot), cold_c=tuple(cold), sph_r=sph_r,
+              interpret=False, y_extent=n)
+
+    def steps(p, k):
+        inner = read_interior(p, alloc_r, local, Dim3(n, stored_y, n))
+        inner = run_loop(inner, k,
+                         lambda q: jacobi7_wrapn_pallas(q, steps=2, **kw),
+                         lambda q: jacobi7_wrap_pallas(q, **kw), 2)
+        return write_interior(p, inner, alloc_r, local)
+
+    compiled = _compile(steps, _spec((n + 2,) * 3, one_chip),
+                        _spec((), one_chip, jnp.int32),
+                        kernel=("jacobi7_wrapn", "jacobi7_wrap"), donate=0)
+    comps, bodies = _while_bodies(compiled)
+
+    def pair_calls(name):
+        return _calls(comps[name], "jacobi7_wrapn")
+
+    assert sorted(pair_calls(b) for b in bodies if pair_calls(b)) == [2]
+    grid = re.compile(rf"= f32\[{n},{stored_y},{n}\]\S* copy\(")
+    for name, lines in comps.items():
+        if pair_calls(name) >= 2:
+            assert not any(grid.search(line) for line in lines), name
+
+
 @pytest.mark.parametrize("substep", [0, 1])
 def test_mhd_wrap_substep_256(one_chip, substep):
     """The MHD wrap megakernel's substeps as Astaroth(256^3) runs them
